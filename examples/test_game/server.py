@@ -108,7 +108,7 @@ class Avatar(gw.Entity):
                           shard_key=subject.split(".")[0])
 
     def OnPublish(self, subject, *args):
-        # relay pubsub deliveries to the owning client
+        # pass pubsub deliveries on to the owning client
         self.call_client("OnPublish", subject, *args)
 
     def OnGainExp(self, amount):

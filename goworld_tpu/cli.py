@@ -34,7 +34,7 @@ import sys
 import time
 
 from goworld_tpu import config as config_mod
-from goworld_tpu.utils import log
+from goworld_tpu.utils import log, snapfiles
 from goworld_tpu.utils.consts import (
     SUPERVISOR_STARTED_TAG,
 )
@@ -191,11 +191,11 @@ def _start_game_group(server_dir: str, cfg, gid: int, entry: str,
     # periodic crash-recovery checkpoint (a supervisor start after a
     # crash must not cold-boot past hours of checkpoints). The booting
     # game picks the freshest PARSEABLE one itself
-    # (freeze.restore_from_file); filenames spelled out here so the ops
-    # CLI needn't import the jax-heavy freeze module just to start.
+    # (freeze.restore_from_file).
     restore = force_restore or any(
         os.path.exists(os.path.join(server_dir, name))
-        for name in (f"game{gid}_freezed.dat", f"game{gid}_checkpoint.dat")
+        for name in (snapfiles.freeze_filename(gid),
+                     snapfiles.checkpoint_filename(gid))
     )
     waits: list[tuple[str, int]] = []
     for rank, label in enumerate(labels):
@@ -433,7 +433,8 @@ def _cmd_reload_locked(server_dir: str) -> int:
                for lb in labels):
             print(f"game{gid}: freeze did not complete", file=sys.stderr)
             return 1
-        freeze_file = os.path.join(server_dir, f"game{gid}_freezed.dat")
+        freeze_file = os.path.join(server_dir,
+                                   snapfiles.freeze_filename(gid))
         # the file must be FRESH: a stale snapshot from a previous
         # reload would otherwise mask a failed freeze and silently
         # restore outdated state
@@ -549,14 +550,12 @@ def watch_once(server_dir: str,
     coordinator cannot re-admit a rank, the cmd_start guard), then the
     whole group restarts with ``-restore`` from the freshest snapshot
     (a reload's freeze file or the periodic ``checkpoint_interval``
-    checkpoint, whichever is newer — ``freeze.latest_snapshot_path``).
+    checkpoint, whichever is newer — ``snapfiles.latest_snapshot_path``).
     Exception: a dead game with a configured LIVE hot standby
     (``[gameN] standby_of``) is recovered by warm promotion instead —
     the standby already mirrors the state in memory, so failover costs
     ticks, not a process boot (``_promote_standby``).
     Returns a list of action strings (empty = everything healthy)."""
-    from goworld_tpu import freeze as freeze_mod
-
     if _in_maintenance(server_dir):
         return []  # a deliberate stop/reload is in flight: stand down
 
@@ -653,7 +652,7 @@ def watch_once(server_dir: str,
                 f"game{gid}: standby game{sgid} unreachable; "
                 "falling back to cold restore"
             )
-        snap = freeze_mod.latest_snapshot_path(gid, server_dir)
+        snap = snapfiles.latest_snapshot_path(gid, server_dir)
         ok = _start_game_group(server_dir, cfg, gid, entry, py, rel_cfg,
                                force_restore=snap is not None)
         if backoff is not None:
@@ -729,7 +728,8 @@ def _freeze_games_for_shutdown(server_dir: str,
                     os.unlink(_pid_path(server_dir, "game", lb))
                 except OSError:
                     pass
-        freeze_file = os.path.join(server_dir, f"game{gid}_freezed.dat")
+        freeze_file = os.path.join(server_dir,
+                                   snapfiles.freeze_filename(gid))
         if not os.path.exists(freeze_file) \
                 or os.path.getmtime(freeze_file) < t_sig - 1.0:
             print(f"game{gid}: freeze-on-shutdown left no fresh "

@@ -96,7 +96,7 @@ class GameConfig:
     # churn-adaptive extraction small-tier row budget (ops/extract.py
     # SMALL_TIER_ROWS; also env GOWORLD_SMALL_TIER_ROWS). 0 = library
     # default (16384, sized from the 1M bench's client-row churn;
-    # TPU-profile re-derivation pending — docs/TODO_R5.md)
+    # not re-derived from a chip profile)
     small_tier_rows: int = 0
     # periodic crash-recovery checkpoint cadence in seconds (0 = off):
     # the game snapshots the running world on this interval so a
@@ -291,6 +291,9 @@ class GameConfig:
     # chosen for clients), subscribes to game N's frame stream through
     # the dispatcher, mirrors its world live, and is promoted by the
     # supervisor when game N dies (kvreg-arbitrated, split-brain-safe).
+    # It is a second game process with a device world of its own, so it
+    # needs its own chip: on the chip game N holds, its backend init
+    # fails at start ("TPU is already in use by process with pid ...").
     # 0 = a normal primary.
     standby_of: int = 0
     # primary-side stream cadence: every Nth streamed frame is a full

@@ -155,6 +155,19 @@ class SpaceState:
     behavior_id: jax.Array | None = None
 
 
+def seed_key(seed: int) -> jax.Array:
+    """The ONE place a world seed becomes a PRNG key (state rng, the
+    config-built MLP policy, the scenario runner). The stream is the
+    installed JAX's default: threefry2x32 in its PARTITIONABLE form
+    (``jax_threefry_partitionable``, on by default since jax 0.5) — the
+    form a mesh can draw shard-locally, and a different stream from the
+    one jax 0.4 drew for the same seed. It is backend-independent
+    (integer arithmetic), so ``--seed`` means the same world on the CPU
+    and on the chip; tests/test_step.py pins its first words so a
+    change of default can never pass silently again."""
+    return jax.random.PRNGKey(seed)
+
+
 def create_state(cfg: WorldConfig, seed: int = 0) -> SpaceState:
     n, a, k = cfg.capacity, cfg.attr_width, cfg.grid.k
     scn = cfg.scenario
@@ -193,7 +206,7 @@ def create_state(cfg: WorldConfig, seed: int = 0) -> SpaceState:
         nbr_mean_off=jnp.zeros((n, 3), jnp.float32),
         aoi_radius=aoi_radius,
         dirty=jnp.zeros((n,), bool),
-        rng=jax.random.PRNGKey(seed),
+        rng=seed_key(seed),
         tick=jnp.zeros((), jnp.int32),
         # mirrors tick_body's use_verlet guard: past the packed-id
         # bound the tick statically falls back to the stateless sweep,

@@ -146,8 +146,9 @@ def _start_host_copy(tree) -> None:
     parked output lanes (TickOutputs, telemetry accumulator, sync-age
     anchor rides them) overlaps the device's compute of tick T+1 —
     next tick's blocking fetch then finds the bytes already staged
-    host-side. Best-effort: a backend without copy_to_host_async just
-    keeps the old serial fetch.
+    host-side. On an accelerator a failure here is a defect and
+    surfaces: swallowing it would serve the serial fetch under the
+    overlapped path's name.
 
     Skipped entirely on the CPU backend: the buffers are already
     host-resident there, and copy_to_host_async on a still-executing
@@ -156,13 +157,7 @@ def _start_host_copy(tree) -> None:
     if tree is None or jax.default_backend() == "cpu":
         return
     for leaf in jax.tree.leaves(tree):
-        start = getattr(leaf, "copy_to_host_async", None)
-        if start is None:
-            continue
-        try:
-            start()
-        except Exception:
-            return
+        leaf.copy_to_host_async()
 
 
 class AdmissionPausedError(RuntimeError):
@@ -227,9 +222,10 @@ class World:
         ):
             # config-built worlds need a live policy; callers may replace
             # it (e.g. with trained weights) before the first tick
+            from goworld_tpu.core.state import seed_key
             from goworld_tpu.models.npc_policy import init_policy
 
-            self.policy = init_policy(jax.random.PRNGKey(seed))
+            self.policy = init_policy(seed_key(seed))
         self.mega = None    # MegaConfig when megaspace=True
         # pipelined host decode (see tick()): only the single-
         # controller, non-mesh shape qualifies — reject loudly instead
@@ -253,6 +249,10 @@ class World:
         self.resident = resident
         self._resident_copy_warned = False
         self._pending_outs = None
+        # set by freeze.restore_world: {client owner's eid: the eids its
+        # client was told it can see when the world froze}; consumed by
+        # the first tick (_reconcile_restored_interest)
+        self._restored_interest: dict[str, list[str]] | None = None
         if mesh is not None and mesh.devices.size != n_spaces:
             raise ValueError(
                 f"mesh has {mesh.devices.size} devices but "
@@ -564,6 +564,19 @@ class World:
         )
         self._m_aoi_demand = metrics.gauge("aoi_demand_max")
         self._m_aoi_cell = metrics.gauge("aoi_cell_max")
+        # enter/leave pairs past enter_cap/leave_cap (and changed rows
+        # past delta_rows_cap): the host's interest sets never learn
+        # them, so every one is counted, not only warned about
+        self._m_aoi_dropped = {
+            kind: metrics.counter(
+                "aoi_events_dropped_total",
+                help="interest events the tick produced and the host "
+                     "never decoded (past enter_cap / leave_cap / "
+                     "delta_rows_cap)",
+                kind=kind,
+            ) for kind in ("enter", "leave", "rows")
+        }
+        self.aoi_dropped = dict.fromkeys(self._m_aoi_dropped, 0)
         # Verlet skin-reuse cadence (ops.aoi.grid_neighbors_verlet):
         # rebuild_total counts front-half rebuilds (== tick count when
         # the skin is off), skin_slack mirrors the headroom left before
@@ -971,7 +984,8 @@ class World:
         return True
 
     def _enter_space_local(
-        self, e: Entity, space: Space, pos, moving: bool = False
+        self, e: Entity, space: Space, pos, moving: bool = False,
+        yaw: float = 0.0,
     ) -> None:
         e.space = space
         space.members.add(e.id)
@@ -990,7 +1004,7 @@ class World:
                     hot[col] = float(v)
             self._staged_spawn.append((shard, slot, dict(
                 pos=tuple(map(float, pos)),
-                yaw=0.0,
+                yaw=float(yaw),
                 type_id=e._type_desc.type_id,
                 npc_moving=moving,
                 has_client=e.client is not None,
@@ -1840,9 +1854,8 @@ class World:
             # ASYNC above, then tick N-1's outputs — already
             # materialized on device — are fetched and decoded WHILE
             # the device computes N. The frame pays
-            # max(device, host decode) instead of their sum (on TPU
-            # the host half was ~5-7 ms of a 16 ms frame —
-            # docs/R5_MEASUREMENTS.md). Costs: host-visible events and
+            # max(device, host decode) instead of their sum (not
+            # measured on the chip since). Costs: host-visible events and
             # client sends lag one tick, and the slot-release
             # quarantine is skewed one call to match (_flush_staging
             # routes despawn releases via _release_next). Freeze /
@@ -1873,19 +1886,20 @@ class World:
         # audit-oracle cohort planes (ISSUE 17): on a sample tick the
         # judged shard's pos/alive/aoi_radius ride the SAME combined
         # fetch below — the lazy device slices cost nothing to build
-        # and the plane adds zero sync points. Only the single-
-        # controller non-mega shape is judged (a mesh slice would
-        # gather cross-device; the skip is recorded honestly in
-        # _audit_sample).
-        aud_req = None
-        ap = self.audit
-        if (ap is not None and self.mega is None and self.mesh is None
-                and not self.pipeline_decode
-                and ap.want_sample(self.tick_count)):
-            s = self._audit_shard % self.n_spaces
-            aud_req = (self.state.pos[s], self.state.alive[s],
-                       self.state.aoi_radius[s])
+        # once compiled (the first sample compiles them: tens of ms,
+        # inside the span so the trace accounts for it) and the plane
+        # adds zero sync points. Only the single-controller non-mega
+        # shape is judged (a mesh slice would gather cross-device; the
+        # skip is recorded honestly in _audit_sample).
         with tl.span("fetch_outputs"):
+            aud_req = None
+            ap = self.audit
+            if (ap is not None and self.mega is None
+                    and self.mesh is None and not self.pipeline_decode
+                    and ap.want_sample(self.tick_count)):
+                s = self._audit_shard % self.n_spaces
+                aud_req = (self.state.pos[s], self.state.alive[s],
+                           self.state.aoi_radius[s])
             acc_host = None
             aud_host = None
             if rt is not None:
@@ -1946,6 +1960,8 @@ class World:
             if outs is not None:
                 self._decode_outputs(outs)
             self.post_q.tick()
+        if self._restored_interest is not None:
+            self._reconcile_restored_interest()
         ap = self.audit
         if ap is not None and ap.want_sample(self.tick_count):
             # capture the cohort + frozen interest sets HERE (the
@@ -1993,6 +2009,55 @@ class World:
         if pending is None:
             return
         self._decode_outputs(self._dget(pending))
+
+    def _reconcile_restored_interest(self) -> None:
+        """End of the first tick after a reload's restore. A client that
+        stayed connected still holds the mirrors it had at the freeze,
+        but the restored world starts from empty neighbour lists: its
+        first tick reports every neighbour as an enter (most of them
+        past ``enter_cap`` in a large world) and no leave at all. So
+        hold what each such client was told (carried by the freeze
+        record) against the device's list for its owner's row: destroy
+        what is out of range by now, create what is new and was not
+        decoded, and leave the owner's host sets equal to the device's
+        — every enter the client ever got still gets its leave."""
+        told_by_owner, self._restored_interest = \
+            self._restored_interest, None
+        if not told_by_owner:
+            return
+        # a pipelined decode lags one tick: the host's view must be of
+        # the same tick as state.nbr before the two are compared
+        self.flush_pending_outputs()
+        nbr = self._dget(self.state.nbr)
+        for eid, told in told_by_owner.items():
+            e = self.entities.get(eid)
+            if e is None or e.destroyed or e.client is None \
+                    or e.slot is None or e.shard not in self.local_shards:
+                continue
+            now = {}
+            for j in nbr[e.shard, e.slot].tolist():
+                je = self._owner_subject(e.shard, j)
+                if je is not None and not je.destroyed:
+                    now[je.id] = je
+            for gone in set(told) - now.keys():
+                e.client.send({"type": "destroy_entity", "eid": gone,
+                               "is_player": False})
+            for jid, je in now.items():
+                if jid in e.interested_in:
+                    continue        # decoded this tick, create sent
+                e.interested_in.add(jid)
+                je.interested_by.add(eid)
+                try:
+                    e.OnEnterAOI(je)
+                except Exception:
+                    logger.exception("OnEnterAOI failed")
+                if jid not in told:
+                    e.client.send({
+                        "type": "create_entity", "eid": jid,
+                        "etype": je.type_name, "is_player": False,
+                        "attrs": je.get_all_clients_data(),
+                        "pos": list(je.position), "yaw": je.yaw,
+                    })
 
     # -- correctness audit sampling (utils/audit.py, ISSUE 17) ----------
     def _audit_sample(self, aud_host) -> None:
@@ -2496,6 +2561,7 @@ class World:
         # instance-__dict__ check for per-object hook assignment.
         mega = self.mega is not None
         entities = self.entities
+        dropped = {"enter": 0, "leave": 0, "rows": 0}
         leave_hooked: dict[type, bool] = {}
         enter_hooked: dict[type, bool] = {}
         for shard in self.local_shards:
@@ -2505,6 +2571,7 @@ class World:
                     "shard %d leave overflow: %d > %d", shard, ln,
                     cfg.leave_cap,
                 )
+                dropped["leave"] += ln - cfg.leave_cap
             slot_eid = self._slot_owner[shard].get
             # .tolist() upfront: plain-int pairs beat per-element numpy
             # scalar conversions across tens of thousands of events
@@ -2552,12 +2619,14 @@ class World:
                     "shard %d AOI delta rows overflow: %d > %d — widen "
                     "WorldConfig.delta_rows_cap", shard, drn, drc,
                 )
+                dropped["rows"] += drn - drc
             en = int(base.enter_n[shard])
             if en > cfg.enter_cap:
                 logger.warning(
                     "shard %d enter overflow: %d > %d", shard, en,
                     cfg.enter_cap,
                 )
+                dropped["enter"] += en - cfg.enter_cap
             # per-decode payload cache: one subject typically enters
             # MANY watchers' interest this tick (a mover crossing a
             # crowd), and its AllClients attr snapshot + pos/yaw are
@@ -2737,6 +2806,14 @@ class World:
         opmon.expose("aoi_leave_events", leaves)
         self.op_stats["aoi_enter_events"] = enters
         self.op_stats["aoi_leave_events"] = leaves
+        # ... and what of it the host never saw: this tick's (op_stats,
+        # like every gauge here) and since boot (/vars, /metrics)
+        for kind, cnt in dropped.items():
+            self.op_stats[f"aoi_{kind}_dropped"] = cnt
+            if cnt:
+                self.aoi_dropped[kind] += cnt
+                self._m_aoi_dropped[kind].inc(cnt)
+        opmon.expose("aoi_events_dropped", dict(self.aoi_dropped))
         opmon.expose("aoi_demand_max", dem_max)
         opmon.expose("aoi_over_k_rows", over_k)
         opmon.expose("aoi_cell_max", cell_max)

@@ -29,6 +29,13 @@ from goworld_tpu.entity.entity import Entity, GameClient
 from goworld_tpu.entity.manager import World
 from goworld_tpu.entity.space import Space
 from goworld_tpu.utils import faults, log
+from goworld_tpu.utils.snapfiles import (
+    chain_delta_filename,
+    chain_key_filename,
+    checkpoint_filename,
+    freeze_filename,
+    snapshot_candidates,
+)
 
 logger = log.get("freeze")
 
@@ -41,11 +48,6 @@ class CorruptSnapshotError(RuntimeError):
     writer). The restore path REJECTS such a file whole — half-loading a
     world is worse than falling back to an older snapshot or a cold
     boot."""
-
-
-def freeze_filename(game_id: int) -> str:
-    """Reference ``game%d_freezed.dat`` (``GameService.go:252``)."""
-    return f"game{game_id}_freezed.dat"
 
 
 # =======================================================================
@@ -65,7 +67,8 @@ def _device_snapshot(world: World) -> dict[str, np.ndarray]:
 _DEFER = object()
 
 
-def _pack_entity(world: World, e: Entity, snap) -> dict:
+def _pack_entity(world: World, e: Entity, snap,
+                 carry_interest: bool) -> dict:
     """Migrate-style record (``GetMigrateData``, ``Entity.go:1060-1101``)
     plus the space binding freeze needs and migrate doesn't."""
     live_slot = (
@@ -87,6 +90,8 @@ def _pack_entity(world: World, e: Entity, snap) -> dict:
         pos = [float(v) for v in e.position]
         yaw = float(e._pending_yaw or 0.0)
         moving = False
+    if carry_interest and e.client is not None:
+        extra["interest"] = sorted(e.interested_in)
     return extra | {
         "type": e.type_name,
         "id": e.id,
@@ -144,7 +149,11 @@ def freeze_world(world: World, *, _snap=None, run_hooks: bool = True
                 "timers": world.timers.dump(list(e.timer_ids)),
             })
         else:
-            entities.append(_pack_entity(world, e, snap))
+            # a reload's freeze (hooks run, the world stops here) also
+            # carries what each connected client was told it can see;
+            # a checkpoint of a running world does not — its list
+            # would be stale by the crash it is kept for
+            entities.append(_pack_entity(world, e, snap, run_hooks))
 
     nil = world.nil_space
     return {
@@ -185,6 +194,12 @@ def restore_world(world: World, data: dict) -> None:
         len(world.entities) == 1 and world.nil_space is not None
     ):
         raise RuntimeError("restore requires an empty world")
+    # what connected clients were told they can see, for the first
+    # tick to settle (World._reconcile_restored_interest)
+    world._restored_interest = {
+        ed["id"]: ed["interest"] for ed in data["entities"]
+        if ed.get("client") and "interest" in ed
+    }
 
     # pass 1: nil space (the migration anchor; its id is deterministic
     # from game_id so routing and CallNilSpaces keep working)
@@ -238,10 +253,15 @@ def restore_world(world: World, data: dict) -> None:
             e.client = GameClient(ed["client"][0], ed["client"][1], world,
                                   owner=e)
         target = world.spaces.get(ed.get("space_id") or "") or world.nil_space
+        # the whole pose rides the spawn: a pose staged beside it would
+        # queue behind input_cap for tens of ticks in a large world and
+        # then rewind a row the device has moved on since
+        yaw = float(ed.get("yaw", 0.0))
         world._enter_space_local(
-            e, target, tuple(ed["pos"]), moving=bool(ed.get("moving"))
+            e, target, tuple(ed["pos"]), moving=bool(ed.get("moving")),
+            yaw=yaw,
         )
-        world.stage_pose(e, ed["pos"], float(ed.get("yaw", 0.0)))
+        e._pending_yaw = yaw    # host mirror until the spawn lands
         for tid in world.timers.restore(ed.get("timers", [])):
             e.timer_ids.add(tid)
         e.OnRestored()
@@ -314,32 +334,6 @@ def freeze_to_file(world: World, directory: str = ".") -> str:
     return path
 
 
-def snapshot_candidates(game_id: int, directory: str = ".") -> list[str]:
-    """Existing snapshot files for a game, freshest (by mtime) first:
-    the freeze file (intentional reload), the periodic crash-recovery
-    checkpoint, and the quantized/delta snapshot chain (delta first —
-    it is the newest state; a corrupt or base-mismatched delta raises
-    CorruptSnapshotError and the walk falls back to its keyframe).
-    Mtime orders because any can be stale — a freeze file left over
-    from an old reload must not shadow hours of newer checkpoints
-    after a crash, and vice versa."""
-    cands = []
-    for p in (os.path.join(directory, freeze_filename(game_id)),
-              os.path.join(directory, checkpoint_filename(game_id)),
-              os.path.join(directory, chain_delta_filename(game_id)),
-              os.path.join(directory, chain_key_filename(game_id))):
-        try:
-            cands.append((os.path.getmtime(p), p))
-        except OSError:
-            continue
-    return [p for _, p in sorted(cands, reverse=True)]
-
-
-def latest_snapshot_path(game_id: int, directory: str = ".") -> str | None:
-    cands = snapshot_candidates(game_id, directory)
-    return cands[0] if cands else None
-
-
 def has_restorable_snapshot(game_id: int, directory: str = ".") -> bool:
     """True when at least one snapshot candidate PARSES. The boot path
     decides restore-vs-cold on this, so an all-corrupt snapshot set
@@ -388,10 +382,6 @@ def restore_from_file(world: World, directory: str = ".") -> None:
 # =======================================================================
 # async checkpoint (crash recovery while the world keeps running)
 # =======================================================================
-def checkpoint_filename(game_id: int) -> str:
-    return f"game{game_id}_checkpoint.dat"
-
-
 class CheckpointHandle:
     """Handle to an in-flight async checkpoint: ``join()`` waits, then
     ``path``/``error`` report the outcome."""
@@ -529,14 +519,6 @@ _PLANES = ("pos_xz", "pos_y", "yaw", "moving")
 # yaw wire/plane step: full turn in 2^16 int16 steps (headings are
 # modular, so int16 wraparound IS the mod-2pi wrap)
 YAW_STEP = (2.0 * 3.141592653589793) / 65536.0
-
-
-def chain_key_filename(game_id: int) -> str:
-    return f"game{game_id}_ckpt_key.dat"
-
-
-def chain_delta_filename(game_id: int) -> str:
-    return f"game{game_id}_ckpt_delta.dat"
 
 
 def _crc(b: bytes) -> int:

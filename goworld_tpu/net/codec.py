@@ -18,12 +18,11 @@ Public API (all batch-level):
 from __future__ import annotations
 
 import ctypes
-import os
-import subprocess
 import threading
 
 import numpy as np
 
+from goworld_tpu.net.nativebuild import ensure_built
 from goworld_tpu.utils import log
 
 logger = log.get("codec")
@@ -33,27 +32,9 @@ CLIENT_SYNC_DTYPE = np.dtype(
     [("cid", "S16"), ("eid", "S16"), ("v", "<f4", (4,))]
 )
 
-_NATIVE_DIR = os.path.join(os.path.dirname(__file__), "..", "native")
-_SO_PATH = os.path.abspath(os.path.join(_NATIVE_DIR, "_packet_codec.so"))
 _build_lock = threading.Lock()
 _lib: ctypes.CDLL | None = None
 _lib_tried = False
-
-
-def _build_native() -> bool:
-    src = os.path.join(_NATIVE_DIR, "packet_codec.cpp")
-    if not os.path.exists(src):
-        return False
-    try:
-        subprocess.run(
-            ["g++", "-O3", "-Wall", "-std=c++17", "-fPIC", "-shared",
-             "-o", _SO_PATH, src],
-            check=True, capture_output=True, timeout=120,
-        )
-        return True
-    except (subprocess.SubprocessError, FileNotFoundError) as e:
-        logger.warning("native codec build failed (%s); using numpy path", e)
-        return False
 
 
 def _load() -> ctypes.CDLL | None:
@@ -65,10 +46,11 @@ def _load() -> ctypes.CDLL | None:
         if _lib is not None or _lib_tried:
             return _lib
         _lib_tried = True
-        if not os.path.exists(_SO_PATH) and not _build_native():
+        so = ensure_built("_packet_codec.so", "packet_codec.cpp", logger)
+        if so is None:
             return None
         try:
-            lib = ctypes.CDLL(_SO_PATH)
+            lib = ctypes.CDLL(so)
         except OSError as e:
             logger.warning("native codec load failed (%s)", e)
             return None

@@ -31,7 +31,7 @@ from goworld_tpu.net import codec, proto
 from goworld_tpu.net.cluster import DispatcherCluster, DispatcherConn
 from goworld_tpu.net.packet import Packet, new_packet
 from goworld_tpu.utils import consts, faults, flightrec, log, metrics, \
-    opmon, overload, syncage, tracing
+    opmon, overload, snapfiles, syncage, tracing
 
 logger = log.get("game")
 
@@ -572,7 +572,7 @@ class GameServer:
         # file is written: its atomic rename landing afterwards would
         # give an OLDER-state checkpoint a NEWER mtime, and the
         # -restore boot picks snapshots by mtime
-        # (freeze.latest_snapshot_path)
+        # (snapfiles.latest_snapshot_path)
         deadline = time.monotonic() + 30.0
         while getattr(w, "_ckpt_inflight", False) \
                 and time.monotonic() < deadline:
@@ -588,7 +588,7 @@ class GameServer:
         if w.storage is not None:
             w.storage.shutdown()
         path = os.path.join(
-            self.freeze_dir, _freeze.freeze_filename(w.game_id)
+            self.freeze_dir, snapfiles.freeze_filename(w.game_id)
         )
         if not self._mh_follower():
             _freeze.write_freeze_file(path, data)
@@ -975,7 +975,7 @@ class GameServer:
                 _freeze.write_freeze_file(
                     os.path.join(
                         self.freeze_dir,
-                        _freeze.checkpoint_filename(w.game_id),
+                        snapfiles.checkpoint_filename(w.game_id),
                     ),
                     data,
                 )

@@ -37,12 +37,12 @@ import ctypes
 import os
 import secrets
 import struct
-import subprocess
 import threading
 import time
 from collections import deque
 from typing import Callable
 
+from goworld_tpu.net.nativebuild import ensure_built
 from goworld_tpu.utils import log
 
 logger = log.get("kcp")
@@ -312,10 +312,9 @@ class KcpCore:
 # (and the fallback); sessions pick the native core when the .so builds.
 # GOWORLD_TPU_PURE_KCP=1 forces the Python core.
 
-_NATIVE_DIR = os.path.join(os.path.dirname(__file__), "..", "native")
 # versioned: v2 added kcp_probe/kcp_test_set_serials and the u32
 # serial-wrap fix — a stale v1 .so must not satisfy the lazy build
-_KCP_SO = os.path.abspath(os.path.join(_NATIVE_DIR, "_kcp_core_v2.so"))
+_KCP_SO_NAME = "_kcp_core_v2.so"
 _kcp_lib: ctypes.CDLL | None = None
 _kcp_lib_tried = False
 _kcp_build_lock = threading.Lock()
@@ -331,38 +330,15 @@ def _load_native() -> ctypes.CDLL | None:
         _kcp_lib_tried = True
         if os.environ.get("GOWORLD_TPU_PURE_KCP") == "1":
             return None
-        src = os.path.join(_NATIVE_DIR, "kcp_core.cpp")
-        if not os.path.exists(_KCP_SO):
-            if not os.path.exists(src):
-                return None
-            # build to a temp path and rename into place: a concurrent
-            # or interrupted build must never leave a corrupt .so that
-            # pins every future process to the fallback
-            tmp = f"{_KCP_SO}.{os.getpid()}.tmp"
-            cxx = os.environ.get("CXX", "g++")  # match the Makefile
-            try:
-                subprocess.run(
-                    [cxx, "-O3", "-Wall", "-Wextra", "-std=c++17",
-                     "-fPIC", "-shared", "-o", tmp, src],
-                    check=True, capture_output=True, timeout=120,
-                )
-                os.replace(tmp, _KCP_SO)
-            except (subprocess.SubprocessError, FileNotFoundError,
-                    OSError) as e:
-                logger.warning(
-                    "native kcp build failed (%s); using python core", e
-                )
-                try:
-                    os.unlink(tmp)
-                except OSError:
-                    pass
-                return None
+        so = ensure_built(_KCP_SO_NAME, "kcp_core.cpp", logger)
+        if so is None:
+            return None
         try:
-            lib = ctypes.CDLL(_KCP_SO)
+            lib = ctypes.CDLL(so)
         except OSError as e:
             logger.warning("native kcp load failed (%s)", e)
             try:
-                os.unlink(_KCP_SO)  # let the next process rebuild
+                os.unlink(so)  # let the next process rebuild
             except OSError:
                 pass
             return None

@@ -77,7 +77,7 @@ class Packet:
         # attached syncage.SyncAgeStamp (or None): set by decode_wire on
         # stamped inbound sync batches and by the game's fan-out flush
         # on outbound ones; the dispatcher patches its forward instant
-        # into it before relaying (utils/syncage.py)
+        # into it before forwarding (utils/syncage.py)
         self.age = None
 
     # -- lifecycle -------------------------------------------------------

@@ -84,7 +84,7 @@ MT_SYNC_POSITION_YAW_ON_CLIENTS = 1503  # batched [16B cid + 32B record]
 # client EXACTLY as the per-message path does, so the client wire is
 # unchanged. Cuts game->dispatcher->gate framing from
 # O(client messages) to O(gates) per tick (churn-heavy AOI ticks emit
-# thousands — docs/R5_MEASUREMENTS.md).
+# thousands).
 MT_CLIENT_EVENTS_BATCH = 1504
 # delta-compressed sync fan-out (ISSUE 12, [gameN] sync_delta): same
 # game -> gate leg as 1503, payload = net/codec.py DeltaSyncEncoder

@@ -25,16 +25,13 @@ from __future__ import annotations
 
 import ctypes
 import os
-import subprocess
 import threading
 
+from goworld_tpu.net.nativebuild import ensure_built
 from goworld_tpu.utils import log
 
 logger = log.get("snappy")
 
-_NATIVE_DIR = os.path.join(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))), "native")
-_SO_PATH = os.path.join(_NATIVE_DIR, "_snappy_core.so")
 _build_lock = threading.Lock()
 _lib: ctypes.CDLL | None = None
 _lib_tried = False
@@ -49,32 +46,6 @@ _CHUNK_STREAM_ID = 0xFF
 # 0x02..0x7f are unskippable reserved; 0x80..0xfd skippable padding
 
 
-def _build_native() -> bool:
-    src = os.path.join(_NATIVE_DIR, "snappy_core.cpp")
-    if not os.path.exists(src):
-        return False
-    # build to a tmp path then os.replace (like net/kcp.py): a
-    # concurrent or interrupted build must never leave a corrupt .so
-    # that pins every future process to "unavailable"
-    tmp = f"{_SO_PATH}.{os.getpid()}.tmp"
-    cxx = os.environ.get("CXX", "g++")  # match the Makefile
-    try:
-        subprocess.run(
-            [cxx, "-O3", "-Wall", "-Wextra", "-std=c++17", "-fPIC",
-             "-shared", "-o", tmp, src],
-            check=True, capture_output=True, timeout=120,
-        )
-        os.replace(tmp, _SO_PATH)
-        return True
-    except (subprocess.SubprocessError, FileNotFoundError, OSError) as e:
-        logger.warning("snappy native build failed (%s)", e)
-        try:
-            os.unlink(tmp)
-        except OSError:
-            pass
-        return False
-
-
 def _load() -> ctypes.CDLL | None:
     global _lib, _lib_tried
     if _lib is not None or _lib_tried:
@@ -83,14 +54,15 @@ def _load() -> ctypes.CDLL | None:
         if _lib is not None or _lib_tried:
             return _lib
         _lib_tried = True
-        if not os.path.exists(_SO_PATH) and not _build_native():
+        so = ensure_built("_snappy_core.so", "snappy_core.cpp", logger)
+        if so is None:
             return None
         try:
-            lib = ctypes.CDLL(_SO_PATH)
+            lib = ctypes.CDLL(so)
         except OSError as e:
             logger.warning("snappy native load failed (%s)", e)
             try:
-                os.unlink(_SO_PATH)  # let the next process rebuild
+                os.unlink(so)  # let the next process rebuild
             except OSError:
                 pass
             return None

@@ -57,8 +57,8 @@ def bounded_extract(
 # at this size and the full-cap graph only executes on mass-event ticks
 # (lax.cond picks ONE branch at runtime, unlike where/select).
 # A deploy knob, not a compile-time constant: the 16384 default was
-# sized from the 1M bench's client-row churn (TPU-profile re-derivation
-# still pending — docs/TODO_R5.md); override via the
+# sized from the 1M bench's client-row churn (not re-derived from a
+# chip profile); override via the
 # GOWORLD_SMALL_TIER_ROWS env var or ini [gameN] small_tier_rows
 # (api boot calls set_small_tier_rows BEFORE the world compiles — the
 # value is baked into traced graphs at jit time).

@@ -45,23 +45,26 @@ bodies share that structure (``lowering=``):
   lookups are vector gathers (``fill[keys]``), which jax's interpreter
   executes directly but Mosaic cannot lower (TPU has no vector
   gather/scatter over VMEM).
-* ``"serial"`` — the REAL TPU lowering: bins live as a 2D
-  ``[ceil(bins/128), 128]`` VMEM tile (proper (8, 128) tiling — a
-  ``[bins, 1]`` layout would lane-pad 128x) and the fill walk is a
-  ``fori_loop`` of single-element reads/updates — the scalar-core
-  emulation of what atomicAdd returns on GPUs. The per-element walk
-  subsumes the within-chunk rank (the running counter already counts
-  earlier same-key elements of the chunk), so no [chunk, chunk]
-  triangle compare exists in this body at all. All block specs are
-  real and no interpret flag is involved on TPU; no DMA semaphores are
-  needed because the sequential grid + automatic block pipelining
-  already serialize the scratch reuse. The same body passes
-  interpret-mode parity on CPU (tests/test_sort.py), so hardware runs
-  exercise a CPU-validated algorithm.
+* ``"serial"`` — the TPU lowering: the per-bin ``starts``/``fill``
+  tables, the chunk's keys and its destinations all live in SMEM as
+  1-D i32 arrays, and the fill walk is a ``fori_loop`` of
+  single-element reads/updates on the scalar core — the emulation of
+  what atomicAdd returns on GPUs. (Mosaic refuses the same walk over
+  VMEM tiles: "Cannot store scalars to VMEM"; the v5e compile of this
+  body at the 131,072 shard is held by tests/test_tpu_compile.py.) The
+  per-element walk subsumes the within-chunk rank (the running counter
+  already counts earlier same-key elements of the chunk), so no
+  [chunk, chunk] triangle compare exists in this body at all. The bin
+  tables must fit SMEM — the compiler refuses a bin space that does
+  not, loudly, at compile time. No DMA semaphores are needed because
+  the sequential grid + automatic block pipelining already serialize
+  the scratch reuse. The same body passes interpret-mode parity on CPU
+  (tests/test_sort.py), so hardware runs exercise a CPU-validated
+  algorithm.
 
-Off-TPU, selecting the pallas impl falls back to interpret mode with a
+Off-TPU, selecting the pallas impl runs in interpret mode with a
 one-time warning (:mod:`goworld_tpu.ops.pallas_compat`) instead of
-failing at trace time.
+failing at trace time; on a TPU backend interpret mode is refused.
 """
 
 from __future__ import annotations
@@ -149,11 +152,6 @@ def counting_sort_cells(
 
 # ---------------------------------------------------------------- pallas ----
 
-# bins per VMEM lane row of the serial kernel's 2D fill/starts tiles
-_BIN_LANES = 128
-_BIN_SHIFT = _BIN_LANES.bit_length() - 1   # log2: bin b -> row b >> SHIFT
-
-
 def counting_sort_cells_pallas(
     srow: jax.Array,
     n_rows: int,
@@ -167,11 +165,11 @@ def counting_sort_cells_pallas(
     the running per-bin histogram across grid steps — the same
     loop-carried state the XLA path threads through ``lax.scan``.
 
-    ``interpret=None`` resolves via
-    :func:`goworld_tpu.ops.pallas_compat.interpret_default`: hardware
-    lowering on TPU, interpret mode (with a one-time warning) anywhere
-    else — never a trace-time failure. ``lowering`` picks the kernel
-    body (module docstring): ``"auto"`` = the ``"serial"`` TPU lowering
+    ``interpret`` resolves via
+    :func:`goworld_tpu.ops.pallas_compat.resolve_interpret`: hardware
+    lowering on TPU (asking for interpret mode there raises), interpret
+    mode (with a one-time warning) anywhere else. ``lowering`` picks
+    the kernel body (module docstring): ``"auto"`` = the ``"serial"`` TPU lowering
     when compiling for hardware, the ``"vector"`` gather form under
     interpret (the interpreter executes vector gathers directly and far
     faster than a serial loop); both are explicitly selectable so tests
@@ -181,10 +179,9 @@ def counting_sort_cells_pallas(
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    from goworld_tpu.ops.pallas_compat import interpret_default
+    from goworld_tpu.ops.pallas_compat import resolve_interpret
 
-    if interpret is None:
-        interpret = interpret_default("counting_sort_fill")
+    interpret = resolve_interpret("counting_sort_fill", interpret)
     if lowering not in ("auto", "serial", "vector"):
         raise ValueError(
             f"lowering must be auto|serial|vector, got {lowering!r}"
@@ -196,19 +193,14 @@ def counting_sort_cells_pallas(
     keys_c, c, nb = _chunk_keys(srow, n_rows, chunk)
 
     if lowering == "serial":
-        # 2D-tiled bins: [nrp, _BIN_LANES] i32 keeps the (8, 128) VMEM
-        # tiling dense; bin b lives at (b >> _BIN_SHIFT, b & LANES-1)
-        nrp = -(-(n_rows + 1) // _BIN_LANES)
-        starts2 = jnp.concatenate(
-            [starts,
-             jnp.zeros(nrp * _BIN_LANES - (n_rows + 1), jnp.int32)]
-        ).reshape(nrp, _BIN_LANES)
-        keys3 = keys_c.reshape(nb, c, 1)
-
         def kernel(starts_ref, keys_ref, dst_ref, fill_ref):
             @pl.when(pl.program_id(0) == 0)
             def _init():
-                fill_ref[...] = jnp.zeros((nrp, _BIN_LANES), jnp.int32)
+                def zero(b, _):
+                    fill_ref[b] = 0
+                    return 0
+
+                lax.fori_loop(0, n_rows + 1, zero, 0)
 
             # the element-wise fill walk IS the stable rank: the running
             # per-bin counter already counts earlier same-key elements
@@ -216,12 +208,10 @@ def counting_sort_cells_pallas(
             # advances per chunk and needs the [c, c] triangle rank on
             # top) — exactly what atomicAdd returns on GPUs
             def body(i, _):
-                key = keys_ref[0, i, 0]
-                bs = key >> _BIN_SHIFT
-                bl = key & (_BIN_LANES - 1)
-                f = fill_ref[bs, bl]
-                dst_ref[0, i, 0] = starts_ref[bs, bl] + f
-                fill_ref[bs, bl] = f + 1
+                key = keys_ref[i]
+                f = fill_ref[key]
+                dst_ref[i] = starts_ref[key] + f
+                fill_ref[key] = f + 1
                 return 0
 
             lax.fori_loop(0, c, body, 0)
@@ -230,17 +220,17 @@ def counting_sort_cells_pallas(
             kernel,
             grid=(nb,),
             in_specs=[
-                pl.BlockSpec((nrp, _BIN_LANES), lambda i: (0, 0)),
-                pl.BlockSpec((1, c, 1), lambda i: (i, 0, 0)),
+                pl.BlockSpec(memory_space=pltpu.SMEM),
+                pl.BlockSpec((c,), lambda i: (i,),
+                             memory_space=pltpu.SMEM),
             ],
-            out_specs=pl.BlockSpec((1, c, 1), lambda i: (i, 0, 0)),
-            out_shape=jax.ShapeDtypeStruct((nb, c, 1), jnp.int32),
-            scratch_shapes=[
-                pltpu.VMEM((nrp, _BIN_LANES), jnp.int32),
-            ],
+            out_specs=pl.BlockSpec((c,), lambda i: (i,),
+                                   memory_space=pltpu.SMEM),
+            out_shape=jax.ShapeDtypeStruct((nb * c,), jnp.int32),
+            scratch_shapes=[pltpu.SMEM((n_rows + 1,), jnp.int32)],
             interpret=interpret,
-        )(starts2, keys3)
-        return _finish(srow, dst.reshape(-1), n)
+        )(starts, keys_c.reshape(-1))
+        return _finish(srow, dst, n)
 
     def kernel(starts_ref, keys_ref, dst_ref, fill_ref):
         @pl.when(pl.program_id(0) == 0)

@@ -31,7 +31,7 @@ Two shipping impls (``halo_impl`` knob on :class:`MegaConfig`):
   of the 5-lane ppermute path in the modeled ICI budget
   (``devprof.roofline_model_bytes_multichip``). Off-TPU the kernel
   runs in interpret mode behind
-  :func:`goworld_tpu.ops.pallas_compat.interpret_default` (loud
+  :func:`goworld_tpu.ops.pallas_compat.resolve_interpret` (loud
   one-time warning, never a CPU default).
 
 Both impls are bit-identical: same ghost blocks, same demand gauges
@@ -99,7 +99,7 @@ def _async_ship(axis: str, n_dev: int, shift: int, buf: jax.Array,
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    from goworld_tpu.ops.pallas_compat import interpret_default
+    from goworld_tpu.ops.pallas_compat import resolve_interpret
 
     def kernel(in_ref, out_ref, send_sem, recv_sem):
         my_id = lax.axis_index(axis)
@@ -115,15 +115,15 @@ def _async_ship(axis: str, n_dev: int, shift: int, buf: jax.Array,
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=0,
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],
-        out_specs=pl.BlockSpec(memory_space=pltpu.ANY),
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
         scratch_shapes=[pltpu.SemaphoreType.DMA] * 2,
     )
     out = pl.pallas_call(
         kernel,
         out_shape=jax.ShapeDtypeStruct(buf.shape, buf.dtype),
         grid_spec=grid_spec,
-        interpret=interpret_default("halo_async"),
+        interpret=resolve_interpret("halo_async"),
     )(buf)
     return jnp.where(recv_ok, out, 0)
 

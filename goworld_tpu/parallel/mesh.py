@@ -19,25 +19,14 @@ from goworld_tpu.core.state import SpaceState, WorldConfig, create_state
 
 SPACE_AXIS = "space"
 
-# shard_map moved from jax.experimental to the jax namespace across
-# the supported versions; resolve ONCE here so every mesh program
-# (parallel/step.py, parallel/megaspace.py) builds on either
-if hasattr(jax, "shard_map"):
-    shard_map = jax.shard_map
-else:  # jax <= 0.4.x
-    from jax.experimental.shard_map import shard_map  # noqa: F401
+shard_map = jax.shard_map
 
 
 def shard_map_norep(fn, **kw):
-    """shard_map with the replication check OFF — required wherever the
-    shard body contains a ``pallas_call`` (no replication rule, e.g.
-    the async halo). The knob name changed across jax versions
-    (check_rep -> check_vma); keep that dance HERE, next to the
-    shard_map resolver, so callers never hand-roll it."""
-    try:
-        return shard_map(fn, check_rep=False, **kw)
-    except TypeError:
-        return shard_map(fn, check_vma=False, **kw)
+    """shard_map with the varying-manual-axes check OFF — required
+    wherever the shard body contains a ``pallas_call`` (no replication
+    rule, e.g. the async halo)."""
+    return jax.shard_map(fn, check_vma=False, **kw)
 
 
 def make_mesh(n_devices: int | None = None) -> Mesh:

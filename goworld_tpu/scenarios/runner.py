@@ -306,7 +306,7 @@ def run_scenario(
 
 
 # ----------------------------------------------------------------------
-# device-only position advance (tools/tpu_ab.py --workload + hotspot row)
+# device-only position advance (the scenario benches' layout helper)
 # ----------------------------------------------------------------------
 
 def scenario_layout(
@@ -334,7 +334,11 @@ def scenario_layout(
     import jax.numpy as jnp
     from jax import lax
 
-    from goworld_tpu.core.state import WorldConfig, create_state
+    from goworld_tpu.core.state import (
+        WorldConfig,
+        create_state,
+        seed_key,
+    )
     from goworld_tpu.ops.aoi import GridSpec
     from goworld_tpu.scenarios.behaviors import scenario_velocity
 
@@ -352,7 +356,7 @@ def scenario_layout(
         scenario=spec,
     )
     st = create_state(cfg, seed=seed)
-    k1, k2 = jax.random.split(jax.random.PRNGKey(seed), 2)
+    k1, k2 = jax.random.split(seed_key(seed), 2)
     pos0 = jnp.stack([
         jax.random.uniform(k1, (n,), maxval=extent),
         jnp.zeros(n),

@@ -656,11 +656,12 @@ class AuditPlane:
         (per-plane CRCs); here that refusal becomes a named violation
         instead of a surprise at the next ``-restore`` boot."""
         from goworld_tpu import freeze as _freeze
+        from goworld_tpu.utils import snapfiles
 
         files = [
-            os.path.join(directory, _freeze.chain_key_filename(game_id)),
+            os.path.join(directory, snapfiles.chain_key_filename(game_id)),
             os.path.join(directory,
-                         _freeze.chain_delta_filename(game_id)),
+                         snapfiles.chain_delta_filename(game_id)),
         ]
         walked = corrupt = 0
         err = None

@@ -189,3 +189,37 @@ def test_big_grid_argsort_path_matches_oracle():
     for i in range(n):
         got = set(nbr[i][nbr[i] < n].tolist())
         assert got == (oracle[i] if alive[i] else set()), i
+
+
+def test_candidate_planes_are_int32():
+    """The sorted view and the cell table carry positions as f32 BITS
+    inside int32 planes, never slot words as int bits inside f32
+    planes: a small int viewed as f32 is a subnormal, and the TPU
+    flushes subnormals to zero (PR 21's first chip run: every
+    neighbour list collapsed to slot 0 — invisible on the CPU, which
+    keeps the bits; chip_smoke.py holds the result on the chip)."""
+    import jax
+    import jax.numpy as jnp
+
+    from goworld_tpu.ops import aoi
+
+    n = 64
+    spec = GridSpec(radius=10.0, extent_x=100.0, extent_z=100.0,
+                    k=8, cell_cap=4)
+    rng = np.random.default_rng(3)
+    pos = jnp.asarray(rng.uniform(0, 100, (n, 3)).astype(np.float32))
+    alive = jnp.ones((n,), bool)
+    _cx, _cz, srow, _al, _czp, n_rows = aoi._cell_rows(
+        spec, pos, alive, None)
+    order, sorted_row = aoi._sort_cells(n, n_rows, srow)
+    src, _sentinel, empty = aoi._sorted_src(
+        spec, pos, jnp.zeros((n,), jnp.int32), order)
+    _rs, s_t = aoi._build_ranges(4, n_rows, srow, src, empty)
+    table = aoi._build_table(4, n_rows, sorted_row, src, empty)
+    assert src.dtype == s_t.dtype == table.dtype == jnp.int32
+    # the coordinates survive the round trip bit for bit
+    back = np.asarray(jax.lax.bitcast_convert_type(src[:, 0],
+                                                   jnp.float32))
+    assert np.array_equal(back, np.asarray(pos[:, 0])[np.asarray(order)])
+    # ... and the words are the slot ids (flags 0 here), as plain ints
+    assert np.array_equal(np.asarray(src[:, 2]) >> 2, np.asarray(order))

@@ -25,7 +25,7 @@ from goworld_tpu.ops.aoi import (
 )
 
 # the fused rows run the Pallas kernel in interpret mode on CPU — part
-# of the kernel-parity set the `pallas` marker selects around a relay
+# of the kernel-parity set the `pallas` marker selects
 FUSED = pytest.param("fused", marks=pytest.mark.pallas)
 
 N = 600

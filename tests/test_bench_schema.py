@@ -1,9 +1,11 @@
 """tools/bench_schema.py — artifact schema validation, in tier-1.
 
-Every CHECKED-IN BENCH_r*/MULTICHIP_r* artifact must validate (so a
-malformed stamp can never land again), and the checker must actually
-catch malformation (required keys, device-plane blocks since r8,
-multichip invariants).
+Every artifact of a BENCH_r*/MULTICHIP_r* trajectory must validate (so
+a malformed stamp can never land), and the checker must actually catch
+malformation (required keys, device-plane blocks since r8, multichip
+invariants). The repository keeps no artifacts of its own any more
+(the old ones were CPU numbers under device-metric names): the
+trajectory these tests walk is synthesised per test.
 """
 
 import importlib.util
@@ -23,17 +25,40 @@ SCHEMA = importlib.util.module_from_spec(spec)
 spec.loader.exec_module(SCHEMA)
 
 
-def test_every_checked_in_artifact_validates():
-    files = sorted(glob.glob(os.path.join(REPO, "BENCH_r*.json"))
-                   + glob.glob(os.path.join(REPO, "MULTICHIP_r*.json")))
-    assert files, "no artifacts in the repo root?"
+def _trajectory(tmp_path):
+    """A small valid trajectory in a directory of its own: two
+    single-chip rounds and one measured-mesh round."""
+    d = tmp_path / "trajectory"
+    d.mkdir()
+    for name, rec in (("BENCH_r08.json", _full_rec()),
+                      ("BENCH_r09.json", _full_rec(value=120.0)),
+                      ("MULTICHIP_r10.json", _multi_rec())):
+        (d / name).write_text(json.dumps(rec))
+    return str(d)
+
+
+def test_every_checked_in_artifact_validates(tmp_path):
+    d = _trajectory(tmp_path)
+    files = sorted(glob.glob(os.path.join(d, "BENCH_r*.json"))
+                   + glob.glob(os.path.join(d, "MULTICHIP_r*.json")))
+    assert len(files) == 3
     problems = {os.path.basename(f): SCHEMA.validate_file(f)
                 for f in files}
     assert all(not errs for errs in problems.values()), problems
 
 
-def test_cli_passes_on_repo(capsys):
-    assert SCHEMA.main(["--dir", REPO]) == 0
+def test_cli_passes_on_repo(tmp_path, capsys):
+    assert SCHEMA.main(["--dir", _trajectory(tmp_path)]) == 0
+    # an empty directory has nothing to violate; a NAMED missing file
+    # is a usage error
+    empty = tmp_path / "empty"
+    empty.mkdir()
+    assert SCHEMA.main(["--dir", str(empty)]) == 0
+    assert SCHEMA.main([str(empty / "BENCH_r01.json")]) == 1
+    # a malformed artifact in the walked directory turns the CLI red
+    bad = tmp_path / "trajectory" / "BENCH_r11.json"
+    bad.write_text(json.dumps({"value": 1.0}))
+    assert SCHEMA.main(["--dir", str(tmp_path / "trajectory")]) != 0
 
 
 def _full_rec(rno=8, **extra):
@@ -90,8 +115,8 @@ def test_honest_error_blocks_accepted(tmp_path):
 
 def test_deliberate_skip_blocks_accepted(tmp_path):
     """BENCH_DEVPROF=0 / BENCH_SLO=0 / BENCH_PHASES=0 runs stamp
-    {"skipped": ...} records — a documented thinner run (e.g. a relay
-    window avoiding the extra compiles) must stay schema-valid."""
+    {"skipped": ...} records — a documented thinner run (e.g. a chip
+    run avoiding the extra compiles) must stay schema-valid."""
     rec = _full_rec(slo={"skipped": "BENCH_SLO=0"},
                     roofline_audit={"skipped": "BENCH_DEVPROF=0"},
                     op_stats={"skipped": "BENCH_SLO=0"})

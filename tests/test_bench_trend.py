@@ -1,9 +1,10 @@
 """tools/bench_trend.py — the trajectory regression gate, in tier-1.
 
-The real checked-in BENCH_r*/MULTICHIP_r* trajectory must PASS (the
-gate runs after every round; a red gate on the committed history would
-make it dead on arrival), an injected regression must FAIL, and a
-missing file is a usage error, not a silent pass.
+A healthy BENCH_r*/MULTICHIP_r* trajectory walked by directory must
+PASS, an injected regression must FAIL, and a missing file is a usage
+error, not a silent pass. The repository keeps no artifacts of its own
+any more (the old ones were CPU numbers under device-metric names), so
+every trajectory here is synthesised.
 """
 
 import importlib.util
@@ -50,8 +51,14 @@ def _write(tmp_path, name, rec):
     return str(p)
 
 
-def test_real_checked_in_trajectory_passes():
-    assert TREND.main(["--dir", REPO]) == 0
+def test_real_checked_in_trajectory_passes(tmp_path):
+    """--dir walks a whole trajectory (single-chip and mesh rounds
+    side by side) and passes a healthy one."""
+    _write(tmp_path, "BENCH_r01.json", _bench_rec(1000.0))
+    _write(tmp_path, "BENCH_r02.json", _bench_rec(1400.0, tick_ms=7.0))
+    _write(tmp_path, "MULTICHIP_r10.json", _multi_rec(100000.0))
+    _write(tmp_path, "MULTICHIP_r11.json", _multi_rec(110000.0))
+    assert TREND.main(["--dir", str(tmp_path)]) == 0
 
 
 def test_missing_file_is_an_error(capsys):

@@ -29,13 +29,36 @@ def test_gate_order_is_the_documented_chain():
     assert GATE.GATES == ("obs_lint", "bench_schema", "bench_trend")
 
 
-def test_real_repo_is_green(capsys):
-    assert GATE.main([]) == 0
-    out = capsys.readouterr().out
-    # every gate actually ran (no silent skip) and the verdict printed
-    for name in GATE.GATES:
-        assert f"== {name} ==" in out
-    assert "ci_gate: ok (3 gates green)" in out
+def _artifact(value: float) -> dict:
+    return {
+        "metric": "entity_ticks_per_sec_per_chip", "value": value,
+        "unit": "entity-ticks/s/chip", "vs_baseline": 0.0,
+        "entities": 1024, "tick_ms": 5.0, "platform": "tpu",
+        "stage": "full", "attempts": [],
+    }
+
+
+def test_real_repo_is_green(tmp_path, capsys):
+    """The real docs + a trajectory the test synthesises (the
+    repository keeps no artifacts of its own): all three gates run and
+    are green; with no --dir the bench gates walk the empty repo root
+    and have nothing to object to."""
+    import json
+
+    for rno, value in ((1, 1000.0), (2, 1200.0)):
+        (tmp_path / f"BENCH_r{rno:02d}.json").write_text(
+            json.dumps(_artifact(value)))
+    for argv in (["--dir", str(tmp_path)], []):
+        assert GATE.main(argv) == 0
+        out = capsys.readouterr().out
+        # every gate actually ran (no silent skip), verdict printed
+        for name in GATE.GATES:
+            assert f"== {name} ==" in out
+        assert "ci_gate: ok (3 gates green)" in out
+    # ... and a regression in the walked trajectory turns it red
+    (tmp_path / "BENCH_r03.json").write_text(
+        json.dumps(_artifact(300.0)))
+    assert GATE.main(["--dir", str(tmp_path)]) == 2
 
 
 def test_threshold_is_forwarded_to_bench_trend_only(monkeypatch):
@@ -55,6 +78,10 @@ def test_threshold_is_forwarded_to_bench_trend_only(monkeypatch):
     assert seen["obs_lint"] == []
     assert seen["bench_schema"] == []
     assert seen["bench_trend"] == ["--threshold", "0.25"]
+    assert GATE.main(["--dir", "/x", "--threshold", "0.25"]) == 0
+    assert seen["obs_lint"] == []
+    assert seen["bench_schema"] == ["--dir", "/x"]
+    assert seen["bench_trend"] == ["--dir", "/x", "--threshold", "0.25"]
 
 
 def test_one_failing_gate_fails_the_chain(monkeypatch, capsys):

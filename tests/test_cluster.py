@@ -103,7 +103,17 @@ def test_login_creates_boot_entity_and_avatar(cluster):
     host, port = harness.gate_addrs[0]
     bot = BotClient(host, port, strict=True)
 
-    done = harness.submit(_bot_login_script(bot))
+    # the script closes the bot's connection when it ends, and the game
+    # then detaches the client (test_client_disconnect_detaches_entity):
+    # look at the server-side ownership while the bot is still connected
+    owned: list[bool] = []
+
+    def look() -> None:
+        owned.extend(e.client is not None
+                     for e in world.entities.values()
+                     if e.type_name == "Avatar" and not e.destroyed)
+
+    done = harness.submit(_bot_login_script(bot, while_connected=look))
     done.result(timeout=30)
 
     assert not bot.errors, bot.errors
@@ -112,13 +122,10 @@ def test_login_creates_boot_entity_and_avatar(cluster):
     assert bot.player.type_name == "Avatar"
     assert bot.player.attrs.get("name") == "bob"
     # the server-side avatar exists and owns the client
-    avatars = [e for e in world.entities.values()
-               if e.type_name == "Avatar" and not e.destroyed]
-    assert len(avatars) == 1
-    assert avatars[0].client is not None
+    assert owned == [True]
 
 
-async def _bot_login_script(bot: BotClient):
+async def _bot_login_script(bot: BotClient, while_connected=None):
     import asyncio
 
     await bot.connect()
@@ -144,6 +151,8 @@ async def _bot_login_script(bot: BotClient):
             if bot.player.attrs.get("name") == "bob":
                 break
             await asyncio.sleep(0.05)
+        if while_connected is not None:
+            while_connected()
     finally:
         recv.cancel()
         await bot.conn.close()

@@ -489,12 +489,26 @@ def test_roofline_audit_block_shape():
              "move": {"bytes_accessed": 2e6},
              "collect": {"bytes_accessed": 3e6}}
     block = devprof.roofline_audit(phase_ms, costs, 4096, kw,
-                                   platform="cpu")
+                                   platform="tpu",
+                                   device_kind="TPU v5 lite")
     assert block["doc"] == "docs/ROOFLINE.md" and block["n"] == 4096
     aoi = block["phases"]["aoi"]
     assert aoi["measured_ms"] == 10.0
     assert aoi["xla_mb"] == 5.0
-    assert "drift_pct" in aoi and "model_ms_v5e" in aoi
+    assert "drift_pct" in aoi
+    # the time projection is priced against the NAMED device's peak
+    assert block["bandwidth_gbps"] == 819.0
+    assert aoi["model_ms"] == round(
+        aoi["model_mb"] * 1e6 / (819.0 * 1e6), 4)
+    # no device named: bytes only, no time under any chip's name
+    bare = devprof.roofline_audit(phase_ms, costs, 4096, kw,
+                                  platform="cpu")
+    assert "model_ms" not in bare["phases"]["aoi"]
+    assert bare["bandwidth_gbps"] is None
+    # an unlisted device is an error, not a default
+    with pytest.raises(ValueError, match="no published peaks"):
+        devprof.roofline_audit(phase_ms, costs, 4096, kw,
+                               device_kind="TPU v99")
     assert block["phases"]["move"]["xla_mb"] == 2.0
     assert "total_drift_pct" in block
 

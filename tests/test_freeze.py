@@ -15,6 +15,7 @@ from goworld_tpu.entity import Entity, GameClient, Space, World
 from goworld_tpu.net.game import GameServer
 from goworld_tpu.net.standalone import ClusterHarness
 from goworld_tpu.ops.aoi import GridSpec
+from goworld_tpu.utils import snapfiles
 
 
 class Npc(Entity):
@@ -104,6 +105,110 @@ class TestFreezeRoundtrip:
             time.sleep(0.01)
         assert b2.heal_count >= 1
         assert b2.attrs.get("hp") >= 60
+
+    @pytest.mark.parametrize("enter_cap", [4096, 2])
+    def test_reload_settles_what_a_connected_client_holds(self, enter_cap):
+        """Every enter a client got still gets its leave across a
+        reload: the freeze carries what the client was told, and the
+        first restored tick destroys what is out of range by then and
+        creates what is new — also when that tick's enter flood is cut
+        at ``enter_cap`` (2: most of the avatar's pairs are dropped)."""
+        def world():
+            w = World(WorldConfig(
+                capacity=64, input_cap=64, enter_cap=enter_cap,
+                grid=GridSpec(radius=30.0, extent_x=120.0,
+                              extent_z=120.0)), n_spaces=1)
+            _register(w)
+            w.create_nil_space()
+            return w
+
+        w = world()
+        arena = w.create_space("Arena")
+        av = w.create_entity("Npc", space=arena, pos=(60.0, 0.0, 60.0))
+        # one at a time, so no tick's enters pass the cap before the
+        # freeze: the client is told of every one of them
+        stayers = []
+        for i in range(5):
+            stayers.append(w.create_entity(
+                "Npc", space=arena, pos=(55.0 + 2 * i, 0.0, 62.0)))
+            w.tick()
+        leaver = w.create_entity("Npc", space=arena, pos=(70.0, 0.0, 70.0))
+        w.tick()
+        comer = w.create_entity("Npc", space=arena, pos=(5.0, 0.0, 5.0))
+        w.tick()
+        av.client = GameClient(2, "c" * 16, w)
+        told = {e.id for e in stayers} | {leaver.id}
+        assert av.interested_in == told
+
+        data = freeze.freeze_world(w)
+        assert freeze.freeze_world(w, run_hooks=False)["entities"][0] \
+            .get("interest") is None       # a checkpoint carries none
+        recs = {ed["id"]: ed for ed in data["entities"]}
+        assert set(recs[av.id]["interest"]) == told
+        assert "interest" not in recs[leaver.id]    # no client, no list
+        # by the first restored tick one neighbour has walked out and
+        # a stranger has walked in
+        recs[leaver.id]["pos"] = [5.0, 0.0, 110.0]
+        recs[comer.id]["pos"] = [58.0, 0.0, 58.0]
+
+        w2 = world()
+        sent = []
+        w2.client_sink = lambda gate, cid, msg: sent.append(msg)
+        freeze.restore_world(w2, data)
+        w2.tick()
+        av2 = w2.entities[av.id]
+        now = {e.id for e in stayers} | {comer.id}
+        assert av2.interested_in == now
+        assert all(av.id in w2.entities[j].interested_by for j in now)
+        destroyed = [m["eid"] for m in sent if m["type"] == "destroy_entity"]
+        created = [m["eid"] for m in sent if m["type"] == "create_entity"]
+        assert destroyed == [leaver.id]
+        assert created.count(comer.id) == 1
+        assert leaver.id not in created
+        assert w2._restored_interest is None
+        # ... and a mirror the client kept still gets its leave later
+        sent.clear()
+        w2.entities[stayers[0].id].set_position((5.0, 0.0, 5.0))
+        w2.tick()
+        w2.tick()
+        assert [m["eid"] for m in sent
+                if m["type"] == "destroy_entity"] == [stayers[0].id]
+
+    def test_restored_poses_ride_the_spawn(self):
+        """More restored entities than ``input_cap``: every pose lands
+        with its spawn on the first tick — none waits in the pos-sync
+        queue to rewind, ticks later, a row the device moved on from."""
+        def world():
+            w = World(WorldConfig(
+                capacity=64, input_cap=4,
+                grid=GridSpec(radius=30.0, extent_x=120.0,
+                              extent_z=120.0)), n_spaces=1)
+            _register(w)
+            w.create_nil_space()
+            return w
+
+        w = world()
+        arena = w.create_space("Arena")
+        ents = [w.create_entity("Npc", space=arena,
+                                pos=(5.0 + 7 * i, 0.0, 9.0 + 6 * i))
+                for i in range(16)]
+        w.tick()
+        for i, e in enumerate(ents):
+            e.set_yaw(0.1 * (i + 1))
+        for _ in range(5):          # 16 yaws through input_cap = 4
+            w.tick()
+        data = freeze.freeze_world(w)
+
+        w2 = world()
+        freeze.restore_world(w2, data)
+        w2.tick()
+        assert not w2._staged_pos
+        for i, e in enumerate(ents):
+            e2 = w2.entities[e.id]
+            assert e2._pending_pos is None and e2._pending_yaw is None
+            assert w2.read_yaw(0, e2.slot) == pytest.approx(0.1 * (i + 1))
+            assert tuple(w2.read_pos(0, e2.slot)) == pytest.approx(
+                (5.0 + 7 * i, 0.0, 9.0 + 6 * i))
 
     def test_file_roundtrip(self, tmp_path):
         w = _make_world()
@@ -317,17 +422,17 @@ class TestSnapshotCorruption:
         e, data = self._frozen()
         # older but VALID checkpoint...
         freeze.write_freeze_file(
-            str(tmp_path / freeze.checkpoint_filename(1)), data)
+            str(tmp_path / snapfiles.checkpoint_filename(1)), data)
         # ...shadowed by a newer TRUNCATED freeze file (simulated crash
         # of a non-atomic writer / disk fault)
         blob = msgpack.packb(data, use_bin_type=True)
-        fz = tmp_path / freeze.freeze_filename(1)
+        fz = tmp_path / snapfiles.freeze_filename(1)
         fz.write_bytes(blob[: len(blob) // 2])
         later = time.time() + 5
         import os
         os.utime(str(fz), (later, later))
 
-        assert freeze.latest_snapshot_path(1, str(tmp_path)) \
+        assert snapfiles.latest_snapshot_path(1, str(tmp_path)) \
             == str(fz)                      # mtime says the corrupt one
         w2 = _make_world()
         freeze.restore_from_file(w2, str(tmp_path))   # ...but it falls back
@@ -340,7 +445,7 @@ class TestSnapshotCorruption:
 
         _e, data = self._frozen()
         blob = msgpack.packb(data, use_bin_type=True)
-        (tmp_path / freeze.freeze_filename(1)).write_bytes(blob[:40])
+        (tmp_path / snapfiles.freeze_filename(1)).write_bytes(blob[:40])
         assert not freeze.has_restorable_snapshot(1, str(tmp_path))
         w2 = _make_world()
         with pytest.raises(freeze.CorruptSnapshotError):
@@ -351,11 +456,11 @@ class TestSnapshotCorruption:
     def test_parseable_but_wrong_shape_rejected(self, tmp_path):
         import msgpack
 
-        (tmp_path / freeze.freeze_filename(1)).write_bytes(
+        (tmp_path / snapfiles.freeze_filename(1)).write_bytes(
             msgpack.packb(["not", "a", "freeze"], use_bin_type=True))
         with pytest.raises(freeze.CorruptSnapshotError):
             freeze.read_freeze_file(
-                str(tmp_path / freeze.freeze_filename(1)))
+                str(tmp_path / snapfiles.freeze_filename(1)))
 
     def test_crash_mid_freeze_leaves_only_tmp(self, tmp_path):
         """Injected crash between the tmp write and the atomic rename
@@ -369,7 +474,7 @@ class TestSnapshotCorruption:
         from goworld_tpu.utils import faults as faults_mod
 
         repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-        target = str(tmp_path / freeze.freeze_filename(1))
+        target = str(tmp_path / snapfiles.freeze_filename(1))
         env = dict(os.environ)
         env["PYTHONPATH"] = repo
         env["JAX_PLATFORMS"] = "cpu"

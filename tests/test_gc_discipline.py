@@ -2,8 +2,7 @@
 
 The game logic loop freezes boot-time objects out of the cyclic GC
 (net/game.py serve_forever, ini gc_freeze) so gen-2 collections stop
-walking the whole world (~100 ms at a 131K shard —
-docs/R5_MEASUREMENTS.md). Frozen objects can then ONLY be reclaimed by
+walking the whole world (~100 ms at a 131K shard, host clock). Frozen objects can then ONLY be reclaimed by
 refcounting, so a destroyed entity must not sit in a reference cycle:
 destroy_entity severs the attr tree's back-references (attrs.sever_tree
 — the root journal closure holds the entity, and every nested node

@@ -4,7 +4,7 @@ impl — ghost blocks AND demand gauges — for 1D strips and 2D tiles,
 across dirty/visible permutations and halo_cap overflow (ISSUE 10).
 
 Off-TPU the async kernel runs in interpret mode behind
-ops/pallas_compat.interpret_default (one-time warning, never a CPU
+ops/pallas_compat.resolve_interpret (one-time warning, never a CPU
 default) — exactly the configuration tier-1 exercises here.
 """
 

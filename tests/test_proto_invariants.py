@@ -82,7 +82,7 @@ def test_range_markers_bracket_their_constants():
         if "START" in name or "STOP" in name:
             continue
         if redirect_lo < val < redirect_hi:
-            # gate relays these verbatim to the owning client — they
+            # gate forwards these verbatim to the owning client — they
             # must carry the [gate_id][client_id] routing prefix, which
             # only redirect-range pack helpers write
             assert name.endswith("_ON_CLIENT") or name in (

@@ -1,5 +1,5 @@
 """Hot-standby replication (ISSUE 18): wire-frame CRC chaining and the
-torn-stream taxonomy (truncated / corrupted / reordered / replayed
+torn-stream classes (truncated / corrupted / reordered / replayed
 frames rejected WHOLE, stream self-heals at the next keyframe),
 double-apply lattice-plane determinism, the bounded replication
 worker's never-block-the-tick contract (slow disk -> loud drops +

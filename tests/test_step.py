@@ -134,3 +134,25 @@ def test_random_walk_stays_in_bounds():
     pos = np.asarray(st.pos[:16])
     assert (pos[:, 0] >= 0).all() and (pos[:, 0] <= 100.0).all()
     assert (pos[:, 2] >= 0).all() and (pos[:, 2] <= 100.0).all()
+
+
+# first words of the stream a seed draws (threefry2x32, partitionable —
+# core/state.seed_key). chip_smoke.py checks the same words on the chip.
+SEED7_SPLIT1 = (195045567, 4062205631)
+SEED7_BITS = (2899676959, 3548400998, 1692160380, 1822441453)
+
+
+def test_seed_stream_is_pinned():
+    """``--seed`` must mean one world: the key derivation is one
+    helper and its stream is pinned, so a JAX default that changes it
+    (as jax_threefry_partitionable did between 0.4 and 0.5) fails HERE
+    instead of shifting every seeded world quietly."""
+    from goworld_tpu.core.state import seed_key
+
+    _, k = jax.random.split(seed_key(7))
+    assert tuple(np.asarray(k).tolist()) == SEED7_SPLIT1
+    assert tuple(np.asarray(
+        jax.random.bits(k, (4,))).tolist()) == SEED7_BITS
+    cfg = small_cfg()
+    assert np.array_equal(np.asarray(create_state(cfg, seed=7).rng),
+                          np.asarray(seed_key(7)))

@@ -63,14 +63,15 @@ def test_live_fold_zero_sync_under_transfer_guard():
     for _ in range(3):
         w.tick()  # trace both executables first
     inputs = w._flush_staging()  # host->device, outside the guard
+    # the resident fold DONATES its accumulator (the carry is deleted
+    # by the call): read the before-count first, outside the guard
+    reb_before = int(np.asarray(w._telem_acc["rebuilt"]).sum())
     with jax.transfer_guard("disallow"):
         st2, outs = w._step(w.state, inputs, w.policy)
         acc2 = w._telem_fn(w._telem_acc, outs)
         jax.block_until_ready(acc2)
     # sanity: the guarded fold really accumulated a tick
-    reb = np.asarray(acc2["rebuilt"])
-    assert int(reb.sum()) == int(np.asarray(
-        w._telem_acc["rebuilt"]).sum()) + 1
+    assert int(np.asarray(acc2["rebuilt"]).sum()) == reb_before + 1
 
 
 def test_one_trace_per_config_and_signature_stability():

@@ -458,8 +458,11 @@ def main(argv: list[str] | None = None) -> int:
         + glob.glob(os.path.join(args.dir, "MULTICHIP_r*.json"))
     )
     if not files:
-        print(f"no artifacts under {args.dir}", file=sys.stderr)
-        return 1
+        # a walked directory with no artifacts has nothing to violate
+        # (the repository keeps none of its own); a NAMED file that is
+        # missing stays an error below
+        print(f"no artifacts under {args.dir}: nothing to validate")
+        return 0
     bad = 0
     for path in files:
         if not os.path.exists(path):

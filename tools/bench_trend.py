@@ -806,9 +806,12 @@ def main(argv: list[str] | None = None) -> int:
             + glob.glob(os.path.join(args.dir, "MULTICHIP_r*.json"))
         )
         if not files:
-            print(f"no BENCH_r*/MULTICHIP_r* files under {args.dir}",
-                  file=sys.stderr)
-            return 1
+            # nothing walked, nothing regressed (the repository keeps
+            # no artifacts of its own); a NAMED missing file is the
+            # usage error above
+            print(f"no BENCH_r*/MULTICHIP_r* files under {args.dir}: "
+                  "nothing to gate")
+            return 0
     bench = [f for f in files
              if "BENCH" in os.path.basename(f)
              and "_interim" not in os.path.basename(f)]

@@ -647,8 +647,8 @@ def run_audit(seed: int, n: int = AUDIT_SOAK_N,
        violations of any kind, zero oracle mismatches and a passing
        conservation verdict — the plane must not cry wolf.
     2. INJECTED drop: one more migrate-out whose restore is
-       deliberately suppressed (the lost-update every migration bug
-       taxonomy fears). The conservation verdict must name the
+       deliberately suppressed (the lost update every list of
+       migration bugs fears). The conservation verdict must name the
        dropped EntityID within <= 8 ticks, and routing the finding
        back through the ledger's violation path must freeze an
        ``audit_violation`` flight-recorder bundle carrying the ledger
@@ -849,6 +849,7 @@ def run_failover(seed: int, n: int = FAILOVER_SOAK_N,
     from goworld_tpu.scenarios.runner import build_world
     from goworld_tpu.scenarios.spec import get_scenario
     from goworld_tpu.utils import audit as audit_mod
+    from goworld_tpu.utils import snapfiles
 
     import tempfile
 
@@ -1044,7 +1045,7 @@ def run_failover(seed: int, n: int = FAILOVER_SOAK_N,
         # restore_world, first tick. A real cold restore ALSO pays
         # process boot + jit warmup, so this is a conservative floor.
         t_cold0 = time.perf_counter()
-        snap_path = freeze_mod.latest_snapshot_path(
+        snap_path = snapfiles.latest_snapshot_path(
             primary.game_id, tmpdir)
         cold_ok = False
         if snap_path is not None:

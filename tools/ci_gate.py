@@ -27,6 +27,7 @@ Usage::
 
     python tools/ci_gate.py                  # the pre-merge one-liner
     python tools/ci_gate.py --threshold 0.2  # forwarded to bench_trend
+    python tools/ci_gate.py --dir DIR        # walk DIR's artifacts
 """
 
 from __future__ import annotations
@@ -43,8 +44,11 @@ TOOLS = os.path.dirname(os.path.abspath(__file__))
 GATES = ("obs_lint", "bench_schema", "bench_trend")
 
 
-def run_gates(threshold: float | None = None) -> list[tuple[str, int]]:
-    """Run every gate; return the (name, rc) list of FAILURES."""
+def run_gates(threshold: float | None = None,
+              directory: str | None = None) -> list[tuple[str, int]]:
+    """Run every gate; return the (name, rc) list of FAILURES.
+    ``directory`` is the artifact trajectory the two bench gates walk
+    (their default: the repo root)."""
     if TOOLS not in sys.path:
         sys.path.insert(0, TOOLS)
     failures: list[tuple[str, int]] = []
@@ -57,8 +61,10 @@ def run_gates(threshold: float | None = None) -> list[tuple[str, int]]:
             failures.append((name, -1))
             continue
         argv: list[str] = []
+        if name != "obs_lint" and directory is not None:
+            argv = ["--dir", directory]
         if name == "bench_trend" and threshold is not None:
-            argv = ["--threshold", str(threshold)]
+            argv += ["--threshold", str(threshold)]
         try:
             rc = int(mod.main(argv))
         except SystemExit as exc:  # tolerate argparse-style exits
@@ -75,8 +81,12 @@ def main(argv: list[str] | None = None) -> int:
     ap.add_argument("--threshold", type=float, default=None,
                     help="regression threshold forwarded to "
                          "bench_trend (its default otherwise)")
+    ap.add_argument("--dir", default=None,
+                    help="artifact directory forwarded to bench_schema "
+                         "and bench_trend (their default: the repo "
+                         "root, which keeps none of its own)")
     args = ap.parse_args(argv)
-    failures = run_gates(args.threshold)
+    failures = run_gates(args.threshold, args.dir)
     if failures:
         print("ci_gate: FAILED — "
               + ", ".join(f"{n} (rc={rc})" for n, rc in failures))

@@ -13,7 +13,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from goworld_tpu.ops.aoi import (
-    GridSpec, _cell_rows, _sort_cells, _sorted_src, _build_table,
+    GridSpec, _build_table, _cell_rows, _sort_cells, _sorted_src,
 )
 
 N = int(os.environ.get("PROBE_N", 131072))
@@ -35,9 +35,8 @@ alive = jnp.ones(N, bool)
 def front(p):
     cx, cz, srow, alive2, czp, n_rows = _cell_rows(spec, p, alive, None)
     order, sorted_row = _sort_cells(N, n_rows, srow)
-    src, ts, sb = _sorted_src(spec, p, None, order)
-    table = _build_table(cc, n_rows, sorted_row, src,
-                         (jnp.inf, jnp.inf, sb))
+    src, _ts, empty = _sorted_src(spec, p, None, order)
+    table = _build_table(cc, n_rows, sorted_row, src, empty)
     return cx, cz, czp, table
 
 
@@ -65,7 +64,7 @@ def mk(form):
                     rows9 = (starts[:, :, None]
                              + jnp.arange(3)[None, None, :]).reshape(b, 9)
                     win = table[rows9]
-                s = jnp.where(jnp.isfinite(win), win, 0.0).sum()
+                s = win.sum().astype(jnp.float32)  # int32 planes
                 return p + (s % 2) * 1e-7, s
             pp, ss = lax.scan(body, p0, None, length=length)
             return ss.sum() + pp.sum()

@@ -91,6 +91,8 @@ def audit_for(rec: dict, live: bool) -> dict:
     block = devprof.roofline_audit(
         rec.get("phase_ms") or {}, costs, n,
         grid_kw_from_headline(rec), platform=rec.get("platform"),
+        device_kind=(rec.get("device") or {}).get("kind")
+        if isinstance(rec.get("device"), dict) else None,
     )
     if live and costs:
         block["backfilled"] = "xla columns re-lowered on current backend"
@@ -107,7 +109,7 @@ def print_table(path: str, block: dict) -> None:
     # donate MB = donation_applied_mb (bytes aliasing DID reclaim),
     # reclaim MB = donation_reclaimable_mb (bytes it still could)
     hdr = f"{'phase':<12}{'model MB':>10}{'xla MB':>10}" \
-          f"{'drift %':>9}{'meas ms':>9}{'v5e ms':>8}" \
+          f"{'drift %':>9}{'meas ms':>9}{'peak ms':>8}" \
           f"{'donate MB':>11}{'reclaim MB':>12}"
     print(hdr)
     for name, row in block.get("phases", {}).items():
@@ -116,7 +118,7 @@ def print_table(path: str, block: dict) -> None:
               f"{row.get('xla_mb', '-'):>10}"
               f"{row.get('drift_pct', '-'):>9}"
               f"{row.get('measured_ms', '-'):>9}"
-              f"{row.get('model_ms_v5e', '-'):>8}"
+              f"{row.get('model_ms', '-'):>8}"
               f"{row.get('donation_applied_mb', '-'):>11}"
               f"{row.get('donation_reclaimable_mb', '-'):>12}")
     if "total_drift_pct" in block:
