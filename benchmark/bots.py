@@ -1,0 +1,419 @@
+"""The load generator: a jax-free child of ``run.py`` that holds every
+client of the cell, drives the mix's open-loop schedule through the
+gate socket, stamps every receipt where the packet is handled, and
+reduces its logs to the client-side metrics and check numbers.
+
+Talks to the parent in lines: prints ``READY`` once every client is
+logged in, placed and mirrors its whole group; reads ``WINDOW <t0>
+<seconds> <grace>`` (``t0`` on ``time.monotonic()``, which parent and
+child share); writes its result to ``--out`` and prints ``DONE``.
+"""
+from __future__ import annotations
+
+import argparse
+import asyncio
+import importlib.util
+import json
+import os
+import sys
+import time
+
+import numpy as np
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+sys.path.insert(0, HERE)
+sys.path.insert(0, os.path.dirname(HERE))
+
+import reduce as R  # noqa: E402
+from goworld_tpu.net import codec, proto  # noqa: E402
+from goworld_tpu.net.botclient import BotClient  # noqa: E402
+
+MAX_SEQ = 8192          # sends a client can make in one run
+LOGIN_TIMEOUT_S = 150.0
+SETTLE_TIMEOUT_S = 60.0  # an answer that comes late is late, not wrong
+ROWS_SAMPLE = 768        # NPC rows read back beside every avatar's row
+CROSS_BAND = 3.0         # a definite crossing swings this far past the edge
+# Logins, and then the first sends (each a jump from the parking spot to
+# the group's anchor), come in waves that double from WAVE_FIRST clients
+# up to WAVE_MAX: a tick decodes at most enter_cap = leave_cap = 4,096
+# interest events and drops the rest, and a client that enters or jumps
+# makes some 25 to 40 of each. A wave starts once the game has answered
+# the one before it (a login frame can take seconds, and waves on a
+# timer would pile up in it) and WAVE_CALM_FRAMES calm frames have
+# passed (a second at the most): a wave's frame stalls for over a second
+# when it brings a staging batch of a new size (the eager scatters
+# compile in the serve loop, one set per power-of-two bucket from 8 up),
+# two such frames in a row read as severe overload twice, and the
+# ladder leaves NORMAL. Doubling, a wave that the game meets in two
+# parts brings at most one new bucket.
+WAVE_FIRST, WAVE_MAX, WAVE_CALM_FRAMES, WAVE_CALM_MAX_S = 8, 64, 3, 1.0
+
+
+def load_generator(kind: str):
+    path = os.path.join(HERE, "generators", f"{kind}.py")
+    spec = importlib.util.spec_from_file_location(f"gen_{kind}", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+class Log:
+    """Receipts of every client, in arrival order."""
+
+    def __init__(self):
+        self.by_eid: dict[bytes, int] = {}    # avatar eid -> client
+        self.sync: list[tuple] = []   # (receiver, t, senders, vals)
+        self.echo: list[tuple] = []   # (receiver, t, token)
+        self.made: list[tuple] = []   # (receiver, t, eid, created?)
+        self.stats: str | None = None
+        self.rows: str | None = None
+
+
+class Client(BotClient):
+    """The SDK's bot with its receipts stamped where they are handled.
+    Everything but position batches and RPCs goes through the SDK's own
+    strict mirror."""
+
+    def __init__(self, *a, log: Log, idx: int, **kw):
+        super().__init__(*a, **kw)
+        self.log, self.idx = log, idx
+
+    def _handle_inner(self, msgtype, pkt):
+        if msgtype == proto.MT_CLIENT_SYNC_POSITION_YAW:
+            t = time.monotonic()
+            eids, vals = codec.decode_sync_batch(
+                memoryview(pkt.buf)[pkt.rpos:])
+            who, rows = [], []
+            by_eid, ents = self.log.by_eid, self.entities
+            for i, eid_b in enumerate(eids.tolist()):
+                me = ents.get(eid_b.decode("ascii", "replace"))
+                if me is None:
+                    continue
+                v = vals[i]
+                me.pos = (float(v[0]), float(v[1]), float(v[2]))
+                me.yaw = float(v[3])
+                self.sync_count += 1
+                c = by_eid.get(eid_b)
+                if c is not None:
+                    who.append(c)
+                    rows.append(i)
+            if who:
+                self.log.sync.append((self.idx, t, who, vals[rows]))
+            return
+        if msgtype == proto.MT_CALL_ENTITY_METHOD_ON_CLIENT:
+            t = time.monotonic()
+            n = len(self.rpc_log)
+            super()._handle_inner(msgtype, pkt)
+            for _eid, method, args in self.rpc_log[n:]:
+                if method == "OnEcho":
+                    self.log.echo.append((self.idx, t, args[0]))
+                elif method == "OnStats":
+                    self.log.stats = args[0]
+                elif method == "OnRows":
+                    self.log.rows = args[0]
+            del self.rpc_log[n:]
+            return
+        if msgtype in (proto.MT_CREATE_ENTITY_ON_CLIENT,
+                       proto.MT_DESTROY_ENTITY_ON_CLIENT):
+            at = pkt.rpos
+            eid = pkt.read_entity_id()
+            pkt.rpos = at
+            self.log.made.append(
+                (self.idx, time.monotonic(), eid,
+                 msgtype == proto.MT_CREATE_ENTITY_ON_CLIENT))
+        super()._handle_inner(msgtype, pkt)
+
+
+async def main_async(a) -> int:
+    with open(a.config) as f:
+        cfg = json.load(f)
+    with open(a.mix) as f:
+        mix = json.load(f)
+    gen = load_generator(mix["kind"])
+    n = int(mix["clients"])
+    radius = float(cfg["game"]["aoi_radius"])
+    plan = gen.Plan(mix, float(cfg["game"]["extent_x"]), radius, n)
+    table = plan.positions(MAX_SEQ)
+    observer = np.array([plan.observer(c) for c in range(n)])
+    loop = asyncio.get_running_loop()
+    log = Log()
+    bots = [Client("127.0.0.1", a.gate_port, bot_id=i, strict=True,
+                   nosync=True, log=log, idx=i) for i in range(n)]
+    tasks = []
+    gap = min(WAVE_CALM_FRAMES / float(cfg["game"]["tick_hz"]),
+              WAVE_CALM_MAX_S)
+    bounds, size = [0], WAVE_FIRST
+    while bounds[-1] < n:
+        size = max(plan.g, size - size % plan.g)      # whole groups
+        bounds.append(min(n, bounds[-1] + size))
+        size = min(2 * size, WAVE_MAX)
+    waves = list(zip(bounds[:-1], bounds[1:]))
+    end = time.monotonic() + LOGIN_TIMEOUT_S
+    for lo, hi in waves:
+        for b in bots[lo:hi]:
+            await b.connect()
+            tasks.append(loop.create_task(b._recv_loop()))
+        while not all(b.player is not None for b in bots[lo:hi]):
+            if time.monotonic() > end:
+                print("FAILED login: %d of %d clients have a player" % (
+                    sum(b.player is not None for b in bots), n),
+                    flush=True)
+                return 1
+            await asyncio.sleep(0.05)
+        await asyncio.sleep(gap)
+    for i, b in enumerate(bots):
+        log.by_eid[b.player.eid.encode("ascii")] = i
+
+    seq = np.zeros(n, np.int64)            # last sequence number sent
+    wave_at = 0
+    moving = waves[0][1]                   # clients that may send yet
+    window: dict = {}
+    sends: list[tuple] = []                # (client, seq, due, sent)
+    calls: list[tuple] = []                # (client, token, due, sent)
+
+    async def drive(offs, who, kind, start, stop, record) -> None:
+        """Run one stretch of schedule, open loop, until ``stop()``
+        gives an instant that has come."""
+        for k in range(len(offs)):
+            due = start + float(offs[k])
+            cut = stop()
+            if cut is not None and due >= cut:
+                return
+            delay = due - time.monotonic()
+            if delay > 0:
+                await asyncio.sleep(delay)
+            c = int(who[k])
+            if c >= moving:
+                continue                   # its wave has not come yet
+            if kind[k] == gen.SEND:
+                seq[c] += 1
+                v = table[c, seq[c]]
+                bots[c].send_position(float(v[0]), float(v[1]),
+                                      float(v[2]), float(v[3]))
+                if record:
+                    sends.append((c, int(seq[c]), due, time.monotonic()))
+            else:
+                token = f"{a.seed}.{c}.{len(calls)}.{record}"
+                bots[c].call_server("Echo_Client", token)
+                if record:
+                    calls.append((c, token, due, time.monotonic()))
+
+    def read_window() -> None:
+        line = sys.stdin.readline().split()
+        if len(line) == 4 and line[0] == "WINDOW":
+            window.update(t0=float(line[1]), seconds=float(line[2]),
+                          grace=float(line[3]))
+        else:
+            window.update(t0=time.monotonic(), seconds=0.0, grace=0.0,
+                          aborted=True)
+
+    reader = loop.run_in_executor(None, read_window)
+
+    def placed(upto: int) -> bool:
+        for c in range(upto):
+            ents = bots[c].entities
+            for d in plan.members(plan.group_of(c)):
+                if d == c:
+                    continue
+                m = ents.get(bots[d].player.eid)
+                if m is None or m.pos[1] < 1.0:
+                    return False
+        return True
+
+    # warm-up: the same cadence as the window, in stretches of 10 s
+    ready = False
+    stretch = 0
+    while "t0" not in window or time.monotonic() < window["t0"]:
+        stretch += 1
+        start = time.monotonic()
+        offs, who, kind = gen.schedule(mix, n, a.seed, 10.0, stretch)
+        task = loop.create_task(drive(
+            offs, who, kind, start, lambda: window.get("t0"), False))
+        while not task.done():
+            await asyncio.sleep(0.1)
+            if not ready and placed(moving):
+                if moving < n:
+                    await asyncio.sleep(gap)
+                    wave_at += 1
+                    moving = waves[wave_at][1]       # the next wave
+                else:
+                    ready = True
+                    print("READY", flush=True)
+        await task
+        if "t0" in window:
+            break
+        if not ready and time.monotonic() > end:
+            print("FAILED placement: groups never mirrored each other",
+                  flush=True)
+            return 1
+        await asyncio.sleep(max(0.0, start + 10.0 - time.monotonic()))
+    await reader
+    if window.get("aborted"):
+        return 1
+
+    # ---- the window ----------------------------------------------------
+    t0, seconds, grace = window["t0"], window["seconds"], window["grace"]
+    n_sync0, n_echo0 = len(log.sync), len(log.echo)
+    offs, who, kind = gen.schedule(mix, n, a.seed, seconds, 0)
+    await asyncio.sleep(max(0.0, t0 - time.monotonic()))
+    await drive(offs, who, kind, t0, lambda: None, True)
+    close = t0 + seconds + grace
+    await asyncio.sleep(max(0.0, close - time.monotonic()))
+
+    # ---- settle: wait for the answers that are due, then judge them ----
+    last = seq.copy()
+    final_vals = table[np.arange(n), last]
+    final_xz = final_vals[:, [0, 2]]
+    slack = float(cfg.get("check", {}).get("npc_slack", 12.0))
+
+    def snapshot():
+        mirrors = []
+        for c, b in enumerate(bots):
+            mir = {}
+            for eid, m in b.entities.items():
+                if b.player is not None and eid == b.player.eid:
+                    continue
+                vals = (m.pos[0], m.pos[1], m.pos[2], m.yaw)
+                d = log.by_eid.get(eid.encode("ascii"))
+                mir[eid] = ("client", d, vals) if d is not None \
+                    else ("npc", eid, vals)
+            mirrors.append(mir)
+        return R.interest_check(final_xz, radius, slack, mirrors,
+                                final_vals)
+
+    settle_end = time.monotonic() + SETTLE_TIMEOUT_S
+    check = snapshot()
+    while (check["final_missing"] or check["interest_extra"]) \
+            and time.monotonic() < settle_end:
+        await asyncio.sleep(0.5)
+        check = snapshot()
+    settled_s = time.monotonic() - close
+
+    bots[0].call_server("Stats_Client")
+    stats_end = time.monotonic() + 30.0
+    while log.stats is None and time.monotonic() < stats_end:
+        await asyncio.sleep(0.05)
+    # ... and, the peak read, what the device holds now (every client's
+    # last position has reached it: the mirrors above say so)
+    bots[0].call_server("Rows_Client", str(a.seed), ROWS_SAMPLE)
+    rows_end = time.monotonic() + 60.0
+    while log.rows is None and time.monotonic() < rows_end:
+        await asyncio.sleep(0.05)
+    # no answer: every row that was to be read counts as wrong
+    rows = {"rows_wrong": ROWS_SAMPLE + n, "avatar_row_off": n,
+            "rows_read": 0}
+    if log.rows is not None:
+        with np.load(os.path.join(os.path.dirname(a.out), log.rows)) as z:
+            order = {e: i for i, e in enumerate(z["avatar_eids"].tolist())}
+            mine = [order.get(b.player.eid, -1) for b in bots]
+            if min(mine) >= 0:
+                rows = R.rows_check(
+                    z["pos"], z["alive"], z["rows"], z["nbr"], radius,
+                    z["avatar_rows"][mine], final_vals)
+                rows["rows_read"] = int(len(z["rows"]))
+
+    # ---- reduce --------------------------------------------------------
+    sync = log.sync[n_sync0:]
+    rc_recv = np.concatenate([np.full(len(w), r) for r, _t, w, _v in sync]
+                             or [np.zeros(0, np.int64)])
+    rc_t = np.concatenate([np.full(len(w), t) for _r, t, w, _v in sync]
+                          or [np.zeros(0)])
+    rc_sender = np.concatenate([np.asarray(w, np.int64)
+                                for _r, _t, w, _v in sync]
+                               or [np.zeros(0, np.int64)])
+    rc_vals = np.concatenate([v for _r, _t, _w, v in sync]
+                             or [np.zeros((0, 4), np.float32)])
+    rc_seq = np.rint(rc_vals[:, 1]).astype(np.int64)
+    s_client = np.array([s[0] for s in sends], np.int64)
+    s_seq = np.array([s[1] for s in sends], np.int64)
+    s_due = np.array([s[2] for s in sends])
+    s_sent = np.array([s[3] for s in sends])
+    seen = R.match_sends(s_client, s_seq, observer, rc_recv, rc_sender,
+                         rc_seq, rc_t)
+    move_ms, move_failed = R.latencies(s_due, seen, close)
+    # late is late, not wrong: what `correct` counts is an operation
+    # still unanswered when the settle wait is over
+    never_seen = int(np.isnan(seen).sum())
+    answered: dict[tuple, float] = {}
+    rpc_wrong = 0
+    asked = {(c, tok) for c, tok, _d, _s in calls}
+    for r, t, tok in log.echo[n_echo0:]:
+        if (r, tok) in asked:
+            answered.setdefault((r, tok), t)
+        elif tok.endswith(".True"):
+            rpc_wrong += 1      # a window token at the wrong client, or
+            #                     one nobody sent; warm-up echoes may trail
+    c_due = np.array([d for _c, _tok, d, _s in calls])
+    c_sent = np.array([s for _c, _tok, _d, s in calls])
+    c_seen = np.array([answered.get((c, tok), np.nan)
+                       for c, tok, _d, _s in calls])
+    rpc_ms, rpc_failed = R.latencies(c_due, c_seen, close)
+    never_seen += int(np.isnan(c_seen).sum())
+    pos_wrong, order_back = R.stream_faults(
+        rc_recv, rc_sender, rc_seq, rc_vals, table, n)
+    late = np.concatenate([s_sent - s_due, c_sent - c_due]) * 1e3
+    # enters and leaves between clients of twin groups, inside the window
+    pairs = plan.crossers()
+    wanted = set(pairs)
+    events: dict[tuple, list] = {}
+    for r, t, eid, created in log.made:
+        d = log.by_eid.get(eid.encode("ascii"))
+        if d is not None and (r, d) in wanted and t >= t0:
+            events.setdefault((r, d), []).append((t, created))
+    cross = R.cross_check(pairs, [(c, q, t) for c, q, _due, t in sends],
+                          table, events, radius, CROSS_BAND)
+    rows_read = rows.pop("rows_read")
+    numbers = dict(check, **rows, cross_missed=cross["cross_missed"],
+                   pos_wrong=pos_wrong, order_back=order_back,
+                   rpc_wrong=rpc_wrong, never_seen=never_seen,
+                   mirror_errors=sum(len(b.errors) for b in bots))
+    out = {
+        "metrics": {
+            "move_seen_ms.p50": R.nearest_rank(move_ms, 0.50),
+            "move_seen_ms.p95": R.nearest_rank(move_ms, 0.95),
+            "rpc_ms.p95": R.nearest_rank(rpc_ms, 0.95),
+        },
+        "attempted": len(sends) + len(calls),
+        "failed": move_failed + rpc_failed,
+        "sends": len(sends), "calls": len(calls),
+        "move_failed": move_failed, "rpc_failed": rpc_failed,
+        "numbers": numbers,
+        "gen_late_ms": {"p50": R.nearest_rank(late, 0.5),
+                        "p95": R.nearest_rank(late, 0.95),
+                        "max": float(late.max())},
+        "receipts": int(len(rc_seq)),
+        "sync_records": sum(b.sync_count for b in bots),
+        "npcs_mirrored": sum(
+            sum(1 for m in b.entities.values() if m.type_name == "Npc")
+            for b in bots),
+        "settled_s_after_close": settled_s,
+        "crossings": cross["crossings"], "rows_read": rows_read,
+        "stats": json.loads(log.stats) if log.stats else None,
+        "mirror_errors_first": [e for b in bots for e in b.errors][:5],
+        "t0": t0, "close": close,
+    }
+    with open(a.out, "w") as f:
+        json.dump(out, f)
+    print("DONE", flush=True)
+    for t in tasks:
+        t.cancel()
+    for b in bots:
+        b._stop = True
+        if b._hb_task is not None:
+            b._hb_task.cancel()
+        await b.conn.close()
+    return 0
+
+
+def main() -> int:
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--gate-port", type=int, required=True)
+    ap.add_argument("--config", required=True)
+    ap.add_argument("--mix", required=True)
+    ap.add_argument("--seed", type=int, required=True)
+    ap.add_argument("--out", required=True)
+    return asyncio.run(main_async(ap.parse_args()))
+
+
+if __name__ == "__main__":
+    sys.exit(main())

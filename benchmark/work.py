@@ -1,0 +1,57 @@
+"""The work one tick cannot avoid, counted from the configuration's
+shapes alone — never from which kernel implements it.
+
+Bytes of one tick (``necessary_bytes``):
+
+* every live row's state read once and written once: the planes of
+  ``core/state.py`` ``SpaceState`` (pos f32[3], yaw f32, vel f32[3],
+  alive, npc_moving, has_client, dirty: 1 byte each, client_gate,
+  type_id, gen, attr_dirty, nbr_cnt, nbr_client_cnt: 4 each, hot_attrs
+  f32[A], nbr_mean_off f32[3], aoi_radius f32);
+* every live row's neighbour list (i32[k]) read once and written once;
+* the sync records it emits: one (slot, target, x, y, z, yaw) record
+  of 24 bytes per client and mirrored mover — ``clients`` times the
+  expected neighbours (NPCs) plus the group's other members.
+
+``least_seconds`` divides by the chip's HBM peak (``peaks.json``); the
+bound is bytes: the tick does a few operations per byte, far under the
+chip's 240 FLOP per byte ridge.
+"""
+from __future__ import annotations
+
+import json
+import os
+
+ATTR_WIDTH = 8      # WorldConfig.attr_width default
+K = 64              # GridSpec k default (utils/consts.py)
+ROW_BYTES = 3 * 4 + 4 + 3 * 4 + 4 * 1 + 6 * 4 + ATTR_WIDTH * 4 \
+    + 3 * 4 + 4
+SYNC_RECORD_BYTES = 24
+
+
+def necessary_bytes(live: int, clients: int, group_size: int,
+                    expected_neighbours: float, k: int = K) -> int:
+    state = 2 * ROW_BYTES * live
+    lists = 2 * 4 * k * live
+    records = SYNC_RECORD_BYTES * clients * (
+        expected_neighbours + group_size - 1)
+    return int(state + lists + records)
+
+
+def peaks(device_kind: str) -> dict:
+    with open(os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                           "peaks.json")) as f:
+        table = json.load(f)
+    if device_kind not in table:
+        raise KeyError(f"no published peaks for device kind "
+                       f"{device_kind!r} in benchmark/peaks.json")
+    return table[device_kind]
+
+
+def least_seconds(cfg: dict, mix: dict, device_kind: str) -> float:
+    """The least time the chip could take for one tick: its necessary
+    bytes at the HBM peak."""
+    b = necessary_bytes(int(cfg["world"]["live"]), int(mix["clients"]),
+                        int(mix["group_size"]),
+                        float(cfg["world"]["expected_neighbours"]))
+    return b / float(peaks(device_kind)["hbm_bytes_per_s"])
