@@ -415,6 +415,14 @@ def run(argv: list[str] | None = None, *, block: bool = True) -> _Runtime:
     from goworld_tpu.utils import compile_cache, devprof, opmon
 
     cache_dir = compile_cache.setup()
+    # the serve loop's spans (utils/metrics.py TickTimeline) also go
+    # onto the profiler's timeline as gw.<span> annotations. The hook
+    # is set here and nowhere else: only the game process has jax.
+    from jax.profiler import TraceAnnotation
+
+    from goworld_tpu.utils import metrics as _metrics
+
+    _metrics.set_annotation(TraceAnnotation)
     world = _build_world(gc, gid)
     # say which device serves this game (log + /vars): an operator —
     # and chip_smoke.py — must be able to refuse a run that is not on

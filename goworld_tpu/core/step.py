@@ -185,56 +185,64 @@ def tick_body(
     if prec:
         state = state.replace(vel=state.vel.astype(jnp.float32))
 
-    # 1. client inputs (scatter).
-    pos, yaw, touched = apply_pos_inputs(
-        state.pos, state.yaw,
-        inputs.pos_sync_idx, inputs.pos_sync_vals, inputs.pos_sync_n,
-    )
+    # 1. client inputs (scatter). Every numbered phase runs under a
+    # ``gw.<phase>`` named scope: metadata only (the HLO is otherwise
+    # byte-identical, tests/test_trace_scopes.py), it is how a profiler
+    # capture names the phase whatever the compiler calls its ops.
+    with jax.named_scope("gw.inputs"):
+        pos, yaw, touched = apply_pos_inputs(
+            state.pos, state.yaw,
+            inputs.pos_sync_idx, inputs.pos_sync_vals, inputs.pos_sync_n,
+        )
 
     # 2. behaviors (vectorized; MXU when behavior == 'mlp'). A scenario
     # config dispatches a heterogeneous population through ONE vmapped
     # lax.switch on the per-entity behavior lane instead of the static
     # Python-if below (goworld_tpu/scenarios/behaviors.py) — one trace
     # per WorldConfig either way.
-    rng, k_behave = jax.random.split(state.rng)
-    tele = None
-    if cfg.scenario is not None:
-        vel, tele_pos, tele = scenario_velocity(
-            cfg, k_behave, pos, yaw, state, policy
-        )
-    else:
-        vel = compute_velocity(
-            cfg, k_behave, pos, yaw, state, policy,
-            (cfg.grid.extent_x, cfg.grid.extent_z),
-            nbr=state.nbr, nbr_cnt=state.nbr_cnt,
-        )
+    with jax.named_scope("gw.behave"):
+        rng, k_behave = jax.random.split(state.rng)
+        tele = None
+        if cfg.scenario is not None:
+            vel, tele_pos, tele = scenario_velocity(
+                cfg, k_behave, pos, yaw, state, policy
+            )
+        else:
+            vel = compute_velocity(
+                cfg, k_behave, pos, yaw, state, policy,
+                (cfg.grid.extent_x, cfg.grid.extent_z),
+                nbr=state.nbr, nbr_cnt=state.nbr_cnt,
+            )
 
     # 3. integrate + world clamp.
-    pos, moved = integrate(
-        pos, vel, state.npc_moving, cfg.dt,
-        cfg.bounds_min, cfg.bounds_max,
-    )
-    if tele is not None:
-        # scenario teleports override the integrated position BEFORE
-        # the sweep, so the Verlet displacement check sees the full
-        # jump and trips the in-graph rebuild cond on this exact tick
-        pos = jnp.where(tele[:, None], tele_pos, pos)
-        moved = moved | tele
-    if prec:
-        # the AOI-visible view: snapped lattice positions. "moved" is
-        # re-derived IN THE LATTICE DOMAIN (y stays a raw compare) —
-        # an entity that didn't cross a lattice step is clean for
-        # sync/halo purposes, exactly because no consumer can observe
-        # the sub-step motion.
-        apos = quantize_positions(cfg.grid, pos)
-        aprev = quantize_positions(cfg.grid, state.pos)
-        moved = jnp.any(apos != aprev, axis=1)
-    else:
-        apos = pos
-    # state.dirty carries host-set pending force-syncs (spawn marks the
-    # new entity dirty so watchers get its position, the syncInfoFlag
-    # analog — Entity.go:1189-1205); consumed here, cleared below.
-    dirty = (moved | touched | state.dirty) & state.alive
+    with jax.named_scope("gw.integrate"):
+        pos, moved = integrate(
+            pos, vel, state.npc_moving, cfg.dt,
+            cfg.bounds_min, cfg.bounds_max,
+        )
+        if tele is not None:
+            # scenario teleports override the integrated position
+            # BEFORE the sweep, so the Verlet displacement check sees
+            # the full jump and trips the in-graph rebuild cond on this
+            # exact tick
+            pos = jnp.where(tele[:, None], tele_pos, pos)
+            moved = moved | tele
+        if prec:
+            # the AOI-visible view: snapped lattice positions. "moved"
+            # is re-derived IN THE LATTICE DOMAIN (y stays a raw
+            # compare) — an entity that didn't cross a lattice step is
+            # clean for sync/halo purposes, exactly because no consumer
+            # can observe the sub-step motion.
+            apos = quantize_positions(cfg.grid, pos)
+            aprev = quantize_positions(cfg.grid, state.pos)
+            moved = jnp.any(apos != aprev, axis=1)
+        else:
+            apos = pos
+        # state.dirty carries host-set pending force-syncs (spawn marks
+        # the new entity dirty so watchers get its position, the
+        # syncInfoFlag analog — Entity.go:1189-1205); consumed here,
+        # cleared below.
+        dirty = (moved | touched | state.dirty) & state.alive
 
     # 4. AOI sweep (the go-aoi XZList replacement). Per-entity aoi_radius
     # honors EntityTypeDesc.aoiDistance (0 = excluded from AOI). The dirty
@@ -245,54 +253,58 @@ def tick_body(
     # window fetch entirely (lax.cond — NOT valid under vmap, where both
     # branches would run; the World manager clears skin for its vmapped
     # multi-space step like adaptive_extract).
-    flag_bits = dirty.astype(jnp.int32) \
-        | (state.has_client.astype(jnp.int32) << 1)
     use_verlet = (
         cfg.grid.skin > 0.0
         and state.aoi_cache is not None
         and n < (1 << _ID_BITS)
     )
-    if use_verlet:
-        (nbr, nbr_cnt, nbr_fl, aoi_stats, aoi_cache, aoi_rebuilt,
-         aoi_slack) = grid_neighbors_verlet(
-            cfg.grid, apos, state.alive, state.aoi_cache,
-            watch_radius=state.aoi_radius, flag_bits=flag_bits,
-            with_stats=True,
-        )
-    else:
-        nbr, nbr_cnt, nbr_fl, aoi_stats = grid_neighbors_flags(
-            cfg.grid, apos, state.alive, watch_radius=state.aoi_radius,
-            flag_bits=flag_bits,
-            with_stats=True,
-        )
-        aoi_cache = state.aoi_cache
-        aoi_rebuilt = jnp.ones((), jnp.int32)
-        aoi_slack = jnp.zeros((), jnp.float32)
+    with jax.named_scope("gw.aoi"):
+        flag_bits = dirty.astype(jnp.int32) \
+            | (state.has_client.astype(jnp.int32) << 1)
+        if use_verlet:
+            (nbr, nbr_cnt, nbr_fl, aoi_stats, aoi_cache, aoi_rebuilt,
+             aoi_slack) = grid_neighbors_verlet(
+                cfg.grid, apos, state.alive, state.aoi_cache,
+                watch_radius=state.aoi_radius, flag_bits=flag_bits,
+                with_stats=True,
+            )
+        else:
+            nbr, nbr_cnt, nbr_fl, aoi_stats = grid_neighbors_flags(
+                cfg.grid, apos, state.alive,
+                watch_radius=state.aoi_radius, flag_bits=flag_bits,
+                with_stats=True,
+            )
+            aoi_cache = state.aoi_cache
+            aoi_rebuilt = jnp.ones((), jnp.int32)
+            aoi_slack = jnp.zeros((), jnp.float32)
 
     # 5. interest deltas -> bounded enter/leave pair lists (changed rows
     # only; the k^2 membership compare never touches stable rows).
-    (enter_w, enter_j, enter_n, leave_w, leave_j, leave_n,
-     delta_rows_n) = interest_pairs(
-        state.nbr, nbr, n, cfg.enter_cap, cfg.leave_cap,
-        min(cfg.delta_rows_cap_eff, n),
-        adaptive=cfg.adaptive_extract,
-    )
+    with jax.named_scope("gw.delta"):
+        (enter_w, enter_j, enter_n, leave_w, leave_j, leave_n,
+         delta_rows_n) = interest_pairs(
+            state.nbr, nbr, n, cfg.enter_cap, cfg.leave_cap,
+            min(cfg.delta_rows_cap_eff, n),
+            adaptive=cfg.adaptive_extract,
+        )
 
     # 6. position sync records (CollectEntitySyncInfos analog). Under
     # precision the records carry the SNAPPED positions — the same
     # lattice values the interest sets were computed from, and exactly
     # what the delta-sync codec re-encodes as int16 steps.
-    sync_w, sync_j, sync_vals, sync_n = collect_sync(
-        nbr, dirty, state.has_client, apos, yaw, cfg.sync_cap,
-        nbr_dirty=(nbr_fl & 1).astype(bool),
-        adaptive=cfg.adaptive_extract,
-    )
+    with jax.named_scope("gw.sync"):
+        sync_w, sync_j, sync_vals, sync_n = collect_sync(
+            nbr, dirty, state.has_client, apos, yaw, cfg.sync_cap,
+            nbr_dirty=(nbr_fl & 1).astype(bool),
+            adaptive=cfg.adaptive_extract,
+        )
 
     # 7. hot-attr deltas.
-    attr_e, attr_i, attr_v, attr_n = collect_attr_deltas(
-        state.hot_attrs, state.attr_dirty, cfg.attr_sync_cap,
-        adaptive=cfg.adaptive_extract,
-    )
+    with jax.named_scope("gw.attrs"):
+        attr_e, attr_i, attr_v, attr_n = collect_attr_deltas(
+            state.hot_attrs, state.attr_dirty, cfg.attr_sync_cap,
+            adaptive=cfg.adaptive_extract,
+        )
 
     new_state = state.replace(
         pos=pos,

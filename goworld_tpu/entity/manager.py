@@ -1790,7 +1790,7 @@ class World:
         tl = metrics.timeline
         self_opened = not tl.is_open
         if self_opened:
-            tl.begin_tick()
+            tl.begin_tick(self.tick_count)
         try:
             self._tick_phases(tl)
         finally:
@@ -1961,18 +1961,20 @@ class World:
                 self._decode_outputs(outs)
             self.post_q.tick()
         if self._restored_interest is not None:
-            self._reconcile_restored_interest()
+            with tl.span("restore_reconcile"):
+                self._reconcile_restored_interest()
         ap = self.audit
         if ap is not None and ap.want_sample(self.tick_count):
             # capture the cohort + frozen interest sets HERE (the
             # decode above just made them current for this tick), then
             # hand the oracle math to the audit worker. A capture
             # failure disables the plane, never the tick.
-            try:
-                self._audit_sample(aud_host)
-            except Exception:
-                logger.exception("audit sampling failed; disabled")
-                self.audit = None
+            with tl.span("audit_sample"):
+                try:
+                    self._audit_sample(aud_host)
+                except Exception:
+                    logger.exception("audit sampling failed; disabled")
+                    self.audit = None
         if rt is not None:
             rt.mark_decode_done()
             if rt.should_sample(self.tick_count):
@@ -2694,10 +2696,12 @@ class World:
                     keep = (js % self.sync_stride) == (
                         self.tick_count % self.sync_stride
                     )
-                    dropped = int(sn - int(keep.sum()))
-                    if dropped:
+                    # (its own name: `dropped` is this method's dict
+                    # of undecoded interest events, walked below)
+                    strided = int(sn - int(keep.sum()))
+                    if strided:
                         _ov.shed_counter(
-                            _ov.CLASS_SYNC, "stride").inc(dropped)
+                            _ov.CLASS_SYNC, "stride").inc(strided)
                     ws, js, vs = ws[keep], js[keep], vs[keep]
                     sn = len(js)
                 if not sn:

@@ -469,17 +469,19 @@ class GameServer:
                 # Nth serve-loop iteration (deterministic, unlike a
                 # wall-clock kill racing the boot compile)
                 faults.maybe_crash("game.tick")
-            # the serve loop owns the tick record: the pump and fan-out
-            # spans land in the same trace row as the World's phases
-            tl.begin_tick()
+            # the serve loop owns the tick record (gw.frame on a
+            # profiler capture): the pump and fan-out spans land in the
+            # same trace row as the World's phases. The record's first
+            # instant is also the residency plane's pump mark: one
+            # clock read serves both.
+            t_pump = tl.begin_tick(self.world.tick_count)
             self._m_queue_depth.set(self._packet_q.qsize())
             # residency accounting (utils/residency.py): the pump below
             # is useful host work between device dispatches, the pacing
             # sleep at the bottom is idle by design — declare both so
             # neither reads as a bubble
             rt = getattr(self.world, "residency", None)
-            t_pump = time.perf_counter()
-            with tl.span("drain_inputs"):
+            with tl.span("drain_inputs") as sp_pump:
                 # 1.5 frames of handler work per tick keeps the loop
                 # observing (and the p99 near 2x the interval) under a
                 # flood; the surplus waits in the class queues
@@ -488,7 +490,7 @@ class GameServer:
                     if self.overload_enabled else None
                 )
             if rt is not None:
-                rt.add_host(time.perf_counter() - t_pump)
+                rt.add_host(sp_pump.t1 - t_pump)
             self.tick()
             dur = tl.end_tick()
             if dur is not None:
@@ -500,10 +502,15 @@ class GameServer:
             delay = next_tick - time.monotonic()
             backlog = max(0.0, -delay / self.tick_interval)
             self._m_backlog.set(backlog)
+            # the tick record is closed: what follows is timed as lone
+            # spans (tick_phase_ms and a profiler annotation, in no
+            # tick's duration)
             if self.overload_enabled:
-                self._observe_overload(dur, backlog)
+                with tl.lone_span("overload_observe"):
+                    self._observe_overload(dur, backlog)
             if delay > 0:
-                time.sleep(delay)
+                with tl.lone_span("pacing_sleep"):
+                    time.sleep(delay)
                 if rt is not None:
                     rt.add_idle(delay)
             else:
@@ -657,16 +664,17 @@ class GameServer:
         # everything from here to the end of tick() is useful host work
         # between device dispatches — declared to the residency plane
         # so the bubble verdict only counts genuinely idle time
-        t_host = time.perf_counter()
-        with tl.span("fan_out"):
+        with tl.span("fan_out") as sp_fan:
             self._flush_sync_out()
             self._maybe_checkpoint()
             self._replication_pump()
+        t_host = sp_fan.t0      # the span's first instant serves both
         if self.rebalance_agent is not None:
-            try:
-                self._rebalance_service()
-            except Exception:  # must never break a tick
-                logger.exception("rebalance service failed")
+            with tl.span("rebalance"):
+                try:
+                    self._rebalance_service()
+                except Exception:  # must never break a tick
+                    logger.exception("rebalance service failed")
         ap = getattr(self.world, "audit", None)
         if (ap is not None and self.audit_scrub_every > 0
                 and self.world.tick_count % self.audit_scrub_every == 0):
@@ -680,10 +688,11 @@ class GameServer:
             # between-ticks commit point: the world's device step for
             # this tick is done, the next tick runs the (possibly)
             # swapped executable
-            try:
-                gov_ev = self._drive_governor()
-            except Exception:  # the governor must never break a tick
-                logger.exception("kernel governor window failed")
+            with tl.span("governor"):
+                try:
+                    gov_ev = self._drive_governor()
+                except Exception:  # the governor must never break a tick
+                    logger.exception("kernel governor window failed")
         if self.flightrec is not None:
             # own span: frame cost stays attributed in the timeline's
             # >=95% per-tick coverage bound

@@ -59,6 +59,7 @@ import jax.numpy as jnp
 from flax import struct
 from jax import lax
 
+from goworld_tpu.ops.scopes import scoped
 from goworld_tpu.utils import consts
 
 # Packed candidate word layouts (n < 2^21 fast path). The top_k ranking key
@@ -526,6 +527,17 @@ class GridSpec:
         return max(1, int(-(-self.extent_z // self.cell_size)))
 
 
+# Profiler names of the sweep's sub-phases (ops/scopes.py). The SAME
+# four names whichever sweep_impl runs, so a capture reads cells /
+# index / gather / rank from any of them and a new implementation
+# keeps the name of the phase it replaces.
+_SCOPE_CELLS = "gw.aoi.cells"    # cell rows + cell sort
+_SCOPE_INDEX = "gw.aoi.index"    # sorted view, row ranges / cell table
+_SCOPE_GATHER = "gw.aoi.gather"  # the 9-cell window fetch
+_SCOPE_RANK = "gw.aoi.rank"      # distances, key pack, top-k, unpack
+
+
+@scoped(_SCOPE_CELLS)
 def _cell_rows(spec: GridSpec, pos, alive, watch_radius):
     """Front half, stage 1: per-entity padded cell-row ids."""
     czp = spec.cells_z + 2          # padded (border) cell columns
@@ -557,6 +569,7 @@ def _cell_rows(spec: GridSpec, pos, alive, watch_radius):
     return cx, cz, srow, alive, czp, n_rows
 
 
+@scoped(_SCOPE_CELLS)
 def _sort_cells(n: int, n_rows: int, srow, sort_impl: str = "argsort"):
     """Front half, stage 2: entities ordered by cell row. Every impl is
     stable (ties broken by ascending slot id), so they are
@@ -587,6 +600,7 @@ def _sort_cells(n: int, n_rows: int, srow, sort_impl: str = "argsort"):
     return order, srow[order]
 
 
+@scoped(_SCOPE_INDEX)
 def _sorted_src(spec: GridSpec, pos, flag_bits, order):
     """Front half, stage 3: sorted (px bits, pz bits, packed word)
     int32 triples (see ``_INF_BITS`` for why the plane is int). The
@@ -610,6 +624,7 @@ def _sorted_src(spec: GridSpec, pos, flag_bits, order):
     return src, table_sentinel, (_INF_BITS, _INF_BITS, table_sentinel)
 
 
+@scoped(_SCOPE_INDEX)
 def _build_ranges(cc: int, n_rows: int, srow, src, pad_vals):
     """Front half, stage 4 (ranges impl): row_start offsets + padded
     component-major sorted view. row_start[r] = first sorted position of
@@ -639,6 +654,7 @@ def _init_row(comp_init, cc: int):
     return jnp.repeat(jnp.asarray(comp_init, jnp.int32), cc)
 
 
+@scoped(_SCOPE_INDEX)
 def _build_table(cc: int, n_rows: int, sorted_row, src, comp_init):
     """Front half, stage 4 (table/shift impls): dense per-cell table.
     Ranks each sorted entity within its cell via a segment scan (no
@@ -713,6 +729,7 @@ def _pack_keys(spec: GridSpec, dist, valid, cand_w, want_flags,
     return jnp.where(valid, (qd << _ID_BITS) | cand_w, invalid_key)
 
 
+@scoped(_SCOPE_CELLS)
 def _cell_occupancy_stats(srow, n_rows: int, cc: int):
     """AOI-cap gauges' cell half: (cell_max, over_cap_cells) from the
     UNclipped per-cell occupancy bincount (overflow = members dropped
@@ -842,10 +859,12 @@ def _sweep_shift(
         )
 
     def do_block(bi):
-        slab = lax.dynamic_slice(
-            t3, (bi * xb, 0, 0), (xb + 2, czp, ncomp * cc)
-        )
-        qs = lax.slice(slab, (1, 1, 0), (1 + xb, 1 + CZ, ncomp * cc))
+        with jax.named_scope(_SCOPE_GATHER):
+            slab = lax.dynamic_slice(
+                t3, (bi * xb, 0, 0), (xb + 2, czp, ncomp * cc)
+            )
+            qs = lax.slice(slab, (1, 1, 0),
+                           (1 + xb, 1 + CZ, ncomp * cc))
         qpx = _bits_f32(qs[..., :cc])
         qpz = _bits_f32(qs[..., cc:2 * cc])
         qw = qs[..., 2 * cc:3 * cc]
@@ -859,35 +878,38 @@ def _sweep_shift(
         dems = []
         for dx in range(3):
             for dz in range(3):
-                cs = lax.slice(
-                    slab, (dx, dz, 0), (dx + xb, dz + CZ, 3 * cc)
-                )
-                cpx = _bits_f32(cs[..., :cc])
-                cpz = _bits_f32(cs[..., cc:2 * cc])
-                cw = cs[..., 2 * cc:3 * cc]
-                cid = cw >> 2 if want_flags else cw
-                dist = jnp.maximum(
-                    jnp.abs(qpx[..., :, None] - cpx[..., None, :]),
-                    jnp.abs(qpz[..., :, None] - cpz[..., None, :]),
-                )
-                valid = (
-                    (cid[..., None, :] != sentinel)
-                    & (dist <= reach[..., :, None])
-                    & (cid[..., None, :] != qid[..., :, None])
-                )
-                keys.append(
-                    _pack_keys(
-                        spec, dist, valid, cw[..., None, :], want_flags,
-                        qmax=spec.radius + reach_pad,
+                with jax.named_scope(_SCOPE_GATHER):
+                    cs = lax.slice(
+                        slab, (dx, dz, 0), (dx + xb, dz + CZ, 3 * cc)
                     )
-                )
-                if with_stats:
-                    dems.append(valid.sum(-1, dtype=jnp.int32))
+                with jax.named_scope(_SCOPE_RANK):
+                    cpx = _bits_f32(cs[..., :cc])
+                    cpz = _bits_f32(cs[..., cc:2 * cc])
+                    cw = cs[..., 2 * cc:3 * cc]
+                    cid = cw >> 2 if want_flags else cw
+                    dist = jnp.maximum(
+                        jnp.abs(qpx[..., :, None] - cpx[..., None, :]),
+                        jnp.abs(qpz[..., :, None] - cpz[..., None, :]),
+                    )
+                    valid = (
+                        (cid[..., None, :] != sentinel)
+                        & (dist <= reach[..., :, None])
+                        & (cid[..., None, :] != qid[..., :, None])
+                    )
+                    keys.append(
+                        _pack_keys(
+                            spec, dist, valid, cw[..., None, :],
+                            want_flags, qmax=spec.radius + reach_pad,
+                        )
+                    )
+                    if with_stats:
+                        dems.append(valid.sum(-1, dtype=jnp.int32))
         rows = xb * CZ * cc
-        packed = jnp.concatenate(keys, axis=-1).reshape(rows, 9 * cc)
-        nbr_b, cnt_b, fl_b = _rank_packed(
-            packed, k, spec.topk_impl, want_flags, sentinel
-        )
+        with jax.named_scope(_SCOPE_RANK):
+            packed = jnp.concatenate(keys, axis=-1).reshape(rows, 9 * cc)
+            nbr_b, cnt_b, fl_b = _rank_packed(
+                packed, k, spec.topk_impl, want_flags, sentinel
+            )
         dem_b = (
             sum(dems).reshape(rows).astype(jnp.int32)
             if with_stats else jnp.zeros((rows,), jnp.int32)
@@ -1005,11 +1027,12 @@ def _sweep_fused(
     row_start, s_t = _build_ranges(cc, n_rows, srow, src, empty)
 
     # query-side scalars ([N]-sized, trivial next to the back half)
-    dxs = jnp.array([-1, 0, 1], jnp.int32)
-    starts = (cx[:, None] + dxs[None, :] + 1) * czp + cz[:, None]
-    starts = jnp.where(alive[:, None], starts, 0)  # border rows: empty
-    lo = row_start[starts]                          # [N, 3]
-    hi = row_start[starts + 3]
+    with jax.named_scope(_SCOPE_INDEX):
+        dxs = jnp.array([-1, 0, 1], jnp.int32)
+        starts = (cx[:, None] + dxs[None, :] + 1) * czp + cz[:, None]
+        starts = jnp.where(alive[:, None], starts, 0)  # border rows: empty
+        lo = row_start[starts]                          # [N, 3]
+        hi = row_start[starts + 3]
     if watch_radius is None:
         reach = jnp.full((n,), spec.radius + reach_pad, jnp.float32)
     else:
@@ -1043,50 +1066,52 @@ def _sweep_fused(
                 ]
             return carry
 
-        lax.fori_loop(0, b, gather_one, 0)
+        with jax.named_scope(_SCOPE_GATHER):
+            lax.fori_loop(0, b, gather_one, 0)
 
-        qx = qx_ref[0]
-        qz = qz_ref[0]
-        qreach = qr_ref[0]
-        qid = qid_ref[0]
-        lanes = lax.broadcasted_iota(jnp.int32, (b, 3 * cc), 1)
-        keys = []
-        dems = []
-        for dx in range(3):
-            cpx = _bits_f32(win_ref[0, :, dx, :])
-            cpz = _bits_f32(win_ref[1, :, dx, :])
-            cw = win_ref[2, :, dx, :]
-            # out-of-range lanes of a run may hold entities of OTHER
-            # cells (the sorted array is dense): hard-invalidate, same
-            # as the ranges impl
-            inr = lanes < (hi_ref[0, dx] - lo_ref[0, dx])[:, None]
-            cpx = jnp.where(inr, cpx, jnp.inf)
-            cw = jnp.where(inr, cw, table_sentinel)
-            dist = jnp.maximum(
-                jnp.abs(cpx - qx[:, None]), jnp.abs(cpz - qz[:, None])
-            )
-            cid = cw >> 2 if want_flags else cw
-            valid = (
-                (cid != sentinel)
-                & (dist <= qreach[:, None])
-                & (cid != qid[:, None])
-            )
-            keys.append(
-                _pack_keys(spec, dist, valid, cw, want_flags,
-                           qmax=spec.radius + reach_pad)
-            )
-            if with_stats:
-                dems.append(valid.sum(axis=1, dtype=jnp.int32))
-        packed = jnp.concatenate(keys, axis=1)        # [B, 9cc], VMEM
-        # unrolled exact min-extract (k is static): ascending ranked
-        # keys, exactly jnp.sort(packed)[:, :k] — valid keys are
-        # unique, so each pass retires exactly one lane
-        outs = []
-        for _j in range(k):
-            m = jnp.min(packed, axis=1)
-            outs.append(m)
-            packed = jnp.where(packed == m[:, None], invalid_key,
-                               packed)
+        with jax.named_scope(_SCOPE_RANK):
+            qx = qx_ref[0]
+            qz = qz_ref[0]
+            qreach = qr_ref[0]
+            qid = qid_ref[0]
+            lanes = lax.broadcasted_iota(jnp.int32, (b, 3 * cc), 1)
+            keys = []
+            dems = []
+            for dx in range(3):
+                cpx = _bits_f32(win_ref[0, :, dx, :])
+                cpz = _bits_f32(win_ref[1, :, dx, :])
+                cw = win_ref[2, :, dx, :]
+                # out-of-range lanes of a run may hold entities of OTHER
+                # cells (the sorted array is dense): hard-invalidate, same
+                # as the ranges impl
+                inr = lanes < (hi_ref[0, dx] - lo_ref[0, dx])[:, None]
+                cpx = jnp.where(inr, cpx, jnp.inf)
+                cw = jnp.where(inr, cw, table_sentinel)
+                dist = jnp.maximum(
+                    jnp.abs(cpx - qx[:, None]), jnp.abs(cpz - qz[:, None])
+                )
+                cid = cw >> 2 if want_flags else cw
+                valid = (
+                    (cid != sentinel)
+                    & (dist <= qreach[:, None])
+                    & (cid != qid[:, None])
+                )
+                keys.append(
+                    _pack_keys(spec, dist, valid, cw, want_flags,
+                               qmax=spec.radius + reach_pad)
+                )
+                if with_stats:
+                    dems.append(valid.sum(axis=1, dtype=jnp.int32))
+            packed = jnp.concatenate(keys, axis=1)        # [B, 9cc], VMEM
+            # unrolled exact min-extract (k is static): ascending ranked
+            # keys, exactly jnp.sort(packed)[:, :k] — valid keys are
+            # unique, so each pass retires exactly one lane
+            outs = []
+            for _j in range(k):
+                m = jnp.min(packed, axis=1)
+                outs.append(m)
+                packed = jnp.where(packed == m[:, None], invalid_key,
+                                   packed)
         top_ref[0] = jnp.stack(outs, axis=1)
         if with_stats:
             rest[0][0] = sum(dems)
@@ -1116,8 +1141,10 @@ def _sweep_fused(
     out_top = outs_pl[0]
     out_dem = outs_pl[1] if with_stats else None
 
-    top = out_top.reshape(padded, k)[:q]
-    nbr, cnt, fl = _unpack_top(top, invalid_key, want_flags, sentinel)
+    with jax.named_scope(_SCOPE_RANK):
+        top = out_top.reshape(padded, k)[:q]
+        nbr, cnt, fl = _unpack_top(top, invalid_key, want_flags,
+                                   sentinel)
     stats = None
     if with_stats:
         dem = out_dem.reshape(padded)[:q]
@@ -1192,7 +1219,8 @@ def _sweep(
     # sweeps only (_upto probes time the split f32 stages)
     q16 = (spec.precision != "off" and ranges_impl and packed_path
            and _upto is None)
-    qxz_plane = quantize_xz_i32(spec, pos) if q16 else None
+    with jax.named_scope(_SCOPE_INDEX):
+        qxz_plane = quantize_xz_i32(spec, pos) if q16 else None
     merged = None
     if ranges_impl:
         # TABLELESS (see GridSpec.sweep_impl): candidates come straight
@@ -1200,7 +1228,8 @@ def _sweep(
         if q16:
             # 2-component sorted view: packed (qx, qz) lattice pair +
             # flag word — 8 B/row streamed instead of 12
-            src = jnp.stack([qxz_plane[order], src[:, 2]], axis=1)
+            with jax.named_scope(_SCOPE_INDEX):
+                src = jnp.stack([qxz_plane[order], src[:, 2]], axis=1)
             row_start, s_t = _build_ranges(
                 cc, n_rows, srow, src, (0, table_sentinel)
             )
@@ -1213,25 +1242,26 @@ def _sweep(
             # premerge the 9 windows of every TRUE cell into one row:
             # 9 static slices of the padded table (no gather), so the
             # per-query fetch below is ONE contiguous row
-            cxs, czs = spec.cells_x, spec.cells_z
-            t3 = table.reshape(cxs + 2, czp, 3 * cc)
-            merged = jnp.concatenate(
-                [
-                    t3[dx:dx + cxs, dz:dz + czs]
-                    for dx in range(3) for dz in range(3)
-                ],
-                axis=-1,
-            ).reshape(cxs * czs, 9 * 3 * cc)
-            # dump row: dead / radius-0 queries fetch an all-empty
-            # window (the table impl reads border rows for them; cell
-            # (0, 0) would hold real candidates)
-            merged = jnp.concatenate(
-                [
-                    merged,
-                    jnp.tile(_init_row(empty, cc), 9)[None],
-                ],
-                axis=0,
-            )
+            with jax.named_scope(_SCOPE_INDEX):
+                cxs, czs = spec.cells_x, spec.cells_z
+                t3 = table.reshape(cxs + 2, czp, 3 * cc)
+                merged = jnp.concatenate(
+                    [
+                        t3[dx:dx + cxs, dz:dz + czs]
+                        for dx in range(3) for dz in range(3)
+                    ],
+                    axis=-1,
+                ).reshape(cxs * czs, 9 * 3 * cc)
+                # dump row: dead / radius-0 queries fetch an all-empty
+                # window (the table impl reads border rows for them; cell
+                # (0, 0) would hold real candidates)
+                merged = jnp.concatenate(
+                    [
+                        merged,
+                        jnp.tile(_init_row(empty, cc), 9)[None],
+                    ],
+                    axis=0,
+                )
 
     dxs = jnp.array([-1, 0, 1], jnp.int32)
     px = pos[:, 0]
@@ -1244,61 +1274,62 @@ def _sweep(
         # z-triple windows: for each x-offset, rows ((cx+dx+1)*czp + cz)
         # .. +2 are the contiguous (cz-1, cz, cz+1) padded cells. Dead
         # query rows read window 0 — border rows, all sentinel/empty.
-        starts = (cx[rows][:, None] + dxs[None, :] + 1) * czp \
-            + cz[rows][:, None]
-        starts = jnp.where(alive[rows][:, None], starts, 0)
+        with jax.named_scope(_SCOPE_GATHER):
+            starts = (cx[rows][:, None] + dxs[None, :] + 1) * czp \
+                + cz[rows][:, None]
+            starts = jnp.where(alive[rows][:, None], starts, 0)
 
-        if cellrow_impl:
-            rq = cx[rows] * spec.cells_z + cz[rows]
-            rq = jnp.where(alive[rows], rq,
-                           spec.cells_x * spec.cells_z)
-            win = jnp.take(merged, rq, axis=0).reshape(b, 9, 3 * cc)
-            cand_px = _bits_f32(win[:, :, :cc]).reshape(b, 9 * cc)
-            cand_pz = _bits_f32(win[:, :, cc:2 * cc]).reshape(b, 9 * cc)
-            cand_w = win[:, :, 2 * cc:].reshape(b, 9 * cc)
-        elif ranges_impl:
-            lo = row_start[starts]                   # [B, 3]
-            hi = row_start[starts + 3]
-            ncmp = 2 if q16 else 3
-            win = jax.vmap(
-                jax.vmap(
-                    lambda s: lax.dynamic_slice(
-                        s_t, (0, s), (ncmp, 3 * cc)
-                    ),
-                )
-            )(lo)                                    # [B, 3, C, 3cc]
-            if q16:
-                cand_qxz = win[:, :, 0, :].reshape(b, 9 * cc)
-                cand_px = cand_pz = None
-                cand_w = win[:, :, 1, :].reshape(b, 9 * cc)
+            if cellrow_impl:
+                rq = cx[rows] * spec.cells_z + cz[rows]
+                rq = jnp.where(alive[rows], rq,
+                               spec.cells_x * spec.cells_z)
+                win = jnp.take(merged, rq, axis=0).reshape(b, 9, 3 * cc)
+                cand_px = _bits_f32(win[:, :, :cc]).reshape(b, 9 * cc)
+                cand_pz = _bits_f32(win[:, :, cc:2 * cc]).reshape(b, 9 * cc)
+                cand_w = win[:, :, 2 * cc:].reshape(b, 9 * cc)
+            elif ranges_impl:
+                lo = row_start[starts]                   # [B, 3]
+                hi = row_start[starts + 3]
+                ncmp = 2 if q16 else 3
+                win = jax.vmap(
+                    jax.vmap(
+                        lambda s: lax.dynamic_slice(
+                            s_t, (0, s), (ncmp, 3 * cc)
+                        ),
+                    )
+                )(lo)                                    # [B, 3, C, 3cc]
+                if q16:
+                    cand_qxz = win[:, :, 0, :].reshape(b, 9 * cc)
+                    cand_px = cand_pz = None
+                    cand_w = win[:, :, 1, :].reshape(b, 9 * cc)
+                else:
+                    cand_px = _bits_f32(win[:, :, 0, :]).reshape(b, 9 * cc)
+                    cand_pz = _bits_f32(win[:, :, 1, :]).reshape(b, 9 * cc)
+                    cand_w = win[:, :, 2, :].reshape(b, 9 * cc)
+                lanes3 = jnp.arange(3 * cc, dtype=jnp.int32)
+                in_range = (
+                    lanes3[None, None, :] < (hi - lo)[:, :, None]
+                ).reshape(b, 9 * cc)
+                # out-of-range lanes may hold entities of OTHER cells (the
+                # sorted array is dense): hard-invalidate them — admitting
+                # one for some watchers but not others would make interest
+                # asymmetric. (The q16 path needs only the word kill: its
+                # validity never consults coordinates.)
+                if not q16:
+                    cand_px = jnp.where(in_range, cand_px, jnp.inf)
+                cand_w = jnp.where(in_range, cand_w, table_sentinel)
             else:
-                cand_px = _bits_f32(win[:, :, 0, :]).reshape(b, 9 * cc)
-                cand_pz = _bits_f32(win[:, :, 1, :]).reshape(b, 9 * cc)
-                cand_w = win[:, :, 2, :].reshape(b, 9 * cc)
-            lanes3 = jnp.arange(3 * cc, dtype=jnp.int32)
-            in_range = (
-                lanes3[None, None, :] < (hi - lo)[:, :, None]
-            ).reshape(b, 9 * cc)
-            # out-of-range lanes may hold entities of OTHER cells (the
-            # sorted array is dense): hard-invalidate them — admitting
-            # one for some watchers but not others would make interest
-            # asymmetric. (The q16 path needs only the word kill: its
-            # validity never consults coordinates.)
-            if not q16:
-                cand_px = jnp.where(in_range, cand_px, jnp.inf)
-            cand_w = jnp.where(in_range, cand_w, table_sentinel)
-        else:
-            win = jax.vmap(
-                jax.vmap(
-                    lambda s: lax.dynamic_slice(
-                        table, (s, 0), (3, 3 * cc)
-                    ),
-                )
-            )(starts)                                # [B, 3, 3, 3cc]
-            win = win.reshape(b, 9, 3 * cc)
-            cand_px = _bits_f32(win[:, :, :cc]).reshape(b, 9 * cc)
-            cand_pz = _bits_f32(win[:, :, cc:2 * cc]).reshape(b, 9 * cc)
-            cand_w = win[:, :, 2 * cc:].reshape(b, 9 * cc)
+                win = jax.vmap(
+                    jax.vmap(
+                        lambda s: lax.dynamic_slice(
+                            table, (s, 0), (3, 3 * cc)
+                        ),
+                    )
+                )(starts)                                # [B, 3, 3, 3cc]
+                win = win.reshape(b, 9, 3 * cc)
+                cand_px = _bits_f32(win[:, :, :cc]).reshape(b, 9 * cc)
+                cand_pz = _bits_f32(win[:, :, cc:2 * cc]).reshape(b, 9 * cc)
+                cand_w = win[:, :, 2 * cc:].reshape(b, 9 * cc)
 
         if _upto == "gather":
             return (
@@ -1306,70 +1337,71 @@ def _sweep(
                 + jnp.where(jnp.isfinite(cand_pz), cand_pz, 0.0).sum()
                 + cand_w.sum().astype(jnp.float32)
             )
-        if q16:
-            # int16-pair domain: |int diff| * step is the EXACT f32
-            # distance over lattice positions (see _q16_dist), so
-            # everything downstream — reach compare, key pack, top-k —
-            # is bit-identical to the f32 branch below
-            dist = _q16_dist(spec, cand_qxz, qxz_plane[rows][:, None])
-        else:
-            ddx = jnp.abs(cand_px - px[rows][:, None])
-            ddz = jnp.abs(cand_pz - pz[rows][:, None])
-            dist = jnp.maximum(ddx, ddz)             # Chebyshev XZ
-        if watch_radius is None:
-            reach = spec.radius + reach_pad
-        else:  # per-watcher view distance, bounded by the cell size
-            reach = (jnp.minimum(watch_radius[rows], spec.radius)
-                     + reach_pad)[:, None]
+        with jax.named_scope(_SCOPE_RANK):
+            if q16:
+                # int16-pair domain: |int diff| * step is the EXACT f32
+                # distance over lattice positions (see _q16_dist), so
+                # everything downstream — reach compare, key pack, top-k —
+                # is bit-identical to the f32 branch below
+                dist = _q16_dist(spec, cand_qxz, qxz_plane[rows][:, None])
+            else:
+                ddx = jnp.abs(cand_px - px[rows][:, None])
+                ddz = jnp.abs(cand_pz - pz[rows][:, None])
+                dist = jnp.maximum(ddx, ddz)             # Chebyshev XZ
+            if watch_radius is None:
+                reach = spec.radius + reach_pad
+            else:  # per-watcher view distance, bounded by the cell size
+                reach = (jnp.minimum(watch_radius[rows], spec.radius)
+                         + reach_pad)[:, None]
 
-        if packed_path:
-            cand_id = cand_w >> 2 if want_flags else cand_w
+            if packed_path:
+                cand_id = cand_w >> 2 if want_flags else cand_w
+                valid = (
+                    (cand_id != sentinel)
+                    & (dist <= reach)
+                    & (cand_id != rows[:, None])
+                )
+                packed_key = _pack_keys(spec, dist, valid, cand_w, want_flags,
+                                        qmax=spec.radius + reach_pad)
+                if _upto == "pack":
+                    return packed_key.sum().astype(jnp.float32)
+                nbr_b, cnt_b, fl_b = _rank_packed(
+                    packed_key, k, spec.topk_impl, want_flags, sentinel
+                )
+                if _upto == "rank":
+                    return nbr_b.sum().astype(jnp.float32) \
+                        + cnt_b.sum().astype(jnp.float32)
+                dem_b = (
+                    valid.sum(axis=1).astype(jnp.int32) if with_stats else None
+                )
+                return nbr_b, cnt_b, fl_b, dem_b
+
             valid = (
-                (cand_id != sentinel)
+                (cand_w != sentinel)
                 & (dist <= reach)
-                & (cand_id != rows[:, None])
+                & (cand_w != rows[:, None])
             )
-            packed_key = _pack_keys(spec, dist, valid, cand_w, want_flags,
-                                    qmax=spec.radius + reach_pad)
+            key = jnp.where(valid, dist, jnp.inf)
             if _upto == "pack":
-                return packed_key.sum().astype(jnp.float32)
-            nbr_b, cnt_b, fl_b = _rank_packed(
-                packed_key, k, spec.topk_impl, want_flags, sentinel
-            )
+                return jnp.where(jnp.isfinite(key), key, 0.0).sum()
+            top_val, top_idx = lax.top_k(-key, k)        # k nearest
+            nbr_b = jnp.take_along_axis(cand_w, top_idx, axis=1)
+            ok = jnp.isfinite(top_val)
+            nbr_b = jnp.where(ok, nbr_b, sentinel).astype(jnp.int32)
+            nbr_b = jnp.sort(nbr_b, axis=1)              # ascending ids
             if _upto == "rank":
-                return nbr_b.sum().astype(jnp.float32) \
-                    + cnt_b.sum().astype(jnp.float32)
-            dem_b = (
-                valid.sum(axis=1).astype(jnp.int32) if with_stats else None
-            )
-            return nbr_b, cnt_b, fl_b, dem_b
-
-        valid = (
-            (cand_w != sentinel)
-            & (dist <= reach)
-            & (cand_w != rows[:, None])
-        )
-        key = jnp.where(valid, dist, jnp.inf)
-        if _upto == "pack":
-            return jnp.where(jnp.isfinite(key), key, 0.0).sum()
-        top_val, top_idx = lax.top_k(-key, k)        # k nearest
-        nbr_b = jnp.take_along_axis(cand_w, top_idx, axis=1)
-        ok = jnp.isfinite(top_val)
-        nbr_b = jnp.where(ok, nbr_b, sentinel).astype(jnp.int32)
-        nbr_b = jnp.sort(nbr_b, axis=1)              # ascending ids
-        if _upto == "rank":
-            return nbr_b.sum().astype(jnp.float32)
-        fl_b = None
-        if want_flags:
-            # wide-id fallback: flags can't ride the word; one bounded
-            # gather over [B, k] recovers them (megaspace-scale only)
-            nbr_c = jnp.minimum(nbr_b, n - 1)
-            fl_b = jnp.where(
-                nbr_b == sentinel, 0,
-                flag_bits[nbr_c].astype(jnp.int32) & 3,
-            )
-        dem_b = valid.sum(axis=1).astype(jnp.int32) if with_stats else None
-        return nbr_b, ok.sum(axis=1).astype(jnp.int32), fl_b, dem_b
+                return nbr_b.sum().astype(jnp.float32)
+            fl_b = None
+            if want_flags:
+                # wide-id fallback: flags can't ride the word; one bounded
+                # gather over [B, k] recovers them (megaspace-scale only)
+                nbr_c = jnp.minimum(nbr_b, n - 1)
+                fl_b = jnp.where(
+                    nbr_b == sentinel, 0,
+                    flag_bits[nbr_c].astype(jnp.int32) & 3,
+                )
+            dem_b = valid.sum(axis=1).astype(jnp.int32) if with_stats else None
+            return nbr_b, ok.sum(axis=1).astype(jnp.int32), fl_b, dem_b
 
     # never let the block exceed the query count: a small space with the
     # default row_block would otherwise pad up to a full block and do
@@ -1623,32 +1655,48 @@ def _rank_candidates(
     qxz_plane = quantize_xz_i32(spec, pos) if q16 else None
 
     def row_block(rows: jax.Array):
+        # the reuse path's "window fetch" is the by-id gather of each
+        # cached candidate's current position (and flag bits); the
+        # scopes alternate so the traced op order stays what it was
+        gather = jax.named_scope(_SCOPE_GATHER)
+        rank = jax.named_scope(_SCOPE_RANK)
+        with gather:
+            if q16:
+                cb = unpack_ids21(cand[rows])          # [B, >=V]
+            else:
+                cb = cand[rows]                        # [B, V]
+            cbc = jnp.minimum(cb, n - 1)
+            cand_x = qxz_plane[cbc] if q16 else px[cbc]
         if q16:
-            cb = unpack_ids21(cand[rows])          # [B, >=V]
+            with rank:
+                dist = _q16_dist(spec, cand_x, qxz_plane[rows][:, None])
         else:
-            cb = cand[rows]                        # [B, V]
-        cbc = jnp.minimum(cb, n - 1)
-        if q16:
-            dist = _q16_dist(spec, qxz_plane[cbc],
-                             qxz_plane[rows][:, None])
-        else:
-            dist = jnp.maximum(
-                jnp.abs(px[cbc] - px[rows][:, None]),
-                jnp.abs(pz[cbc] - pz[rows][:, None]),
-            )
-        if watch_radius is None:
-            reach = spec.radius
-        else:
-            reach = jnp.minimum(watch_radius[rows], spec.radius)[:, None]
-        valid = (cb != sentinel) & (dist <= reach)
+            with rank:
+                ddx = jnp.abs(cand_x - px[rows][:, None])
+            with gather:
+                cand_z = pz[cbc]
+            with rank:
+                dist = jnp.maximum(
+                    ddx, jnp.abs(cand_z - pz[rows][:, None])
+                )
+        with rank:
+            if watch_radius is None:
+                reach = spec.radius
+            else:
+                reach = jnp.minimum(watch_radius[rows],
+                                    spec.radius)[:, None]
+            valid = (cb != sentinel) & (dist <= reach)
+            w = cb << 2 if want_flags else cb
         if want_flags:
-            w = (cb << 2) | (flag_bits[cbc].astype(jnp.int32) & 3)
-        else:
-            w = cb
-        packed = _pack_keys(spec, dist, valid, w, want_flags)
-        nbr_b, cnt_b, fl_b = _rank_packed(
-            packed, k, spec.topk_impl, want_flags, sentinel
-        )
+            with gather:
+                cand_fl = flag_bits[cbc]
+            with rank:
+                w = w | (cand_fl.astype(jnp.int32) & 3)
+        with rank:
+            packed = _pack_keys(spec, dist, valid, w, want_flags)
+            nbr_b, cnt_b, fl_b = _rank_packed(
+                packed, k, spec.topk_impl, want_flags, sentinel
+            )
         dem_b = valid.sum(axis=1).astype(jnp.int32) if with_stats \
             else jnp.zeros(rows.shape, jnp.int32)
         if fl_b is None:

@@ -268,25 +268,30 @@ def telemetry_update_live(acc, outs, *, mega: bool = False,
     output (one sample per shard per tick) and tracks ``occ_last``.
     Entirely on device: callers assert zero host syncs with
     ``jax.transfer_guard`` in the tests."""
+    import jax
     import jax.numpy as jnp
 
     TRACE_COUNTS["telemetry_update_live"] = \
         TRACE_COUNTS.get("telemetry_update_live", 0) + 1
     base = getattr(outs, "base", outs)
-    if mega:
-        # the ONE mega fold (shared with the multichip bench scan) so
-        # the live serving path and the bench path can never diverge
-        acc = telemetry_update_mega(acc, outs, base_ms)
-    else:
-        acc = telemetry_update(acc, live_signals(base), base_ms,
-                               delta_ms, half_skin)
-    if "occupancy" in acc:
-        occ = base.alive_count
-        acc = dict(acc)
-        acc["occupancy"] = _bucket_add_vec(acc["occupancy"],
-                                           COUNT_EDGES, occ)
-        acc["occ_last"] = occ.astype(jnp.int32).reshape(
-            acc["occ_last"].shape)
+    # the fold is its own program beside the tick's: a capture names
+    # it by this scope (ops/scopes.py)
+    with jax.named_scope("gw.telemetry"):
+        if mega:
+            # the ONE mega fold (shared with the multichip bench scan)
+            # so the live serving path and the bench path can never
+            # diverge
+            acc = telemetry_update_mega(acc, outs, base_ms)
+        else:
+            acc = telemetry_update(acc, live_signals(base), base_ms,
+                                   delta_ms, half_skin)
+        if "occupancy" in acc:
+            occ = base.alive_count
+            acc = dict(acc)
+            acc["occupancy"] = _bucket_add_vec(acc["occupancy"],
+                                               COUNT_EDGES, occ)
+            acc["occ_last"] = occ.astype(jnp.int32).reshape(
+                acc["occ_last"].shape)
     return acc
 
 

@@ -46,6 +46,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from goworld_tpu.ops.extract import bounded_extract
+from goworld_tpu.ops.scopes import scoped
 
 HALO_IMPLS = ("ppermute", "async")
 
@@ -148,6 +149,7 @@ def _ship(axis: str, n_dev: int, shift: int, perm, pack, recv_ok,
     return jax.tree.map(lambda t: lax.ppermute(t, axis, perm), pack)
 
 
+@scoped("gw.halo")
 def exchange_halo(
     axis: str,
     n_dev: int,
@@ -222,6 +224,7 @@ def exchange_halo(
     return gpos, gyaw, gdirty, gvalid, ggid, strip_demand
 
 
+@scoped("gw.halo")
 def exchange_halo_2d(
     axis: str,
     shape: tuple[int, int],   # (tx, tz) device grid over the flat axis

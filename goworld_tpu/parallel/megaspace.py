@@ -234,78 +234,82 @@ def make_mega_tick(mc: MegaConfig, mesh: Mesh, donate: bool = False):
 
         # 1. client inputs (global coords), behaviors, integrate over the
         #    WHOLE world extent (not the tile: movers cross borders freely).
-        pos, yaw, touched = apply_pos_inputs(
-            state.pos, state.yaw,
-            inputs.base.pos_sync_idx, inputs.base.pos_sync_vals,
-            inputs.base.pos_sync_n,
-        )
-        rng, k_behave = jax.random.split(state.rng)
-        # state.nbr holds GLOBAL gids (not local gather indices); the MLP
-        # observation instead reads state.nbr_cnt/nbr_mean_off — neighbor
-        # features computed over local+ghost positions by the PREVIOUS
-        # tick's AOI sweep (step 5 below)
-        tele = None
-        if cfg.scenario is not None:
-            # heterogeneous scenario mix (goworld_tpu/scenarios): the
-            # same vmapped lax.switch as tick_body, with the phase
-            # schedule anchored to WORLD bounds (the tile grid's
-            # extents are tile-local) and the neighbor features read
-            # from the summary lanes the previous tick's sweep left
-            # behind — gid neighbor lists can't feed the per-slot
-            # feature gathers. This is how the multichip bench's
-            # border_churn phase drives sustained tile crossings.
-            vel, tele_pos, tele = scenario_velocity(
-                cfg, k_behave, pos, yaw, state, policy,
-                bounds=(0.0, 0.0, mc.world_x, mc.world_z),
-                features=(
-                    state.nbr_mean_off,
-                    state.nbr_client_cnt.astype(jnp.float32),
-                    jnp.zeros_like(state.nbr_mean_off),
-                ),
+        with jax.named_scope("gw.inputs"):
+            pos, yaw, touched = apply_pos_inputs(
+                state.pos, state.yaw,
+                inputs.base.pos_sync_idx, inputs.base.pos_sync_vals,
+                inputs.base.pos_sync_n,
             )
-        else:
-            vel = compute_velocity(
-                cfg, k_behave, pos, yaw, state, policy,
-                (mc.world_x, mc.world_z), nbr=None, nbr_cnt=None,
+        with jax.named_scope("gw.behave"):
+            rng, k_behave = jax.random.split(state.rng)
+            # state.nbr holds GLOBAL gids (not local gather indices); the MLP
+            # observation instead reads state.nbr_cnt/nbr_mean_off — neighbor
+            # features computed over local+ghost positions by the PREVIOUS
+            # tick's AOI sweep (step 5 below)
+            tele = None
+            if cfg.scenario is not None:
+                # heterogeneous scenario mix (goworld_tpu/scenarios): the
+                # same vmapped lax.switch as tick_body, with the phase
+                # schedule anchored to WORLD bounds (the tile grid's
+                # extents are tile-local) and the neighbor features read
+                # from the summary lanes the previous tick's sweep left
+                # behind — gid neighbor lists can't feed the per-slot
+                # feature gathers. This is how the multichip bench's
+                # border_churn phase drives sustained tile crossings.
+                vel, tele_pos, tele = scenario_velocity(
+                    cfg, k_behave, pos, yaw, state, policy,
+                    bounds=(0.0, 0.0, mc.world_x, mc.world_z),
+                    features=(
+                        state.nbr_mean_off,
+                        state.nbr_client_cnt.astype(jnp.float32),
+                        jnp.zeros_like(state.nbr_mean_off),
+                    ),
+                )
+            else:
+                vel = compute_velocity(
+                    cfg, k_behave, pos, yaw, state, policy,
+                    (mc.world_x, mc.world_z), nbr=None, nbr_cnt=None,
+                )
+        with jax.named_scope("gw.integrate"):
+            pos, moved = integrate(
+                pos, vel, state.npc_moving, cfg.dt,
+                (0.0, -1e9, 0.0), (mc.world_x, 1e9, mc.world_z),
             )
-        pos, moved = integrate(
-            pos, vel, state.npc_moving, cfg.dt,
-            (0.0, -1e9, 0.0), (mc.world_x, 1e9, mc.world_z),
-        )
-        if tele is not None:
-            # teleports override the integrated position BEFORE tile
-            # targeting, so a cross-tile jump migrates on this tick
-            pos = jnp.where(tele[:, None], tele_pos, pos)
-            moved = moved | tele
-        state = state.replace(pos=pos, yaw=yaw, vel=vel, rng=rng)
-        pre_dirty = (moved | touched | state.dirty) & state.alive
+            if tele is not None:
+                # teleports override the integrated position BEFORE tile
+                # targeting, so a cross-tile jump migrates on this tick
+                pos = jnp.where(tele[:, None], tele_pos, pos)
+                moved = moved | tele
+            state = state.replace(pos=pos, yaw=yaw, vel=vel, rng=rng)
+            pre_dirty = (moved | touched | state.dirty) & state.alive
 
         # 2. automatic tile migration from position (x strip in 1D;
         #    (ix, iz) tile in 2D).
-        tgt_ix = jnp.clip(
-            jnp.floor(pos[:, 0] / mc.tile_w).astype(jnp.int32), 0, tx - 1
-        )
-        if mc.is_2d:
-            tgt_iz = jnp.clip(
-                jnp.floor(pos[:, 2] / mc.tile_d).astype(jnp.int32),
-                0, tz - 1,
+        with jax.named_scope("gw.migrate"):
+            tgt_ix = jnp.clip(
+                jnp.floor(pos[:, 0] / mc.tile_w).astype(jnp.int32), 0, tx - 1
             )
-            tgt = tgt_ix * tz + tgt_iz
-        else:
-            tgt = tgt_ix
-        tgt = jnp.where(state.alive & (tgt != d), tgt, -1)
-        tag = d * n + jnp.arange(n, dtype=jnp.int32)   # old gid as tag
-        fbuf, ibuf, departed, mig_demand = mig.pack_emigrants(
-            state, tgt, tag, n_dev, mc.migrate_cap
-        )
-        state = mig.despawn_departed(state, departed)
-        pre_dirty &= ~departed
-        fbuf = jax.lax.all_to_all(fbuf, SPACE_AXIS, 0, 0, tiled=True)
-        ibuf = jax.lax.all_to_all(ibuf, SPACE_AXIS, 0, 0, tiled=True)
-        state, arr_tag, arr_slot, arr_n, dropped = mig.insert_arrivals(
-            state, fbuf, ibuf, nbr_sentinel=gsent, quarantine=departed
-        )
-        dirty = pre_dirty | state.dirty   # arrivals force-sync
+            if mc.is_2d:
+                tgt_iz = jnp.clip(
+                    jnp.floor(pos[:, 2] / mc.tile_d).astype(jnp.int32),
+                    0, tz - 1,
+                )
+                tgt = tgt_ix * tz + tgt_iz
+            else:
+                tgt = tgt_ix
+            tgt = jnp.where(state.alive & (tgt != d), tgt, -1)
+            tag = d * n + jnp.arange(n, dtype=jnp.int32)   # old gid as tag
+            fbuf, ibuf, departed, mig_demand = mig.pack_emigrants(
+                state, tgt, tag, n_dev, mc.migrate_cap
+            )
+            state = mig.despawn_departed(state, departed)
+            pre_dirty &= ~departed
+            fbuf = jax.lax.all_to_all(fbuf, SPACE_AXIS, 0, 0, tiled=True)
+            ibuf = jax.lax.all_to_all(ibuf, SPACE_AXIS, 0, 0, tiled=True)
+            state, arr_tag, arr_slot, arr_n, dropped = mig.insert_arrivals(
+                state, fbuf, ibuf, nbr_sentinel=gsent, quarantine=departed
+            )
+            dirty = pre_dirty | state.dirty   # arrivals force-sync
 
         # 3. halo ghost exchange (ring ppermute). AOI-excluded entities
         #    (aoi_radius <= 0, e.g. service types) never ship as ghosts —
@@ -327,83 +331,87 @@ def make_mega_tick(mc: MegaConfig, mesh: Mesh, donate: bool = False):
         # 4. AOI over the extended local+ghost population, in tile-shifted
         #    coordinates so the static grid covers [0, tile_w + 2R)
         #    (x [0, tile_d + 2R) in z for 2D tiles).
-        pos_ext = jnp.concatenate([state.pos, gpos])
-        shift = jnp.array([0.0, 0.0, 0.0], jnp.float32) \
-            .at[0].set(tile_min - radius)
-        if mc.is_2d:
-            shift = shift.at[2].set(tile_min_z - radius)
-        alive_ext = jnp.concatenate([state.alive, gvalid])
-        # ghosts already passed the source-side visibility filter: give
-        # them +inf so only the local per-entity radii gate here
-        wr_ext = jnp.concatenate([
-            state.aoi_radius,
-            jnp.full((ghost_rows,), jnp.inf, jnp.float32),
-        ])
-        # ghosts are candidates but never watchers: query only local rows.
-        # Dirty and has_client bits (local + ghost) ride the sweep so sync
-        # collection needs no [N, k] dirty gather and the behavior tree
-        # gets its players-in-AOI count for free. Halo records don't carry
-        # has_client, so remote-tile clients read as NPCs to the
-        # behavior tree (boundary approximation; transport.py-level parity
-        # is unaffected — sync/interest never consult bit 1 of ghosts).
-        dirty_ext = jnp.concatenate([dirty, gdirty])
-        hc_ext = jnp.concatenate([
-            state.has_client,
-            jnp.zeros((ghost_rows,), bool),
-        ])
-        nbr_ext, nbr_cnt, nbr_fl, aoi_stats = grid_neighbors_flags(
-            cfg.grid, pos_ext - shift, alive_ext, query_rows=n,
-            watch_radius=wr_ext,
-            flag_bits=dirty_ext.astype(jnp.int32)
-            | (hc_ext.astype(jnp.int32) << 1),
-            with_stats=True,
-        )
+        with jax.named_scope("gw.aoi"):
+            pos_ext = jnp.concatenate([state.pos, gpos])
+            shift = jnp.array([0.0, 0.0, 0.0], jnp.float32) \
+                .at[0].set(tile_min - radius)
+            if mc.is_2d:
+                shift = shift.at[2].set(tile_min_z - radius)
+            alive_ext = jnp.concatenate([state.alive, gvalid])
+            # ghosts already passed the source-side visibility filter: give
+            # them +inf so only the local per-entity radii gate here
+            wr_ext = jnp.concatenate([
+                state.aoi_radius,
+                jnp.full((ghost_rows,), jnp.inf, jnp.float32),
+            ])
+            # ghosts are candidates but never watchers: query only local rows.
+            # Dirty and has_client bits (local + ghost) ride the sweep so sync
+            # collection needs no [N, k] dirty gather and the behavior tree
+            # gets its players-in-AOI count for free. Halo records don't carry
+            # has_client, so remote-tile clients read as NPCs to the
+            # behavior tree (boundary approximation; transport.py-level parity
+            # is unaffected — sync/interest never consult bit 1 of ghosts).
+            dirty_ext = jnp.concatenate([dirty, gdirty])
+            hc_ext = jnp.concatenate([
+                state.has_client,
+                jnp.zeros((ghost_rows,), bool),
+            ])
+            nbr_ext, nbr_cnt, nbr_fl, aoi_stats = grid_neighbors_flags(
+                cfg.grid, pos_ext - shift, alive_ext, query_rows=n,
+                watch_radius=wr_ext,
+                flag_bits=dirty_ext.astype(jnp.int32)
+                | (hc_ext.astype(jnp.int32) << 1),
+                with_stats=True,
+            )
 
         # 5. neighbor features for next tick's MLP observation (computed
         #    HERE because nbr_ext still indexes pos_ext; after the gid
         #    translation below the positions are no longer addressable),
         #    then translate to stable GLOBAL ids and diff.
-        p_ext = n + ghost_rows
-        wants_features = (
-            cfg.behavior in ("mlp", "btree")
-            if cfg.scenario is None else cfg.scenario.needs_features
-        )
-        if wants_features:  # static at trace time
-            mean_off = neighbor_mean_offset(
-                pos_ext, state.pos, nbr_ext, nbr_cnt, p_ext
+        with jax.named_scope("gw.delta"):
+            p_ext = n + ghost_rows
+            wants_features = (
+                cfg.behavior in ("mlp", "btree")
+                if cfg.scenario is None else cfg.scenario.needs_features
             )
-        else:
-            # nothing reads the features: skip the [N, k, 3] gather
-            # (gathers are the scarce resource on TPU)
-            mean_off = state.nbr_mean_off
-        gid_ext = jnp.concatenate(
-            [d * n + jnp.arange(n, dtype=jnp.int32), ggid]
-        )
-        nbr_gid = jnp.where(
-            nbr_ext == p_ext, gsent,
-            gid_ext[jnp.minimum(nbr_ext, p_ext - 1)],
-        )
-        nbr_gid = jnp.sort(nbr_gid, axis=1)
-        (enter_w, enter_j, enter_n, leave_w, leave_j, leave_n,
-         delta_rows_n) = interest_pairs(
-            state.nbr, nbr_gid, gsent, cfg.enter_cap, cfg.leave_cap,
-            min(cfg.delta_rows_cap_eff, n),
-        )
+            if wants_features:  # static at trace time
+                mean_off = neighbor_mean_offset(
+                    pos_ext, state.pos, nbr_ext, nbr_cnt, p_ext
+                )
+            else:
+                # nothing reads the features: skip the [N, k, 3] gather
+                # (gathers are the scarce resource on TPU)
+                mean_off = state.nbr_mean_off
+            gid_ext = jnp.concatenate(
+                [d * n + jnp.arange(n, dtype=jnp.int32), ggid]
+            )
+            nbr_gid = jnp.where(
+                nbr_ext == p_ext, gsent,
+                gid_ext[jnp.minimum(nbr_ext, p_ext - 1)],
+            )
+            nbr_gid = jnp.sort(nbr_gid, axis=1)
+            (enter_w, enter_j, enter_n, leave_w, leave_j, leave_n,
+             delta_rows_n) = interest_pairs(
+                state.nbr, nbr_gid, gsent, cfg.enter_cap, cfg.leave_cap,
+                min(cfg.delta_rows_cap_eff, n),
+            )
 
         # 6. sync records over the extended population; subjects -> gids.
-        yaw_ext = jnp.concatenate([state.yaw, gyaw])
-        sync_w, sync_j, sync_vals, sync_n = collect_sync(
-            nbr_ext, dirty_ext, state.has_client, pos_ext, yaw_ext,
-            cfg.sync_cap, nbr_dirty=(nbr_fl & 1).astype(bool),
-        )
-        sync_j = jnp.where(
-            sync_j >= 0, gid_ext[jnp.clip(sync_j, 0, p_ext - 1)], -1
-        )
+        with jax.named_scope("gw.sync"):
+            yaw_ext = jnp.concatenate([state.yaw, gyaw])
+            sync_w, sync_j, sync_vals, sync_n = collect_sync(
+                nbr_ext, dirty_ext, state.has_client, pos_ext, yaw_ext,
+                cfg.sync_cap, nbr_dirty=(nbr_fl & 1).astype(bool),
+            )
+            sync_j = jnp.where(
+                sync_j >= 0, gid_ext[jnp.clip(sync_j, 0, p_ext - 1)], -1
+            )
 
         # 7. attr deltas (local only; ghosts' attrs sync on their own shard).
-        attr_e, attr_i, attr_v, attr_n = collect_attr_deltas(
-            state.hot_attrs, state.attr_dirty, cfg.attr_sync_cap
-        )
+        with jax.named_scope("gw.attrs"):
+            attr_e, attr_i, attr_v, attr_n = collect_attr_deltas(
+                state.hot_attrs, state.attr_dirty, cfg.attr_sync_cap
+            )
 
         global_alive = jax.lax.psum(
             state.alive.sum().astype(jnp.int32), SPACE_AXIS

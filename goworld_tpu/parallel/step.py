@@ -81,21 +81,25 @@ def make_multi_tick(cfg: WorldConfig, mesh: Mesh, migrate_cap: int = 256,
         state, outs = tick_body(cfg, state, inputs.base, policy)
 
         # --- migration: pack -> all_to_all over ICI -> insert ------------
-        fbuf, ibuf, departed, demand = mig.pack_emigrants(
-            state, inputs.migrate_target, inputs.migrate_tag,
-            n_dev, migrate_cap,
-        )
-        state = mig.despawn_departed(state, departed)
-        fbuf = jax.lax.all_to_all(
-            fbuf, SPACE_AXIS, split_axis=0, concat_axis=0, tiled=True
-        )
-        ibuf = jax.lax.all_to_all(
-            ibuf, SPACE_AXIS, split_axis=0, concat_axis=0, tiled=True
-        )
-        state, arr_tag, arr_slot, arr_n, dropped = mig.insert_arrivals(
-            state, fbuf, ibuf, nbr_sentinel=cfg.capacity,
-            quarantine=departed,
-        )
+        with jax.named_scope("gw.migrate"):
+            fbuf, ibuf, departed, demand = mig.pack_emigrants(
+                state, inputs.migrate_target, inputs.migrate_tag,
+                n_dev, migrate_cap,
+            )
+            state = mig.despawn_departed(state, departed)
+            fbuf = jax.lax.all_to_all(
+                fbuf, SPACE_AXIS, split_axis=0, concat_axis=0,
+                tiled=True,
+            )
+            ibuf = jax.lax.all_to_all(
+                ibuf, SPACE_AXIS, split_axis=0, concat_axis=0,
+                tiled=True,
+            )
+            state, arr_tag, arr_slot, arr_n, dropped = \
+                mig.insert_arrivals(
+                    state, fbuf, ibuf, nbr_sentinel=cfg.capacity,
+                    quarantine=departed,
+                )
 
         # --- global stats over the mesh (one psum) -----------------------
         global_alive = jax.lax.psum(

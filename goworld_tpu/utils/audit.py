@@ -556,7 +556,11 @@ class AuditPlane:
                 self._q.task_done()
                 return
             try:
-                job()
+                # on a profiler capture the worker's share of a frame
+                # shows on its own thread's line, beside the logic
+                # thread's gw.audit_sample
+                with metrics.annotation("gw.audit_judge"):
+                    job()
             except Exception:
                 logger.exception(
                     "[%s] audit worker job failed", self.name)

@@ -10,14 +10,22 @@ that attribution as an always-on subsystem:
   histograms. Lock-protected, labels rendered as name suffixes
   (``name{k="v"}``), exported in Prometheus text exposition format
   (served by ``debug_http`` as ``/metrics``).
-* :class:`TickTimeline` — a ring buffer of per-tick phase spans
-  (drain-inputs / device-step / fetch-outputs / fan-out, with the
-  jitted step's timing folded in as tick args), exportable as Chrome
-  ``chrome://tracing`` / Perfetto JSON (served as ``/trace``).
+* :class:`TickTimeline` — the ONE host span substrate: a ring buffer
+  of per-tick phase spans (drain-inputs / device-step / fetch-outputs /
+  fan-out, with the jitted step's timing folded in as tick args). Every
+  span is read three ways: Chrome ``chrome://tracing`` / Perfetto JSON
+  (served as ``/trace``), the labelled histogram
+  ``tick_phase_ms{phase="<span>"}`` in ``/metrics`` (observed when the
+  tick closes, plus ``phase="unspanned"`` for what no span covered),
+  and — in the process that called :func:`set_annotation` — a profiler
+  annotation ``gw.<span>`` on the capture's own clock, so a
+  ``/profile`` capture shows host spans beside the device's ops.
 
-Overhead budget: one span is two ``perf_counter`` calls and one tuple
-append; a full game tick records ~6 spans — microseconds against the
-16 ms frame (< 0.1%), so the recorder stays on unconditionally.
+Overhead budget: one span is two ``perf_counter`` calls, one tuple
+append, one histogram observation and (with the hook set and no
+capture running) one no-op annotation; a full game tick records ~10
+spans — microseconds against the 16 ms frame (< 0.2%), so the
+recorder stays on unconditionally.
 
 Metric naming scheme (see docs/OBSERVABILITY.md):
 ``<subsystem>_<what>_<unit|total>`` — e.g. ``tick_latency_ms``,
@@ -28,7 +36,7 @@ Metric naming scheme (see docs/OBSERVABILITY.md):
 from __future__ import annotations
 
 import bisect
-import json
+import contextlib
 import os
 import threading
 import time
@@ -38,6 +46,7 @@ from typing import Any
 __all__ = [
     "Counter", "Gauge", "Histogram", "Registry", "TickTimeline",
     "REGISTRY", "counter", "gauge", "histogram", "timeline",
+    "set_annotation", "annotation",
     "DEFAULT_MS_BUCKETS", "DEFAULT_SIZE_BUCKETS",
     "parse_prometheus_text",
 ]
@@ -215,6 +224,9 @@ class Registry:
     def __init__(self):
         self._lock = threading.Lock()
         self._families: dict[str, _Family] = {}
+        # bumped by reset(): holders of cached children (the timeline's
+        # per-phase histograms) look theirs up again
+        self.generation = 0
 
     def _get(self, kind: str, name: str, help_: str, buckets,
              labels: dict[str, str]):
@@ -309,37 +321,83 @@ class Registry:
         """Drop every registered metric (tests)."""
         with self._lock:
             self._families.clear()
+            self.generation += 1
 
 
 # =======================================================================
 # per-tick phase timeline
 # =======================================================================
+# Profiler annotation class (``jax.profiler.TraceAnnotation``), set by
+# the process that owns the device (api.run, where jax is imported
+# anyway). This module is imported by the gate and the dispatcher too,
+# which must map neither jaxlib nor libtpu: it never imports jax, and
+# with no hook set a span is exactly a host-clock span.
+_annotation_cls = None
+
+
+def set_annotation(cls) -> None:
+    """Install (or, with ``None``, remove) the class every span also
+    enters: ``cls(name, **kwargs)`` must be a context manager. With no
+    capture running a ``TraceAnnotation`` is a flag test."""
+    global _annotation_cls
+    _annotation_cls = cls
+
+
+_NO_ANNOTATION = contextlib.nullcontext()
+
+
+def annotation(name: str, **kwargs):
+    """``with metrics.annotation("gw.audit_judge"): ...`` — a profiler
+    annotation only (no span, no histogram), for threads other than the
+    logic thread. A no-op where no hook is set."""
+    cls = _annotation_cls
+    if cls is None:
+        return _NO_ANNOTATION
+    return cls(name, **kwargs)
+
+
 class _Span:
     """``with timeline.span("device_step"): ...`` — records a phase span
-    into the currently open tick. No-op when no tick is open."""
+    into the currently open tick. No-op when no tick is open. A LONE
+    span (``timeline.lone_span``) belongs to no tick record: it is the
+    annotation and the histogram observation only. ``t0`` / ``t1`` are
+    the ``perf_counter`` readings at entry and exit (taken in every
+    case), for a caller that marks the same instants."""
 
-    __slots__ = ("_tl", "_name", "_args", "_t0")
+    __slots__ = ("_tl", "_name", "_args", "_ann", "_lone", "t0", "t1")
 
-    def __init__(self, tl: "TickTimeline | None", name: str, args):
+    def __init__(self, tl: "TickTimeline | None", name: str, args,
+                 lone: bool = False):
         self._tl = tl
         self._name = name
         self._args = args
+        self._lone = lone
 
     def __enter__(self) -> "_Span":
-        self._t0 = time.perf_counter()
+        cls = _annotation_cls
+        if cls is not None and self._tl is not None:
+            self._ann = cls("gw." + self._name)
+            self._ann.__enter__()
+        else:
+            self._ann = None
+        self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc) -> None:
+        self.t1 = t1 = time.perf_counter()
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
         tl = self._tl
         if tl is None:
+            return
+        if self._lone:
+            tl._phase_hist(self._name).observe((t1 - self.t0) * 1e3)
             return
         open_ = tl._open
         if open_ is None:
             return
-        start = self._t0 - open_[1]
         open_[2].append(
-            (self._name, start, time.perf_counter() - self._t0,
-             self._args)
+            (self._name, self.t0 - open_[1], t1 - self.t0, self._args)
         )
 
 
@@ -355,21 +413,51 @@ class TickTimeline:
         self.capacity = capacity
         self._recs: deque = deque(maxlen=capacity)
         self._lock = threading.Lock()
-        # open tick: [wall_us, perf_t0, spans, args]
+        # open tick: [wall_us, perf_t0, spans, args, frame annotation]
         self._open: list | None = None
+        # tick_phase_ms{phase=...} children, looked up once per name
+        # (and again after a Registry.reset())
+        self._hists: dict[str, Histogram] = {}
+        self._hists_gen = -1
 
     @property
     def is_open(self) -> bool:
         return self._open is not None
 
-    def begin_tick(self) -> None:
-        """Open a tick record; an unclosed previous tick is discarded."""
-        self._open = [time.time() * 1e6, time.perf_counter(), [], {}]
+    def begin_tick(self, tick: int | None = None) -> float:
+        """Open a tick record (``gw.frame`` on a profiler capture,
+        with the tick's number where the caller knows it); an unclosed
+        previous tick is discarded. Returns the record's first instant
+        (``perf_counter``), so a caller that marks the same boundary
+        takes no second clock reading."""
+        self._close_frame()
+        ann = None
+        cls = _annotation_cls
+        if cls is not None:
+            ann = cls("gw.frame") if tick is None \
+                else cls("gw.frame", tick=tick)
+            ann.__enter__()
+        t0 = time.perf_counter()
+        self._open = [time.time() * 1e6, t0, [], {}, ann]
+        return t0
+
+    def _close_frame(self) -> None:
+        open_ = self._open
+        if open_ is not None and open_[4] is not None:
+            open_[4].__exit__(None, None, None)
+            open_[4] = None
 
     def span(self, name: str, **args) -> _Span:
         if self._open is None:
             return _NULL_SPAN
         return _Span(self, name, args or None)
+
+    def lone_span(self, name: str) -> _Span:
+        """A span OUTSIDE the tick record (the serve loop's pacing
+        sleep, the governor's observation after the tick has closed):
+        a profiler annotation and a ``tick_phase_ms`` observation, in
+        no record and in no tick's duration."""
+        return _Span(self, name, None, lone=True)
 
     def set_tick_args(self, **kw) -> None:
         """Fold extra attribution (e.g. the jitted step's phase timing)
@@ -377,12 +465,36 @@ class TickTimeline:
         if self._open is not None:
             self._open[3].update(kw)
 
+    def _phase_hist(self, phase: str) -> Histogram:
+        if self._hists_gen != REGISTRY.generation:
+            self._hists = {}
+            self._hists_gen = REGISTRY.generation
+        h = self._hists.get(phase)
+        if h is None:
+            h = self._hists[phase] = REGISTRY.histogram(
+                "tick_phase_ms",
+                help="serve-loop tick wall time by timeline span "
+                     "(unspanned: under no span)",
+                phase=phase)
+        return h
+
     def end_tick(self) -> float | None:
-        """Close the open tick; returns its wall duration in seconds."""
+        """Close the open tick; returns its wall duration in seconds.
+        Every span of the record is observed into
+        ``tick_phase_ms{phase}``, and the rest of the tick into
+        ``phase="unspanned"``: summed over phases that is the tick's
+        duration again, over every tick served and not only over the
+        ring's."""
+        self._close_frame()
         open_, self._open = self._open, None
         if open_ is None:
             return None
         dur = time.perf_counter() - open_[1]
+        covered = 0.0
+        for name, _start, sdur, _args in open_[2]:
+            covered += sdur
+            self._phase_hist(name).observe(sdur * 1e3)
+        self._phase_hist("unspanned").observe((dur - covered) * 1e3)
         with self._lock:
             self._recs.append((open_[0], dur, open_[2], open_[3]))
         return dur
@@ -394,6 +506,7 @@ class TickTimeline:
     def clear(self) -> None:
         with self._lock:
             self._recs.clear()
+        self._close_frame()
         self._open = None
 
     def coverage(self) -> float:
@@ -434,9 +547,6 @@ class TickTimeline:
                     ev["args"] = sargs
                 events.append(ev)
         return {"traceEvents": events, "displayTimeUnit": "ms"}
-
-    def chrome_trace_json(self, process_name: str = "goworld_tpu") -> str:
-        return json.dumps(self.chrome_trace(process_name))
 
 
 # =======================================================================

@@ -401,6 +401,12 @@ class ClassQueues:
     too). Overflow drops the *incoming* packet of the overflowing class
     (bounds are per class, so a sync flood can never evict an RPC) and
     counts it in ``shed_total{class,stage}``.
+
+    The wait is counted: ``offer`` stamps the item (``perf_counter``),
+    ``pop`` / ``drain`` observe now minus the stamp into
+    ``<stage>_wait_ms{class}`` (``game_queue_wait_ms`` for the game's
+    queue) — one clock read at each end, the time a packet stood
+    between the network thread and the pump.
     """
 
     def __init__(self, bounds: dict[int, int] | None = None,
@@ -419,6 +425,14 @@ class ClassQueues:
         self._qs: tuple[deque, ...] = tuple(
             deque() for _ in range(N_CLASSES)
         )
+        self._m_wait = tuple(
+            metrics.histogram(
+                f"{stage}_wait_ms",
+                help="time a packet stood in its class queue (offer "
+                     "to pop)",
+                **{"class": CLASS_NAMES[cls]},
+            ) for cls in range(N_CLASSES)
+        )
 
     def offer(self, cls: int, item: Any) -> bool:
         """Enqueue; False (and a counted drop) when the class is full."""
@@ -426,29 +440,34 @@ class ClassQueues:
         if len(q) >= self.bounds[cls]:
             shed_counter(cls, self.stage).inc()
             return False
-        q.append(item)
+        q.append((time.perf_counter(), item))
         return True
 
     def drain(self) -> "list[Any]":
         """Pop everything, highest priority class first (within a
         class, FIFO)."""
         out: list[Any] = []
-        for q in self._qs:
+        now = time.perf_counter()
+        for q, wait in zip(self._qs, self._m_wait):
             while True:
                 try:
-                    out.append(q.popleft())
+                    t, item = q.popleft()
                 except IndexError:
                     break
+                wait.observe((now - t) * 1e3)
+                out.append(item)
         return out
 
     def pop(self) -> Any:
         """Pop one item from the highest-priority non-empty class;
         raises IndexError when empty."""
-        for q in self._qs:
+        for q, wait in zip(self._qs, self._m_wait):
             try:
-                return q.popleft()
+                t, item = q.popleft()
             except IndexError:
                 continue
+            wait.observe((time.perf_counter() - t) * 1e3)
+            return item
         raise IndexError("all class queues empty")
 
     def qsize(self) -> int:
