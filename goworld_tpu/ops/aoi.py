@@ -315,10 +315,11 @@ class GridSpec:
     #              (ops/pallas_compat.FUSED_SWEEP_REFUSAL). Packed-id
     #              fast path only (n < 2^21); wide worlds fall back to
     #              "ranges".
-    # The default literal lives in consts.DEFAULT_SWEEP_IMPL ("ranges",
-    # the r4 measured winner) — one source of truth shared with
-    # GameConfig.aoi_sweep_impl and bench.py, so kernel-level GridSpec
-    # users can't silently get a slower impl than the production stack.
+    # The default literal lives in consts.DEFAULT_SWEEP_IMPL ("cellrow",
+    # decided by the chip: the reason is written there) — one source of
+    # truth shared with GameConfig.aoi_sweep_impl and bench.py, so
+    # kernel-level GridSpec users can't silently get a slower impl than
+    # the production stack.
     sweep_impl: str = consts.DEFAULT_SWEEP_IMPL
     # Front-half cell-sort lowering:
     #   "argsort"  — XLA's generic sort (a ~0.5*log2(n)^2-pass bitonic
@@ -1213,10 +1214,11 @@ def _sweep(
     # sibling "ranges" (the fused kernel packs ids into key words)
     ranges_impl = spec.sweep_impl in ("ranges", "fused")
     cellrow_impl = spec.sweep_impl == "cellrow"
-    # the packed int16-pair fast path: "ranges" only (the default /
-    # production impl; the fused kernel already keeps its window in
-    # VMEM, the table impls keep the shared f32 table layout), real
-    # sweeps only (_upto probes time the split f32 stages)
+    # the packed int16-pair fast path: "ranges" only (the fused kernel
+    # already keeps its window in VMEM; the table impls, the default
+    # "cellrow" among them, keep the shared f32 table layout and sweep
+    # the snapped positions through it), real sweeps only (_upto probes
+    # time the split f32 stages)
     q16 = (spec.precision != "off" and ranges_impl and packed_path
            and _upto is None)
     with jax.named_scope(_SCOPE_INDEX):

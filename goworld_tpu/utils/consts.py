@@ -25,12 +25,20 @@ DEFAULT_ROW_BLOCK = 32768         # AOI row-block size (memory ceiling knob)
 # The ONE source of truth for the AOI sweep/top-k implementation
 # defaults. GridSpec (kernel level), GameConfig.aoi_* (ini level) and
 # bench.py all draw from here so a direct GridSpec user gets the same
-# measured-winner config the production stack runs (r4 A/B: "ranges"
-# beat "table" by ~18% on CPU and is fidelity-identical-or-better —
-# its pooled 3*cell_cap triple cap only ever ADMITS candidates the
-# per-cell cap dropped; "sort" ranking is exact under every workload
-# and was ~2.5x the generic int32 lax.top_k on both platforms).
-DEFAULT_SWEEP_IMPL = "ranges"
+# config the production stack runs. The CHIP decides the sweep, not a
+# CPU A/B: on a TPU v5e at the 131,072 shard the compiler expands the
+# per-query windowed dynamic_slice of "ranges" / "table" into a loop of
+# ~1.2M tiny slice-and-update ops a tick (565 / 406 ms the sweep, 91%
+# of the served tick's device time: PERF.md), while "cellrow" fetches
+# each query's whole candidate pool as ONE contiguous row of a
+# premerged per-cell block (28 ms the sweep, docs/chip_logs/pr21). It
+# is bit-identical to "table"; against "ranges" it differs only past
+# the cap (per-cell cap vs the pooled 3*cell_cap of a z-triple), where
+# the cell gauge (aoi_over_cap_cells) fires either way. Its block grows
+# with cells_x*cells_z, not with n (9x the table's bytes): a sparse
+# world pays for empty cells. "sort" ranking is exact under every
+# workload and was ~2.5x the generic int32 lax.top_k on both platforms.
+DEFAULT_SWEEP_IMPL = "cellrow"
 DEFAULT_TOPK_IMPL = "sort"
 # Front-half cell-sort lowering (GridSpec.sort_impl): "argsort" is the
 # XLA sort; "counting" is the two-pass counting sort (ops/sort.py) that

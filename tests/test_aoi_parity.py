@@ -13,6 +13,7 @@ follows tests/test_aoi_shift.py.
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
 from goworld_tpu.ops.aoi import (
@@ -294,3 +295,80 @@ def test_new_knob_validation_mirrors_existing_messages():
         GridSpec(**base, sweep_impl="bogus")
     with pytest.raises(ValueError, match=r"exact\|sort\|f32\|approx"):
         GridSpec(**base, topk_impl="bogus")
+
+
+# =======================================================================
+# the served path: the default sweep against "ranges" through World.tick
+# =======================================================================
+def _served_world(sweep_kw: dict, n_spaces: int, precision: str):
+    from goworld_tpu.core import WorldConfig
+    from goworld_tpu.entity import Entity, GameClient, Space, World
+
+    class _Mob(Entity):
+        pass
+
+    w = World(WorldConfig(
+        capacity=256,
+        grid=GridSpec(radius=RADIUS, extent_x=EXTENT, extent_z=EXTENT,
+                      k=32, cell_cap=32, row_block=64,
+                      precision=precision, **sweep_kw),
+        input_cap=64), n_spaces=n_spaces, seed=11)
+    w.register_entity("Mob", _Mob)
+    w.register_space("Arena", Space)
+    w.create_nil_space()
+    sent = []
+    w.sync_sink = lambda gate, cids, eids, vals: sent.append(
+        (gate, list(cids), list(eids), np.asarray(vals).tobytes()))
+    rng = np.random.default_rng(3)
+    for s in range(n_spaces):
+        sp = w.create_space("Arena")
+        for i in range(150):
+            w.create_entity(
+                "Mob", space=sp, eid=f"s{s}mob{i:010d}", moving=True,
+                pos=(float(rng.uniform(5, EXTENT - 5)), 0.0,
+                     float(rng.uniform(5, EXTENT - 5))),
+                client=(GameClient(1, f"CID{s}{i:012d}", w)
+                        if i < 6 else None))
+    return w, sent
+
+
+@pytest.mark.parametrize(
+    "n_spaces,precision", [(1, "off"), (2, "off"), (1, "q16")],
+    ids=["one_space", "vmapped_s2", "q16"])
+def test_served_default_sweep_matches_ranges(n_spaces, precision):
+    """ISSUE 26: the library default (``cellrow``, drawn from the one
+    constant — no ``sweep_impl`` is named) and ``ranges`` serve the
+    same world while occupancy <= cell_cap: equal neighbour rows,
+    enter/leave events, sync records and gauges out of ``World.tick``,
+    equal interest sets and client sends on the host. Under q16 the
+    default sweeps the snapped positions through the f32-bits table
+    and ``ranges`` its packed int16-pair view."""
+    from goworld_tpu.utils import consts
+
+    wa, sent_a = _served_world({}, n_spaces, precision)
+    wb, sent_b = _served_world({"sweep_impl": "ranges"}, n_spaces,
+                               precision)
+    assert wa.cfg.grid.sweep_impl == consts.DEFAULT_SWEEP_IMPL != "ranges"
+    events = 0
+    for _ in range(8):
+        wa.tick()
+        wb.tick()
+        oa, ob = wa.last_outputs, wb.last_outputs
+        assert int(np.max(oa.aoi_over_cap_cells)) == 0
+        assert int(np.max(oa.aoi_over_k_rows)) == 0
+        for x, y in zip(jax.tree.leaves(oa), jax.tree.leaves(ob)):
+            assert np.array_equal(np.asarray(x), np.asarray(y))
+        assert np.array_equal(np.asarray(wa.state.nbr),
+                              np.asarray(wb.state.nbr))
+        assert np.array_equal(np.asarray(wa.state.nbr_cnt),
+                              np.asarray(wb.state.nbr_cnt))
+        events += int(np.sum(oa.enter_n)) + int(np.sum(oa.leave_n))
+    assert events > 0 and int(np.sum(wa.last_outputs.sync_n)) > 0
+    assert int(np.max(np.asarray(wa.state.nbr_cnt))) > 0
+    assert sent_a and sent_a == sent_b
+    mobs = [eid for eid in wa.entities if "mob" in eid]   # not the spaces
+    assert len(mobs) == 150 * n_spaces
+    for eid in mobs:
+        assert wa.entities[eid].interested_in \
+            == wb.entities[eid].interested_in
+    assert any(wa.entities[eid].interested_in for eid in mobs)

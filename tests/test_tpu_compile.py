@@ -23,7 +23,9 @@ only one process may hold libtpu):
   written for a described device cannot be read back without one.
 """
 
+import contextlib
 import os
+import re
 
 import numpy as np
 import pytest
@@ -73,19 +75,27 @@ def mesh4(topo):
     return Mesh(np.asarray(topo.devices), (SPACE_AXIS,))
 
 
-@pytest.fixture()
-def for_tpu(monkeypatch):
-    """Compile as the chip would: hardware lowering for every Pallas
-    kernel, persistent cache off."""
+@contextlib.contextmanager
+def _as_the_chip(monkeypatch):
     from jax.experimental.compilation_cache import compilation_cache
 
     monkeypatch.setattr(pallas_compat, "on_tpu", lambda: True)
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield
-    jax.config.update("jax_enable_compilation_cache", was)
-    compilation_cache.reset_cache()
+    try:
+        yield
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+
+
+@pytest.fixture()
+def for_tpu(monkeypatch):
+    """Compile as the chip would: hardware lowering for every Pallas
+    kernel, persistent cache off."""
+    with _as_the_chip(monkeypatch):
+        yield
 
 
 def _shaped(tree, sharding):
@@ -101,10 +111,12 @@ def _device_bytes(compiled) -> int:
             + m.generated_code_size_in_bytes)
 
 
-def test_served_tick_compiles_for_v5e(one_chip, for_tpu):
+@pytest.fixture(scope="module")
+def served_tick(one_chip):
     """The main path: the step ``World.tick()`` dispatches (one space,
     every kernel choice at its library default, the carry donated), at
-    the real shard. Compiled once: it is most of this file's time."""
+    the real shard. Compiled once for the tests that read it: it is
+    most of this file's time."""
     from goworld_tpu.core.step import TickInputs
     from goworld_tpu.entity.manager import _make_local_tick
     from goworld_tpu.parallel.mesh import create_multi_state
@@ -115,13 +127,44 @@ def test_served_tick_compiles_for_v5e(one_chip, for_tpu):
                     one_chip)
     inputs = _shaped(jax.eval_shape(lambda: jax.tree.map(
         lambda x: x[None], TickInputs.empty(cfg))), one_chip)
-    compiled = _make_local_tick(cfg, 1, donate=True) \
-        .lower(state, inputs, None).compile()
+    with pytest.MonkeyPatch.context() as mp, _as_the_chip(mp):
+        compiled = _make_local_tick(cfg, 1, donate=True) \
+            .lower(state, inputs, None).compile()
+    return cfg, compiled
+
+
+def test_served_tick_compiles_for_v5e(served_tick):
+    cfg, compiled = served_tick
     m = compiled.memory_analysis()
     print(f"\nserved tick n={N} k={cfg.grid.k} "
           f"cell_cap={cfg.grid.cell_cap}: {m}")
     assert m.alias_size_in_bytes > 0, "the donated carry did not alias"
     assert _device_bytes(compiled) < HBM_BYTES
+
+
+def test_served_tick_window_fetch_is_no_loop(served_tick):
+    """The default sweep's window fetch stays one row gather per query
+    (ISSUE 26): under ``ranges``/``table`` the v5e compiler expands the
+    per-query windowed ``dynamic_slice`` into a ``while`` of ~1.2
+    million tiny slices a tick, named ``.../gw.aoi.gather/vmap(vmap())/
+    gather`` — 91% of the served tick's device time on the chip
+    (PERF.md, PR 25). A change that brings it back fails here, on the
+    CPU host, before a chip is asked."""
+    cfg, compiled = served_tick
+    loops = []
+    for line in compiled.as_text().splitlines():
+        if re.search(r"\bwhile\(", line):
+            name = re.search(r'op_name="([^"]*)"', line)
+            loops.append(name.group(1) if name else "")
+    assert loops, "no while at all: the row blocks' lax.map is one"
+    under = [n for n in loops if "gw.aoi.gather" in n.split("/")]
+    assert not under, f"the window fetch is a loop again: {under}"
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    print(f"\nserved tick sweep_impl={cfg.grid.sweep_impl}: "
+          f"temporaries {temp:,} bytes, loops {loops}")
+    # the premerged block and one row block's window, and no more than
+    # the expanded gather's temporaries were (657 MB)
+    assert temp < 512 * 10**6
 
 
 def test_counting_sort_pallas_compiles_for_v5e(one_chip, for_tpu):

@@ -52,7 +52,14 @@ def _tick_text(grid_kw: dict) -> tuple[str, str]:
 
 
 def _strip_metadata(hlo: str) -> str:
-    return re.sub(r",? ?metadata=\{[^{}]*\}", "", hlo)
+    """Without ``metadata={...}``, and with every instruction's label
+    numbered by first appearance: the compiler forms a label from the
+    last part of ``op_name`` (``%tile.14``), so labels are names too."""
+    hlo = re.sub(r",? ?metadata=\{[^{}]*\}", "", hlo)
+    seen: dict[str, str] = {}
+    return re.sub(
+        r"%[\w.\-]+",
+        lambda m: seen.setdefault(m.group(0), f"%v{len(seen)}"), hlo)
 
 
 @pytest.mark.parametrize("grid_kw", [
@@ -83,7 +90,7 @@ def test_tick_holds_every_scope_and_nothing_else_changed(
 
 def test_compiled_tick_is_the_same_program_without_its_metadata(
         monkeypatch):
-    """The served default (``ranges``), through the compiler."""
+    """The served default (``cellrow``), through the compiler."""
     def compiled() -> str:
         jax.clear_caches()
         cfg = WorldConfig(capacity=256, grid=GridSpec(
