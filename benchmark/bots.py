@@ -25,21 +25,29 @@ sys.path.insert(0, HERE)
 sys.path.insert(0, os.path.dirname(HERE))
 
 import reduce as R  # noqa: E402
+from world import Shape  # noqa: E402
 from goworld_tpu.net import codec, proto  # noqa: E402
 from goworld_tpu.net.botclient import BotClient  # noqa: E402
 
 MAX_SEQ = 8192          # sends a client can make in one run
-LOGIN_TIMEOUT_S = 150.0
+LOGIN_TIMEOUT_S = 150.0  # for logins and placement together, and ...
+WAVE_TIMEOUT_S = 12.0    # ... this much more for every wave of clients
 SETTLE_TIMEOUT_S = 60.0  # an answer that comes late is late, not wrong
-ROWS_SAMPLE = 768        # NPC rows read back beside every avatar's row
+ROWS_SAMPLE = 768        # NPC rows read back beside every avatar's row, a
+#                          tile; in a tiled world half of them from rows
+#                          within the radius of a tile border
 CROSS_BAND = 3.0         # a definite crossing swings this far past the edge
 # Logins, and then the first sends (each a jump from the parking spot to
 # the group's anchor), come in waves that double from WAVE_FIRST clients
-# up to WAVE_MAX: a tick decodes at most enter_cap = leave_cap = 4,096
-# interest events and drops the rest, and a client that enters or jumps
-# makes some 25 to 40 of each. A wave starts once the game has answered
-# the one before it (a login frame can take seconds, and waves on a
-# timer would pile up in it) and WAVE_CALM_FRAMES calm frames have
+# until one would bring a tile more than WAVE_MAX: a tile's tick decodes
+# at most enter_cap = leave_cap = 4,096 interest events and drops the
+# rest, and a client that enters or jumps makes some 25 to 40 of each.
+# In a tiled world the clients log in and jump in an order that goes
+# round the tiles (by their groups' anchors; the fixture parks the
+# logins round the tiles too), so a wave brings every tile its share
+# and grows to nearly WAVE_MAX x tiles. A wave starts once the game has
+# answered the one before it (a login frame can take seconds, and waves
+# on a timer would pile up in it) and WAVE_CALM_FRAMES calm frames have
 # passed (a second at the most): a wave's frame stalls for over a second
 # when it brings a staging batch of a new size (the eager scatters
 # compile in the serve loop, one set per power-of-two bucket from 8 up),
@@ -47,6 +55,37 @@ CROSS_BAND = 3.0         # a definite crossing swings this far past the edge
 # ladder leaves NORMAL. Doubling, a wave that the game meets in two
 # parts brings at most one new bucket.
 WAVE_FIRST, WAVE_MAX, WAVE_CALM_FRAMES, WAVE_CALM_MAX_S = 8, 64, 3, 1.0
+
+
+def round_the_tiles(tile_of_group, members) -> np.ndarray:
+    """The clients in the order of their waves: whole groups, every
+    tile's groups spread evenly over the whole order (the k-th of a
+    tile's m groups stands at (k + 0.5) / m), so any stretch of it holds
+    each tile's share. With one tile that is the clients' own order."""
+    tile_of_group = np.asarray(tile_of_group, np.int64)
+    at = np.empty(len(tile_of_group))
+    for t in np.unique(tile_of_group):
+        mine = np.nonzero(tile_of_group == t)[0]
+        at[mine] = (np.arange(len(mine)) + 0.5) / len(mine)
+    groups = np.lexsort((tile_of_group, at))
+    return np.array([c for g in groups for c in members(g)], np.int64)
+
+
+def wave_bounds(tiles_in_order, g: int) -> list[int]:
+    """Where the waves begin and end in the order of the clients: whole
+    groups of ``g``, each wave twice the one before it, cut where it
+    would bring one tile more than WAVE_MAX clients."""
+    n = len(tiles_in_order)
+    bounds, size = [0], WAVE_FIRST
+    while bounds[-1] < n:
+        lo = bounds[-1]
+        hi = min(n, lo + max(g, size - size % g))
+        while hi - g > lo and np.bincount(
+                tiles_in_order[lo:hi]).max() > WAVE_MAX:
+            hi -= g
+        bounds.append(hi)
+        size = 2 * (hi - lo)
+    return bounds
 
 
 def load_generator(kind: str):
@@ -132,7 +171,10 @@ async def main_async(a) -> int:
     gen = load_generator(mix["kind"])
     n = int(mix["clients"])
     radius = float(cfg["game"]["aoi_radius"])
-    plan = gen.Plan(mix, float(cfg["game"]["extent_x"]), radius, n)
+    shape = Shape(cfg)
+    plan = gen.Plan(mix, shape.extent_x, radius, n,
+                    **({"borders": shape.borders} if shape.mega else {}))
+    tile_of = shape.tile_of if shape.mega else None
     table = plan.positions(MAX_SEQ)
     observer = np.array([plan.observer(c) for c in range(n)])
     loop = asyncio.get_running_loop()
@@ -142,18 +184,23 @@ async def main_async(a) -> int:
     tasks = []
     gap = min(WAVE_CALM_FRAMES / float(cfg["game"]["tick_hz"]),
               WAVE_CALM_MAX_S)
-    bounds, size = [0], WAVE_FIRST
-    while bounds[-1] < n:
-        size = max(plan.g, size - size % plan.g)      # whole groups
-        bounds.append(min(n, bounds[-1] + size))
-        size = min(2 * size, WAVE_MAX)
+    # the order of the waves, and every client's place in it
+    first_tile = np.zeros(n, np.int64) if tile_of is None \
+        else tile_of(table[:, 1, 0], table[:, 1, 2])
+    order = round_the_tiles(
+        [first_tile[plan.members(g)[0]] for g in range(n // plan.g)],
+        plan.members)
+    rank = np.empty(n, np.int64)
+    rank[order] = np.arange(n)
+    bounds = wave_bounds(first_tile[order], plan.g)
     waves = list(zip(bounds[:-1], bounds[1:]))
-    end = time.monotonic() + LOGIN_TIMEOUT_S
+    end = time.monotonic() + LOGIN_TIMEOUT_S + WAVE_TIMEOUT_S * len(waves)
     for lo, hi in waves:
-        for b in bots[lo:hi]:
+        wave = [bots[c] for c in order[lo:hi]]
+        for b in wave:
             await b.connect()
             tasks.append(loop.create_task(b._recv_loop()))
-        while not all(b.player is not None for b in bots[lo:hi]):
+        while not all(b.player is not None for b in wave):
             if time.monotonic() > end:
                 print("FAILED login: %d of %d clients have a player" % (
                     sum(b.player is not None for b in bots), n),
@@ -183,7 +230,7 @@ async def main_async(a) -> int:
             if delay > 0:
                 await asyncio.sleep(delay)
             c = int(who[k])
-            if c >= moving:
+            if rank[c] >= moving:
                 continue                   # its wave has not come yet
             if kind[k] == gen.SEND:
                 seq[c] += 1
@@ -210,7 +257,7 @@ async def main_async(a) -> int:
     reader = loop.run_in_executor(None, read_window)
 
     def placed(upto: int) -> bool:
-        for c in range(upto):
+        for c in order[:upto]:
             ents = bots[c].entities
             for d in plan.members(plan.group_of(c)):
                 if d == c:
@@ -278,8 +325,10 @@ async def main_async(a) -> int:
                 mir[eid] = ("client", d, vals) if d is not None \
                     else ("npc", eid, vals)
             mirrors.append(mir)
-        return R.interest_check(final_xz, radius, slack, mirrors,
-                                final_vals)
+        return R.interest_check(
+            final_xz, radius, slack, mirrors, final_vals,
+            None if tile_of is None
+            else tile_of(final_xz[:, 0], final_xz[:, 1]))
 
     settle_end = time.monotonic() + SETTLE_TIMEOUT_S
     check = snapshot()
@@ -299,18 +348,30 @@ async def main_async(a) -> int:
     rows_end = time.monotonic() + 60.0
     while log.rows is None and time.monotonic() < rows_end:
         await asyncio.sleep(0.05)
-    # no answer: every row that was to be read counts as wrong
-    rows = {"rows_wrong": ROWS_SAMPLE + n, "avatar_row_off": n,
-            "rows_read": 0}
+    # no answer: every row that was to be read counts as wrong, and
+    # every entity as lost
+    live = int(cfg["world"]["live"])
+    rows = {"rows_wrong": ROWS_SAMPLE * shape.tiles + n,
+            "avatar_row_off": n, "entities_lost": live, "rows_read": 0,
+            "rows_near_border": 0, "rows_wrong_near_border": 0}
     if log.rows is not None:
         with np.load(os.path.join(os.path.dirname(a.out), log.rows)) as z:
             order = {e: i for i, e in enumerate(z["avatar_eids"].tolist())}
             mine = [order.get(b.player.eid, -1) for b in bots]
             if min(mine) >= 0:
+                # the whole world under one row number, whatever its
+                # tiling: the reference knows no tiles
+                pos, held = z["pos"], z["rows"]
+                near = shape.border_distance(
+                    pos[held, 0], pos[held, 2]) <= radius
                 rows = R.rows_check(
-                    z["pos"], z["alive"], z["rows"], z["nbr"], radius,
-                    z["avatar_rows"][mine], final_vals)
-                rows["rows_read"] = int(len(z["rows"]))
+                    pos, z["alive"], held, z["nbr"], radius,
+                    z["avatar_rows"][mine], final_vals, near)
+                rows["rows_read"] = int(len(held))
+                rows["rows_near_border"] = int(near.sum())
+                # live rows on the device against the configuration's:
+                # a migration that drops or doubles a row reads here
+                rows["entities_lost"] = R.entities_lost(z["alive"], live)
 
     # ---- reduce --------------------------------------------------------
     sync = log.sync[n_sync0:]
@@ -361,8 +422,13 @@ async def main_async(a) -> int:
         if d is not None and (r, d) in wanted and t >= t0:
             events.setdefault((r, d), []).append((t, created))
     cross = R.cross_check(pairs, [(c, q, t) for c, q, _due, t in sends],
-                          table, events, radius, CROSS_BAND)
+                          table, events, radius, CROSS_BAND, tile_of)
     rows_read = rows.pop("rows_read")
+    over_border = {
+        "crossings_over_border": cross["crossings_over_border"],
+        "finals_over_border": check.pop("finals_over_border"),
+        "rows_near_border": rows.pop("rows_near_border"),
+        "rows_wrong_near_border": rows.pop("rows_wrong_near_border")}
     numbers = dict(check, **rows, cross_missed=cross["cross_missed"],
                    pos_wrong=pos_wrong, order_back=order_back,
                    rpc_wrong=rpc_wrong, never_seen=never_seen,
@@ -388,6 +454,7 @@ async def main_async(a) -> int:
             for b in bots),
         "settled_s_after_close": settled_s,
         "crossings": cross["crossings"], "rows_read": rows_read,
+        "over_border": over_border,
         "stats": json.loads(log.stats) if log.stats else None,
         "mirror_errors_first": [e for b in bots for e in b.errors][:5],
         "t0": t0, "close": close,

@@ -1,8 +1,9 @@
-"""The benchmark's game script: one space of device-driven NPCs and an
+"""The benchmark's game script: one world of device-driven NPCs and an
 Avatar per client. Runs inside the game process (``python -m goworld_tpu
-start`` executes it from the server directory) and reads its sizes from
-``bench_params.json`` beside it, which ``run.py`` writes from the
-cell's configuration file.
+start`` executes it from the server directory) and reads its sizes and
+the world's kind from ``bench_params.json`` beside it, which ``run.py``
+writes from the cell's configuration file: one space on one chip, or
+one megaspace tiled over the cell's chips (``megaspace``, ``borders``).
 
 Besides the world it gives the harness what only the process that owns
 the chip can read (device memory as an RPC reply; the device's rows and
@@ -10,6 +11,7 @@ neighbour lists as a file beside it), and — for the tests
 under ``benchmark/tests`` only — plants a fault under the timed path
 when ``bench_params.json`` names one and the file ``plant.on`` exists.
 """
+import dataclasses
 import json
 import os
 
@@ -20,15 +22,25 @@ from goworld_tpu.utils import opmon
 
 with open("bench_params.json") as _f:
     P = json.load(_f)
-EXTENT = float(P["extent"])
+EXTENT_X, EXTENT_Z = float(P["extent_x"]), float(P["extent_z"])
+RADIUS = float(P["aoi_radius"])
+MEGA = bool(P.get("megaspace"))
+BORDERS = P.get("borders") or {}      # a tiled world's inner borders
 # parking: the i-th login enters on a grid wider than an AOI box, so a
-# login wave never puts hundreds of avatars into one neighbourhood
-PARK = 2.0 * float(P["aoi_radius"]) + 20.0
-PARK_ROW = max(int(EXTENT // PARK) - 1, 1)
+# login wave never puts hundreds of avatars into one neighbourhood. In a
+# tiled world the logins go round the tiles, each tile with a grid of
+# its own, so that a wave falls on every tile alike (bots.py sizes its
+# waves by the tile); one space is one tile, and parks as ever
+PARK = 2.0 * RADIUS + 20.0
+_XS = [0.0] + sorted(BORDERS.get("x", ())) + [EXTENT_X]
+_ZS = [0.0] + sorted(BORDERS.get("z", ())) + [EXTENT_Z]
+PARKS = [(x0, z0, max(int((x1 - x0) // PARK) - 1, 1),
+          max(int((z1 - z0) // PARK) - 1, 1))
+         for x0, x1 in zip(_XS, _XS[1:]) for z0, z1 in zip(_ZS, _ZS[1:])]
 _logins = [0]
 
 
-@gw.register_space("Arena")
+@gw.register_space("Arena", megaspace=MEGA)
 class Arena(gw.Space):
     pass
 
@@ -45,8 +57,10 @@ class Avatar(gw.Entity):
                      if sp.type_name == "Arena")
         i = _logins[0]
         _logins[0] += 1
-        x = PARK * (0.5 + i % PARK_ROW)
-        z = PARK * (0.5 + (i // PARK_ROW) % PARK_ROW)
+        x0, z0, row, rows = PARKS[i % len(PARKS)]
+        i //= len(PARKS)
+        x = x0 + PARK * (0.5 + i % row)
+        z = z0 + PARK * (0.5 + (i // row) % rows)
         self.enter_space(arena.id, (x, 0.0, z))
 
     def OnClientDisconnected(self):
@@ -72,23 +86,51 @@ class Avatar(gw.Entity):
         """For the check, once the world has settled: what the device
         holds, read in the one process that can read it. Every row's
         position and liveness, each avatar's row, and the neighbour
-        lists of every avatar row and of ``sample`` NPC rows drawn from
-        the seed go to ``rows.npz`` beside this script (program-prepared
-        data: the benchmark compares the lists with its own brute force
-        over these positions, and the avatars' rows with what it sent).
-        Whole arrays come to the host as they are and are cut there, so
-        nothing compiles for it."""
+        lists of every avatar row and of ``sample`` NPC rows a tile
+        drawn from the seed go to ``rows.npz`` beside this script
+        (program-prepared data: the benchmark compares the lists with
+        its own brute force over these positions, and the avatars' rows
+        with what it sent). Whole arrays come to the host as they are
+        and are cut there, so nothing compiles for it.
+
+        The WHOLE world is read, under one row number: tile * capacity
+        + slot, the number the neighbour lists themselves hold (the
+        program's gid; a tile's ghost rows are copies and no rows of
+        the world). For one space that is the slot, and the file holds
+        what it always held. In a tiled world half of a tile's sample
+        comes from its rows within the radius of a tile border."""
         st = self.world.state
-        pos, alive, nbr = (np.asarray(x)[0]
-                           for x in (st.pos, st.alive, st.nbr))
-        avatars = sorted((e.id, int(e.slot))
+        pos, alive, nbr = (np.asarray(x) for x in (st.pos, st.alive, st.nbr))
+        cap = pos.shape[1]
+        if not MEGA:                  # one space: shard 0 is the world
+            pos, alive, nbr = pos[:1], alive[:1], nbr[:1]
+        pos, alive, nbr = (x.reshape((-1,) + x.shape[2:])
+                           for x in (pos, alive, nbr))
+        avatars = sorted((e.id, int(e.shard or 0) * cap + int(e.slot))
                          for e in self.world.entities.values()
                          if e.type_name == "Avatar" and e.slot is not None)
         av_rows = np.array([r for _i, r in avatars], np.int64)
         npc_rows = np.setdiff1d(np.nonzero(alive)[0], av_rows)
         rng = np.random.default_rng([int(seed), 0x726F7773])
-        rows = np.concatenate([av_rows, rng.choice(
-            npc_rows, min(int(sample), len(npc_rows)), replace=False)])
+        if not MEGA:
+            picked = [rng.choice(npc_rows, min(int(sample), len(npc_rows)),
+                                 replace=False)]
+        else:
+            near = np.zeros(len(npc_rows), bool)
+            for axis, col in (("x", 0), ("z", 2)):
+                for line in BORDERS.get(axis, ()):
+                    near |= np.abs(pos[npc_rows, col] - line) <= RADIUS
+            picked = []
+            for t in range(len(pos) // cap):
+                mine = npc_rows // cap == t
+                a = npc_rows[mine & near]
+                a = rng.choice(a, min(int(sample) // 2, len(a)),
+                               replace=False)
+                b = npc_rows[mine & ~near]
+                b = rng.choice(b, min(int(sample) - len(a), len(b)),
+                               replace=False)
+                picked += [a, b]
+        rows = np.concatenate([av_rows] + picked)
         np.savez("rows.npz", pos=pos, alive=alive, rows=rows,
                  nbr=nbr[rows], avatar_rows=av_rows,
                  avatar_eids=np.array([i for i, _r in avatars]),
@@ -111,19 +153,35 @@ def _plant(world, kind: str) -> None:
     """Tests only: break the timed path underneath, once ``plant.on``
     exists. ``alter`` changes a position where it is staged, ``half``
     leaves out every second client's record, ``freeze`` stages nothing
-    (the state stays as it was)."""
+    (the state stays as it was), ``lose`` takes one NPC out of the
+    world (as a migration that drops a row would), ``caps`` sets the
+    program's alarm for a tile's migrate buffer to go off at ANY
+    migration (the host-side threshold only: the compiled tick keeps
+    its buffers), so its own overflow lines are in its own log."""
     cls = type(world)
     stage = cls.stage_pos_sync_batch
+    once = []
 
     def broken(self, eids, vals):
         if not os.path.exists("plant.on"):
             return stage(self, eids, vals)
+        if kind == "lose" and not once:
+            once.append(next(e for e in self.entities.values()
+                             if e.type_name == "Npc"))
+            once[0].destroy()
+        if kind == "caps" and not once:
+            once.append(dataclasses.replace(self.mega, migrate_cap=0))
+            self.mega = once[0]
         eids = np.asarray(eids, "S16")
         vals = np.array(vals, np.float32).reshape(-1, 4)
         if kind == "alter":
             vals[:, 0] += 1.0
         elif kind == "half":
-            keep = np.array([sum(e) % 2 == 0 for e in eids], bool)
+            if not once:      # every second avatar, whatever ids a run draws
+                once.append({e.encode() for e in sorted(
+                    e.id for e in self.entities.values()
+                    if e.type_name == "Avatar")[1::2]})
+            keep = np.array([e not in once[0] for e in eids.tolist()], bool)
             eids, vals = eids[keep], vals[keep]
         elif kind == "freeze":
             return 0
@@ -136,7 +194,10 @@ def _plant(world, kind: str) -> None:
 def fill(world):
     arena = world.create_space("Arena")
     rng = np.random.default_rng(int(P["seed"]))
-    xz = rng.uniform(0.0, EXTENT, (int(P["npcs"]), 2))
+    # uniform over the world's extent (bit for bit uniform(0, extent)
+    # where the world is square)
+    xz = rng.uniform(0.0, 1.0, (int(P["npcs"]), 2)) \
+        * np.array([EXTENT_X, EXTENT_Z])
     for x, z in xz:
         world.create_entity("Npc", space=arena, pos=(x, 0.0, z),
                             moving=True)
@@ -145,9 +206,11 @@ def fill(world):
     # run.py opens every window at the same place against that cadence,
     # so every run holds as many samples
     opmon.expose("bench_tick", _LiveTick(world))
+    # (on a megaspace the plane skips every sample before it walks
+    # anything, entity/manager.py _audit_sample: no cadence to meet)
     aud = getattr(world, "audit", None)
     opmon.expose("bench_audit_every",
-                 int(getattr(aud, "sample_every", 0) or 0))
+                 0 if MEGA else int(getattr(aud, "sample_every", 0) or 0))
     if P.get("plant"):
         _plant(world, P["plant"])
 

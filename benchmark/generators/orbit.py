@@ -16,6 +16,19 @@ exactly ``send_probability`` of the window's slots (which ones is
 drawn), at a drawn phase, and makes one RPC in every 1/rate seconds at
 an instant drawn anew each time.
 
+``border_share`` (absent = 0: the plan above, bit for bit) puts that
+share of the sites ON an inner border of a tiled world (``borders``,
+which the harness reads from the configuration: ``world.py``), spread
+over every border line and never on a crossing of two. A group
+anchored on a border has its members on either side of it, each
+walking over it twice a lap: an entity that changes its tile under its
+client, seen by a partner that holds it across the seam.
+``twin_border_sites`` of the twin sites lie on a border too, so AOI-edge
+crossings between clients happen across a seam. Sites on a border are
+``need`` apart along it (the least spacing that keeps two sites out of
+each other's AOI); the other sites keep the grid, without the grid
+lines closer than that to a border.
+
 The height coordinate carries the send's sequence number (an integer,
 exact in f32 up to 2**24), so a receipt names the send it mirrors with
 no server field; x, z and yaw follow from the same number, which lets
@@ -32,7 +45,7 @@ SEND, RPC = 0, 1
 
 class Plan:
     def __init__(self, mix: dict, extent: float, aoi_radius: float,
-                 clients: int):
+                 clients: int, borders: dict | None = None):
         self.mix = mix
         self.n = clients
         self.g = g = int(mix["group_size"])
@@ -58,6 +71,77 @@ class Plan:
         self.r = float(mix["orbit_radius"])
         self.step = float(mix["orbit_step_rad"])
         self.origin = 0.5 * (extent - spacing * (side - 1))
+        self.sites = None           # the grid's own arithmetic (anchor)
+        self.on_border = np.zeros(self.groups - self.twins, bool)
+        share = float(mix.get("border_share", 0.0))
+        if share > 0.0:
+            self._lay_borders(share, extent, need, borders or {})
+
+    def _lay_borders(self, share: float, extent: float, need: float,
+                     borders: dict) -> None:
+        """Site positions where ``border_share`` of them lie on an inner
+        border: ``self.sites`` f64[sites, 2], ``self.on_border``."""
+        n_sites = self.groups - self.twins
+        lines = [("x", float(b)) for b in borders.get("x", ())] \
+            + [("z", float(b)) for b in borders.get("z", ())]
+        if not lines:
+            raise ValueError("border_share needs a world with an inner "
+                             "border (a megaspace configuration)")
+        n_border = int(round(share * n_sites))
+        twin_border = min(int(self.mix.get("twin_border_sites", 0)),
+                          self.twins, n_border)
+
+        def clear(axis: str, v: float) -> bool:
+            """``v`` along a line of ``axis``: inside the world and not
+            within ``need`` of a line that crosses it."""
+            other = "z" if axis == "x" else "x"
+            return need <= v <= extent - need and all(
+                abs(v - b) >= need for b in borders.get(other, ()))
+
+        # along every line, outwards from the world's middle, in turn
+        on_line: list[tuple[float, float]] = []
+        reach = int(extent / need) + 1
+        for j in range(reach):
+            for sign in ((1,) if j == 0 else (1, -1)):
+                v = 0.5 * extent + sign * j * need
+                for axis, b in lines:
+                    if clear(axis, v):
+                        on_line.append((b, v) if axis == "x" else (v, b))
+        if len(on_line) < n_border:
+            raise ValueError(f"{n_border} border sites do not fit the "
+                             f"borders ({len(on_line)} places)")
+        # the grid, without the grid lines too close to a border; its
+        # side grows until the sites that are left fit
+        n_grid = n_sites - n_border
+        side = max(math.ceil(math.sqrt(max(n_grid, 1))), 1)
+        while True:
+            spacing = min(float(self.mix["grid_spacing_max"]),
+                          extent / (side + 1))
+            if spacing < need:
+                raise ValueError(
+                    f"{n_grid} sites off the borders need a grid spacing "
+                    f"of {need}, the extent {extent} gives {spacing:.1f}")
+            origin = 0.5 * (extent - spacing * (side - 1))
+            at = [origin + spacing * i for i in range(side)]
+            xs = [v for v in at if all(abs(v - b) >= need
+                                       for b in borders.get("x", ()))]
+            zs = [v for v in at if all(abs(v - b) >= need
+                                       for b in borders.get("z", ()))]
+            if len(xs) * len(zs) >= n_grid:
+                break
+            side += 1
+        self.side, self.spacing, self.origin = side, spacing, origin
+        grid = [(x, z) for z in zs for x in xs][:n_grid]
+        # twin sites come first (anchor): some on a border, the rest on
+        # the grid; then every other site, the border's before the grid's
+        t_grid = self.twins - twin_border
+        order = on_line[:twin_border] + grid[:t_grid] \
+            + on_line[twin_border:n_border] + grid[t_grid:]
+        self.sites = np.array(order, np.float64)
+        flag = np.zeros(n_sites, bool)
+        flag[:twin_border] = True
+        flag[self.twins:self.twins + n_border - twin_border] = True
+        self.on_border = flag
 
     def group_of(self, c: int) -> int:
         return c // self.g
@@ -77,6 +161,9 @@ class Plan:
             site, off = grp // 2, (grp % 2 - 0.5) * self.twin_gap
         else:
             site, off = grp - self.twins, 0.0
+        if self.sites is not None:
+            return (float(self.sites[site, 0]) + off,
+                    float(self.sites[site, 1]))
         return (self.origin + self.spacing * (site % self.side) + off,
                 self.origin + self.spacing * (site // self.side))
 

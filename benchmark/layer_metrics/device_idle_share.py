@@ -1,8 +1,11 @@
 """Device: the share of the captured span in which no operation ran on
-it (1 - busy / span), in percent."""
+it (1 - busy / span), in percent: of the busiest device plane where the
+cell lies on several chips."""
+from scrapes import plane_of
 
 
 def read(scrapes, trace, cell):
-    if not trace or not trace.get("busy_s") or not trace.get("window_s"):
+    one = plane_of(trace)
+    if not one or not one.get("busy_s") or not one.get("window_s"):
         return None
-    return 100.0 * (1.0 - trace["busy_s"] / trace["window_s"])
+    return 100.0 * (1.0 - one["busy_s"] / one["window_s"])

@@ -45,6 +45,14 @@ cuts (first to last whole run of the tick's program):
   it (``gw.pacing_sleep``, ``gw.fetch_outputs``, ... or ``gw.frame``
   where only the tick record covers it); the rest is ``unlabelled``.
 
+A cell on several chips leaves one device plane per chip. Everything
+above is then read on the BUSIEST plane (most busy time per frame: the
+device every frame waits for; benchmark/README.md), never as a mean,
+and the planes are held against each other:
+
+* ``planes``: per plane, busy and idle ms per frame;
+* ``skew_ms``: the busiest plane's busy time minus the least busy's.
+
 The per-layer readers (``layer_metrics/aoi_ms.py`` and the rest) call
 :func:`phases`, which reduces the run's capture once and leaves
 ``.bench_work/<cell>/phases.json`` for the others. Where no decoder can
@@ -514,6 +522,7 @@ def reduce_phases(planes: list[dict]) -> dict | None:
         every = merged(starts, ends)
         busy = clip(every, lo, hi)
         out = {
+            "plane": p["name"],
             "frames": frames, "window_ns": hi - lo, "busy_ns": busy,
             "scopes": {n: under((n,)) for n in sorted(
                 {n for sc in path_scopes for n in sc})},
@@ -541,33 +550,36 @@ def reduce_phases(planes: list[dict]) -> dict | None:
         per_dev.append(out)
     if not per_dev:
         return None
-    n = len(per_dev)
 
-    def per_frame(ns_of) -> float:
-        """Mean over the device planes, nanoseconds -> ms per frame."""
-        return sum(ns_of(d) / d["frames"] for d in per_dev) / n / 1e6
+    def ms(d: dict, ns: float) -> float:
+        """Nanoseconds of plane ``d`` -> ms per frame."""
+        return ns / d["frames"] / 1e6
 
-    scopes = sorted({s for d in per_dev for s in d["scopes"]})
-    labels = sorted({s for d in per_dev for s in d["idle"]})
+    # the plane every frame waits for: most busy time per frame
+    top = max(per_dev, key=lambda d: d["busy_ns"] / d["frames"])
+    planes = [{"plane": d["plane"], "busy_ms": ms(d, d["busy_ns"]),
+               "idle_ms": ms(d, d["window_ns"] - d["busy_ns"])}
+              for d in per_dev]
     shifts = [d["shift"] for d in per_dev if d["shift"]]
     return {
-        "device_planes": n,
-        "frames": sum(d["frames"] for d in per_dev) / n,
-        "window_s": sum(d["window_ns"] for d in per_dev) / n / 1e9,
-        "busy_ms": per_frame(lambda d: d["busy_ns"]),
-        "scopes": {s: per_frame(lambda d: d["scopes"].get(s, 0.0))
-                   for s in scopes},
-        "groups": {g: per_frame(lambda d: d["groups"][g])
-                   for g in GROUPS},
-        "unscoped_ms": per_frame(lambda d: d["unscoped_ns"]),
-        "unscoped_top": per_dev[0]["unscoped_top"],
+        "device_planes": len(per_dev),
+        "busiest": top["plane"],
+        "planes": planes,
+        "skew_ms": max(q["busy_ms"] for q in planes)
+        - min(q["busy_ms"] for q in planes),
+        "frames": top["frames"],
+        "window_s": top["window_ns"] / 1e9,
+        "busy_ms": ms(top, top["busy_ns"]),
+        "scopes": {s: ms(top, v) for s, v in top["scopes"].items()},
+        "groups": {g: ms(top, v) for g, v in top["groups"].items()},
+        "unscoped_ms": ms(top, top["unscoped_ns"]),
+        "unscoped_top": top["unscoped_top"],
         "clock_shift_ms": max(s for s, _m in shifts) / 1e6
         if shifts else None,
         "clock_shift_by": shifts[0][1] if shifts else None,
         "host_line": logic["name"] if logic else None,
-        "idle_ms": per_frame(lambda d: d["window_ns"] - d["busy_ns"]),
-        "idle": {s: per_frame(lambda d: d["idle"].get(s, 0.0))
-                 for s in labels},
+        "idle_ms": ms(top, top["window_ns"] - top["busy_ns"]),
+        "idle": {s: ms(top, v) for s, v in top["idle"].items()},
     }
 
 
@@ -657,6 +669,15 @@ def scope_ms(cell: dict, scope: str) -> float | None:
             return None
         return res["groups"][scope]
     return res["scopes"].get(scope)
+
+
+def skew_ms(cell: dict) -> float | None:
+    """``skew_ms`` of a capture with several device planes; ``None`` on
+    one chip (nothing to hold against each other)."""
+    res = phases(cell)
+    if not res or res.get("device_planes", 1) < 2:
+        return None
+    return res.get("skew_ms")
 
 
 def idle_ms(cell: dict, want) -> float | None:

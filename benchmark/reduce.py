@@ -86,7 +86,7 @@ def stream_faults(rc_recv, rc_sender, rc_seq, rc_vals, table,
 
 
 def interest_check(final_xz, radius: float, slack: float, mirrors,
-                   final_vals) -> dict[str, int]:
+                   final_vals, tile=None) -> dict[str, int]:
     """The clients' mirrors, once the world has settled, against the
     brute-force reference over true positions.
 
@@ -104,11 +104,20 @@ def interest_check(final_xz, radius: float, slack: float, mirrors,
       plus the slack (what an NPC can move while records are in flight);
     * ``npc_cross_missing``: an NPC some client mirrors at a place well
       inside ``c``'s box (radius minus slack) that ``c`` does not hold.
+
+    ``tile[c]`` (a tiled world) is the tile ``c``'s final position lies
+    in: ``finals_over_border`` then counts the clients whose reference
+    neighbourhood holds a client of another tile, which says how much
+    of the above was judged across a seam (no fault by itself).
     """
     final_xz = np.asarray(final_xz, np.float64)
     want = neighbourhoods(final_xz, radius)
     out = dict(final_missing=0, interest_extra=0, npc_stray=0,
-               npc_cross_missing=0)
+               npc_cross_missing=0, finals_over_border=0)
+    if tile is not None:
+        out["finals_over_border"] = sum(
+            any(tile[d] != tile[c] for d in want[c])
+            for c in range(len(want)))
     npc_at: dict = {}
     for c, mir in enumerate(mirrors):
         got = set()
@@ -139,7 +148,7 @@ def interest_check(final_xz, radius: float, slack: float, mirrors,
 
 
 def rows_check(pos, alive, rows, nbr, radius: float, avatar_rows,
-               final_vals) -> dict[str, int]:
+               final_vals, near_border=None) -> dict[str, int]:
     """What the game read back from the device once the world had
     settled (program-prepared data: positions of every row, the
     neighbour lists of the sampled ``rows``) against the reference.
@@ -150,6 +159,14 @@ def rows_check(pos, alive, rows, nbr, radius: float, avatar_rows,
       capacity);
     * ``avatar_row_off``: clients whose device row does not hold the
       last position they sent, bit for bit.
+
+    A tiled world comes as ONE world: ``pos``, ``alive`` over every
+    tile's rows under the global row number (tile * capacity + slot),
+    which is also what the lists hold; the brute force knows no tiles.
+    ``near_border[i]`` says that sampled row ``i`` lies within the
+    radius of a tile border: ``rows_wrong_near_border`` counts the
+    wrong ones among those (part of ``rows_wrong``, not a number of its
+    own).
     """
     pos = np.asarray(pos, np.float32)
     live = np.nonzero(np.asarray(alive, bool))[0]
@@ -157,15 +174,25 @@ def rows_check(pos, alive, rows, nbr, radius: float, avatar_rows,
     at[live] = np.arange(len(live))
     rows = np.asarray(rows, np.int64)
     want = neighbours_of(pos[live][:, [0, 2]], at[rows], radius)
-    wrong = 0
+    wrong = wrong_near = 0
     for i, lst in enumerate(np.asarray(nbr, np.int64)):
         got = {int(j) for j in lst if 0 <= j < len(pos)}
         if at[rows[i]] < 0 or got != {int(live[j]) for j in want[i]}:
             wrong += 1
+            wrong_near += int(near_border is not None and near_border[i])
     fv = np.asarray(final_vals, np.float32)[:, :3]
     held = pos[np.asarray(avatar_rows, np.int64)]
     off = int((held.view(np.uint32) != fv.view(np.uint32)).any(axis=1).sum())
-    return {"rows_wrong": wrong, "avatar_row_off": off}
+    return {"rows_wrong": wrong, "avatar_row_off": off,
+            "rows_wrong_near_border": wrong_near}
+
+
+def entities_lost(alive, live: int) -> int:
+    """Live rows the device holds against the configured number: a
+    migration that drops a row reads one too few, one that doubles a
+    row one too many (ghost rows are no rows of the world and are never
+    read back)."""
+    return abs(int(np.count_nonzero(alive)) - int(live))
 
 
 def excursions(t, dist, radius: float, band: float) -> list[tuple]:
@@ -184,7 +211,7 @@ def excursions(t, dist, radius: float, band: float) -> list[tuple]:
 
 
 def cross_check(pairs, sends, table, events, radius: float,
-                band: float) -> dict[str, int]:
+                band: float, tile_of=None) -> dict[str, int]:
     """Enters and leaves between clients inside the window. For every
     ordered pair (c, d) the reference walks both clients' sent
     positions in time order; every definite crossing it finds has to be
@@ -194,11 +221,13 @@ def cross_check(pairs, sends, table, events, radius: float,
     maps (c, d) to its (instant, created?) list in arrival order.
 
     ``crossings``: definite crossings the reference found;
-    ``cross_missed``: those the mirror never answered."""
+    ``cross_missed``: those the mirror never answered;
+    ``crossings_over_border``: those at which the two clients stood in
+    different tiles (``tile_of(x, z)``: a tiled world's rule)."""
     by: dict[int, list] = {}
     for c, q, t in sends:
         by.setdefault(int(c), []).append((t, int(q)))
-    found = missed = 0
+    found = missed = over = 0
     for c, d in pairs:
         sc, sd = by.get(c), by.get(d)
         if not sc or not sd:
@@ -206,23 +235,27 @@ def cross_check(pairs, sends, table, events, radius: float,
         qc, qd = sc[0][1] - 1, sd[0][1] - 1
         merged = sorted([(t, 0, q) for t, q in sc]
                         + [(t, 1, q) for t, q in sd])
-        ts, ds = [], []
+        ts, ds, apart = [], [], {}
         for t, who, q in merged:
             if who:
                 qd = q
             else:
                 qc = q
+            a = table[c, qc, [0, 2]].astype(np.float64)
+            b = table[d, qd, [0, 2]].astype(np.float64)
             ts.append(t)
-            ds.append(float(np.abs(
-                table[c, qc, [0, 2]].astype(np.float64)
-                - table[d, qd, [0, 2]].astype(np.float64)).max()))
+            ds.append(float(np.abs(a - b).max()))
+            if tile_of is not None:
+                apart[t] = tile_of(a[0], a[1]) != tile_of(b[0], b[1])
         got = list(events.get((c, d), ()))
         for kind, after in excursions(ts, ds, radius, band):
             found += 1
+            over += int(apart.get(after, False))
             k = next((i for i, (t, made) in enumerate(got)
                       if t > after and made == (kind == "enter")), None)
             if k is None:
                 missed += 1
             else:
                 del got[:k + 1]
-    return {"crossings": found, "cross_missed": missed}
+    return {"crossings": found, "cross_missed": missed,
+            "crossings_over_border": over}

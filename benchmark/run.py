@@ -16,7 +16,14 @@ LAST line of stdout, the one JSON object of the contract.
 
 Set-up (``setup_s``) is everything from the start of this process to
 the first instant of the window. ``--rehearsal`` shrinks the sizes for
-a CPU run (never a cell) and is the only way past the TPU check.
+a CPU run (never a cell) and is the only way past the TPU check; a cell
+on several chips is rehearsed on as many host devices
+(``--xla_force_host_platform_device_count``, for the served cluster
+only).
+
+A cell's world is read from its configuration (``world.py``): one space
+on one chip, or one megaspace tiled over the cell's chips. Nothing here
+branches on a cell's or a configuration's name.
 """
 from __future__ import annotations
 
@@ -42,6 +49,8 @@ ROOT = os.path.dirname(HERE)
 sys.path.insert(0, HERE)          # the per-layer readers import scrapes, work
 WORK = os.path.join(ROOT, ".bench_work")
 
+from world import Shape, border_untested  # noqa: E402
+
 # Warm-up, after every client is placed: the game has to serve
 # WARM_FRAMES consecutive frames (at least WARM_SECONDS of them) on
 # time — in no more than WARM_SLACK times their nominal span — with no
@@ -49,14 +58,37 @@ WORK = os.path.join(ROOT, ".bench_work")
 WARM_FRAMES, WARM_SECONDS, WARM_SLACK = 5, 3.0, 1.1
 WARM_TIMEOUT_S = 240.0
 READY_TIMEOUT_S = 420.0
+# `start` gives a game 120 s to say it has started (cli.py
+# _wait_started, no option). A tiled world that compiles its tick in
+# that run needs a little more (fill 400,000 rows, then ~92 s of
+# compiling: 121 s, my chip run, PR 28), and `start` then reports FAILED
+# while the game is alive and comes up a second later. `start` skips
+# what already runs, so the operator's remedy is the harness's: wait
+# for the game's own word, then `start` once more for the gate.
+STARTED_TAG = b"GOWORLD_TPU_PROCESS_STARTED"
+SLOW_START_TIMEOUT_S = 900.0
+# the runtime's word when another process still holds a chip, and how
+# long a run asks again before it gives up
+CHIPS_BUSY = b"Device or resource busy"
+CHIPS_BUSY_TIMEOUT_S, CHIPS_BUSY_PAUSE_S = 60.0, 5.0
 GRACE_FRAMES = 2          # a send not seen this long after the close (and
 GRACE_MIN_S = 1.0         # at least this long) is `failed`
 WINDOW_LEAD_S = 0.5       # from choosing the window's start to the start
 SAMPLE_AT = 0.125         # where in the window the first audit sample falls
-# what --rehearsal changes (a CPU run at a tiny size)
+# what --rehearsal changes (a CPU run at a tiny size): sizes of ONE tile;
+# a tiled world keeps its tiling and takes them once a tile
 REHEARSAL = {"game": {"capacity": 2048, "extent_x": 1400.0,
                       "extent_z": 1400.0, "tick_hz": 4},
-             "world": {"live": 1500}, "clients": 16, "twin_sites": 2}
+             "world": {"live": 1500}, "clients": 16, "twin_sites": 2,
+             # a lap in 10 s, not 50: a window of 8 s holds crossings of
+             # the AOI edge (and, in a tiled world, of a tile border)
+             "orbit_step_rad": 0.125}
+# the program's own words when a tile's exchange buffers overflow
+# (entity/manager.py: it has no counter for them): lines of the game's
+# log since the window opened
+MESH_DROPPED = re.compile(
+    rb"megaspace (?:migrate demand \d+ exceeds|halo demand \d+ exceeds"
+    rb"|dropped \d+ border-crossing)")
 
 # the limit of every number that decides ``correct``: all are counts of
 # answers that differ from the reference's, so all are exact (PERF.md)
@@ -64,7 +96,8 @@ LIMITS = {"pos_wrong": 0, "order_back": 0, "final_missing": 0,
           "interest_extra": 0, "npc_stray": 0, "npc_cross_missing": 0,
           "rows_wrong": 0, "avatar_row_off": 0, "cross_missed": 0,
           "rpc_wrong": 0, "mirror_errors": 0, "never_seen": 0,
-          "shed": 0, "events_undecoded": 0, "world_size_off": 0}
+          "shed": 0, "events_undecoded": 0, "world_size_off": 0,
+          "entities_lost": 0, "mesh_dropped": 0, "border_untested": 0}
 
 
 def say(msg: str) -> None:
@@ -77,20 +110,33 @@ def free_port() -> int:
         return s.getsockname()[1]
 
 
-def env_for_children() -> dict:
+def env_for_children(host_devices: int = 0) -> dict:
     env = dict(os.environ)
     env["PYTHONPATH"] = os.pathsep.join(
         [ROOT] + ([env["PYTHONPATH"]] if env.get("PYTHONPATH") else []))
     env["JAX_LOG_COMPILES"] = "1"     # the game logs every compile
+    # the megaspace tick is traced in an order that follows Python's
+    # string hashes: under a random hash seed every process compiles it
+    # anew (89-92 s on the chip in each of 4 starts, my chip runs,
+    # PR 28; 5 of 6 starts missed the cache on the CPU, 6 of 6 hit under
+    # a fixed seed; PERF.md section 7). One seed for every run, so that
+    # only a checkout's first run compiles
+    env["PYTHONHASHSEED"] = "0"
+    if host_devices > 1:              # a rehearsal of a cell on several chips
+        env["XLA_FLAGS"] = " ".join(
+            [env.get("XLA_FLAGS", ""),
+             f"--xla_force_host_platform_device_count={host_devices}"]
+        ).strip()
     return env
 
 
-def gw(args: list[str], timeout: float) -> tuple[int, str, float]:
+def gw(args: list[str], timeout: float,
+       host_devices: int = 0) -> tuple[int, str, float]:
     t0 = time.monotonic()
     try:
         r = subprocess.run([sys.executable, "-m", "goworld_tpu"] + args,
                            capture_output=True, text=True,
-                           env=env_for_children(), cwd=ROOT,
+                           env=env_for_children(host_devices), cwd=ROOT,
                            timeout=timeout)
         rc, out = r.returncode, r.stdout + r.stderr
     except subprocess.TimeoutExpired as e:
@@ -191,6 +237,38 @@ class Cluster:
             data = f.read()
         return len(re.findall(rb"Compiling \S+", data)), lo + len(data)
 
+    def game_came_up(self, timeout: float) -> bool:
+        """After a `start` that gave up on the game: whether the game's
+        process is alive and says it has started within ``timeout``."""
+        end = time.monotonic() + timeout
+        while time.monotonic() < end:
+            try:
+                with open(os.path.join(self.sd, "run", "game1.pid")) as f:
+                    os.kill(int(f.read().strip()), 0)
+                with open(self.game_log, "rb") as f:
+                    if STARTED_TAG in f.read():
+                        return True
+            except (OSError, ValueError):
+                return False
+            time.sleep(1.0)
+        return False
+
+    def chips_were_busy(self) -> bool:
+        """Whether the game died because it could not open its chips."""
+        try:
+            with open(self.game_log, "rb") as f:
+                return CHIPS_BUSY in f.read()
+        except OSError:
+            return False
+
+    def mesh_dropped(self, lo: int) -> int:
+        """Lines of the game's log from byte ``lo`` that say a tile's
+        migrate or halo buffer overflowed or a border-crosser was
+        dropped."""
+        with open(self.game_log, "rb") as f:
+            f.seek(lo)
+            return len(MESH_DROPPED.findall(f.read()))
+
     def scrape(self) -> dict:
         """Both processes' counters at one edge of the window."""
         for left in (2, 1, 0):       # the edge's instant is that of the
@@ -213,6 +291,40 @@ class Cluster:
                 "syncage": syncage, "frames": frames, "ladder": ladder,
                 "events_undecoded": gv.get("aoi_events_dropped", {}),
                 "log_size": os.path.getsize(self.game_log)}
+
+
+def start_cluster(cl: Cluster, host_devices: int = 0) -> int:
+    """`start`, and what an operator would do where it fails for a
+    reason that passes. (1) The chips are busy: a game that held four
+    chips is gone some ten seconds before they can be opened again —
+    every thread of it has ended by then, nothing in ``/proc`` shows who
+    holds them — and the next game dies with ``open(/dev/vfio/1):
+    Device or resource busy`` (my chip runs, PR 28: 9-15 s after four
+    chips, 3 s after one; a run that followed another at once found
+    them busy once and free 14 s later). The harness opens no device
+    node to find out: it runs `start` again, which skips what runs.
+    (2) `start` gave up on a game that compiles its tick (see
+    STARTED_TAG above): wait for the game's own word, then `start` once
+    more for the gate."""
+    rc, out, secs = gw(["start", cl.sd], 1100, host_devices)
+    say(f"[run] start: rc {rc} in {secs:.1f} s: "
+        + " | ".join(out.strip().split("\n")[:6]))
+    busy_end = time.monotonic() + CHIPS_BUSY_TIMEOUT_S
+    while rc != 0 and cl.chips_were_busy() \
+            and time.monotonic() < busy_end:
+        time.sleep(CHIPS_BUSY_PAUSE_S)
+        os.replace(cl.game_log, cl.game_log + ".busy")
+        rc, out, secs = gw(["start", cl.sd], 1100, host_devices)
+        say(f"[run] the chips were busy; `start` again: rc {rc} in "
+            f"{secs:.1f} s: " + " | ".join(out.strip().split("\n")[:6]))
+    if rc != 0 and "game1: FAILED" in out \
+            and cl.game_came_up(SLOW_START_TIMEOUT_S):
+        rc, out, secs = gw(["start", cl.sd], 300, host_devices)
+        say(f"[run] the game came up after `start` had given up on "
+            f"it (it compiles its tick in this run); `start` once "
+            f"more: rc {rc} in {secs:.1f} s: "
+            + " | ".join(out.strip().split("\n")[:6]))
+    return rc
 
 
 def shed_count(ladder: dict) -> int:
@@ -353,12 +465,21 @@ def find_xplane(logdir: str) -> str | None:
 def effective(cfg: dict, mix: dict, rehearsal: bool) -> tuple[dict, dict]:
     cfg, mix = json.loads(json.dumps(cfg)), json.loads(json.dumps(mix))
     if rehearsal:
+        sh = Shape(cfg)
         cfg["game"].update(REHEARSAL["game"])
-        cfg["world"].update(REHEARSAL["world"])
-        mix["clients"] = REHEARSAL["clients"]
+        cfg["game"]["extent_x"] *= sh.tx
+        cfg["game"]["extent_z"] *= sh.tz
+        cfg["world"]["live"] = REHEARSAL["world"]["live"] * sh.tiles
+        mix["clients"] = REHEARSAL["clients"] * sh.tiles
+        if "orbit_step_rad" in mix:
+            mix["orbit_step_rad"] = REHEARSAL["orbit_step_rad"]
+        twins = int(mix.get("twin_sites", 0))
         mix["twin_sites"] = min(
-            int(mix.get("twin_sites", 0)), REHEARSAL["twin_sites"],
+            twins, REHEARSAL["twin_sites"] * sh.tiles,
             mix["clients"] // int(mix["group_size"]) // 2)
+        if twins and "twin_border_sites" in mix:    # the mix's own share
+            mix["twin_border_sites"] = math.ceil(
+                mix["twin_sites"] * int(mix["twin_border_sites"]) / twins)
     return cfg, mix
 
 
@@ -384,6 +505,12 @@ def run(a) -> int:
         mix = json.load(f)
     cfg, mix = effective(cfg, mix, a.rehearsal)
     chips = int(cell["chips"])
+    shape = Shape(cfg)
+    if shape.tiles not in (1, chips):
+        say(f"the configuration tiles {shape.tiles} chips, the cell asks "
+            f"for {chips}")
+        return 2
+    host_devices = chips if a.rehearsal else 0
     hz = float(a.tick_hz or cfg["game"]["tick_hz"])
     cfg["game"]["tick_hz"] = hz
     clients = int(mix["clients"])
@@ -408,19 +535,26 @@ def run(a) -> int:
         served["aoi_radius"] = float(served["aoi_radius"]) - 2.0
     with open(os.path.join(sd, "goworld_tpu.ini"), "w") as f:
         f.write(ini.format(
-            game_keys="\n".join(f"{k} = {v}" for k, v in served.items()),
+            game_keys="\n".join(
+                f"{k} = {str(v).lower() if isinstance(v, bool) else v}"
+                for k, v in served.items()),
             deployment=deployment, **cl.ports))
     with open(os.path.join(sd, "bench_params.json"), "w") as f:
         json.dump({"npcs": npcs, "seed": a.seed,
-                   "extent": cfg["game"]["extent_x"],
+                   "megaspace": shape.mega, "borders": shape.borders,
+                   "extent_x": shape.extent_x, "extent_z": shape.extent_z,
                    "aoi_radius": cfg["game"]["aoi_radius"],
                    "plant": a.plant}, f)
     for name, obj in (("config.json", cfg), ("mix.json", mix)):
         with open(os.path.join(sd, name), "w") as f:
             json.dump(obj, f)
     say(f"[run] {a.workload}: config {cell['config']}, mix "
-        f"{cell['traffic']}, {clients} clients, {npcs} NPCs, capacity "
-        f"{cfg['game']['capacity']}, {hz:g} Hz, seed {a.seed}, window "
+        f"{cell['traffic']}, {clients} clients, {npcs} NPCs, "
+        + (f"a megaspace of {shape.tx}x{shape.tz} tiles, " if shape.mega
+           else "one space, ")
+        + f"capacity {cfg['game']['capacity']}"
+        + (" a tile" if shape.mega else "")
+        + f", {hz:g} Hz, seed {a.seed}, window "
         f"{a.seconds:g} s, trace {int(traced)}"
         + (", REHEARSAL" if a.rehearsal else "")
         + (f", CONTROL faults {a.control_faults}" if a.control_faults
@@ -430,9 +564,7 @@ def run(a) -> int:
     result: dict = {}
     problems: list[str] = []
     try:
-        rc, out, secs = gw(["start", sd], 1100)
-        say(f"[run] start: rc {rc} in {secs:.1f} s: "
-            + " | ".join(out.strip().split("\n")[:6]))
+        rc = start_cluster(cl, host_devices)
         if rc != 0:
             tail_logs(sd)
             return 1
@@ -488,8 +620,11 @@ def run(a) -> int:
                            - time.monotonic()))
             # the frames the capture holds, by the game's own histogram:
             # host_ms is read over these, not over the whole window
-            span_open = {"game": parse_prom(http(
-                cl.ports["game_http_port"], "metrics"))}
+            span_open = {
+                "game": parse_prom(http(cl.ports["game_http_port"],
+                                        "metrics")),
+                "gate": parse_prom(http(cl.ports["gate_http_port"],
+                                        "metrics"))}
             prof = capture(cl, span, os.path.join(sd, "profile"))
             say(f"[run] profiler capture: {prof}")
             time.sleep(span)
@@ -531,7 +666,10 @@ def run(a) -> int:
             bots.wait(timeout=30)
         except subprocess.TimeoutExpired:
             problems.append("the clients' process did not exit")
+        # window, settle wait and read-back: until the last answer
+        dropped = cl.mesh_dropped(edge0["log_size"])
         result = {"edge0": edge0, "edge1": edge1, "setup_s": setup_s,
+                  "mesh_dropped": dropped,
                   "compiles_in_window": in_window, "device": device,
                   "audit_samples_in_window": samples,
                   "on_chip": on_chip, "world": gv.get("bench_npcs"),
@@ -565,10 +703,10 @@ def run(a) -> int:
     if problems or not result:
         say(f"[run] no result: {problems}")
         return 1
-    return report(a, bench, cell, cfg, mix, cl, result)
+    return report(a, bench, cell, cfg, mix, cl, result, shape)
 
 
-def report(a, bench, cell, cfg, mix, cl, res) -> int:
+def report(a, bench, cell, cfg, mix, cl, res, shape) -> int:
     sd = cl.sd
     with open(os.path.join(sd, "bots.json")) as f:
         bots = json.load(f)
@@ -608,7 +746,13 @@ def report(a, bench, cell, cfg, mix, cl, res) -> int:
               "memory_peak_bytes": stats.get("memory_peak_bytes")}
     cellinfo = {"cell": cell, "config": cfg, "mix": mix,
                 "device_kind": device["kind"], "served_hz": frames / seconds}
-    scrapes = {"open": e0, "close": e1, "bots": bots,
+    # a traced run: the window's series end where the capture begins.
+    # The tracer stretches the captured frames' host spans, and the
+    # frame in which stop_trace runs takes seconds on four chips
+    # (2.66 s of `decode_fanout`, my chip run, PR 28): read over the
+    # whole window, every span's mean would be the tracer's
+    close = dict(e1, **res["span_open"]) if res["span_open"] else e1
+    scrapes = {"open": e0, "close": close, "bots": bots,
                "span_open": res["span_open"],
                "span_close": res["span_close"]}
     metrics_out: dict = {}
@@ -643,11 +787,18 @@ def report(a, bench, cell, cfg, mix, cl, res) -> int:
     numbers["world_size_off"] = abs(
         int(res["world"] or 0)
         - (int(cfg["world"]["live"]) - int(mix["clients"])))
+    # a tiled world: log lines that say a tile's exchange overflowed;
+    # and the checks judged at the clients must have met a seam — a run
+    # with no AOI-edge crossing and no final neighbourhood across a tile
+    # border has not tested what the cell is for
+    over = bots.get("over_border") or {}
+    numbers["mesh_dropped"] = res["mesh_dropped"]
+    numbers["border_untested"] = border_untested(over, shape.mega)
     checks = {k: {"value": numbers[k], "limit": LIMITS[k]}
               for k in LIMITS}
     correct = all(c["value"] <= c["limit"] for c in checks.values())
     say(f"[run] end to end: {json.dumps(end_to_end)}")
-    say(f"[run] clients: {json.dumps({k: bots[k] for k in ('sends', 'calls', 'move_failed', 'rpc_failed', 'gen_late_ms', 'receipts', 'sync_records', 'npcs_mirrored', 'settled_s_after_close', 'crossings', 'rows_read', 'mirror_errors_first')})}")
+    say(f"[run] clients: {json.dumps({k: bots[k] for k in ('sends', 'calls', 'move_failed', 'rpc_failed', 'gen_late_ms', 'receipts', 'sync_records', 'npcs_mirrored', 'settled_s_after_close', 'crossings', 'rows_read', 'over_border', 'mirror_errors_first')})}")
     line = {"correct": correct, "attempted": bots["attempted"],
             "failed": bots["failed"], "metrics": metrics_out,
             "device": device}
@@ -655,6 +806,7 @@ def report(a, bench, cell, cfg, mix, cl, res) -> int:
         line["breakdown"] = trace["breakdown"]
     line["compiles_in_window"] = res["compiles_in_window"]
     line["audit_samples_in_window"] = res["audit_samples_in_window"]
+    line["over_border"] = over
     line["checks"] = checks
     for k, c in checks.items():
         print(f"check {k}: {c['value']} (limit {c['limit']})",
@@ -682,7 +834,8 @@ def main(argv=None) -> int:
                     help="the control: a [deployment] faults spec that "
                          "breaks a guarantee (docs/ROBUSTNESS.md)")
     ap.add_argument("--plant", default="",
-                    choices=("", "alter", "half", "freeze", "radius"),
+                    choices=("", "alter", "half", "freeze", "radius",
+                             "caps", "lose"),
                     help="tests: break the timed path underneath")
     return run(ap.parse_args(argv))
 

@@ -1,5 +1,7 @@
 """Helpers the per-layer readers share: window deltas of the series
-``run.py`` scraped at both edges."""
+``run.py`` scraped at both edges (``open``, ``close``; in a traced run
+``close`` holds the game's and the gate's series as they stood when the
+capture began: run.py ``report``)."""
 from __future__ import annotations
 
 
@@ -47,7 +49,17 @@ def frame_ms_traced(scrapes: dict) -> float | None:
 
 def busy_ms_per_frame(trace: dict | None, cell: dict) -> float | None:
     """Device busy milliseconds per served frame over the traced
-    window's whole frames."""
-    if not trace or not trace.get("busy_s") or not trace.get("frames"):
+    window's whole frames: on the BUSIEST device plane where the cell
+    lies on several chips (the device every frame waits for)."""
+    one = plane_of(trace)
+    if not one or not one.get("busy_s") or not one.get("frames"):
         return None
-    return 1e3 * trace["busy_s"] / trace["frames"]
+    return 1e3 * one["busy_s"] / one["frames"]
+
+
+def plane_of(trace: dict | None) -> dict | None:
+    """``busy_s``, ``window_s`` and ``frames`` of the busiest device
+    plane of a reduced capture (the only one, on one chip)."""
+    if not trace:
+        return None
+    return trace.get("busiest") or trace

@@ -114,7 +114,7 @@ def test_interest_check_passes_and_catches_each_fault():
     plan, final, xz, mirrors = _world()
     ok = R.interest_check(xz, 50.0, 12.0, mirrors, final)
     assert ok == dict(final_missing=0, interest_extra=0, npc_stray=0,
-                      npc_cross_missing=0)
+                      npc_cross_missing=0, finals_over_border=0)
     # the partner mirrored at a stale place
     _p, final, xz, m = _world()
     m[0]["p1"] = ("client", 1, tuple(plan.positions(8)[1, 4]))
@@ -199,14 +199,16 @@ def test_rows_check_catches_a_wrong_list_and_a_stale_avatar():
     rows = np.arange(0, n, 3)
     final = np.concatenate([pos[:4], np.zeros((4, 1), np.float32)], axis=1)
     ok = R.rows_check(pos, alive, rows, nbr[rows], 50.0, np.arange(4), final)
-    assert ok == {"rows_wrong": 0, "avatar_row_off": 0}
+    assert ok == {"rows_wrong": 0, "avatar_row_off": 0,
+                  "rows_wrong_near_border": 0}
     bad = nbr[rows].copy()
     full = next(i for i in range(len(rows)) if bad[i, 0] < cap)
     bad[full, 0] = cap                      # a neighbour left out
     bad[full + 1, -1] = int(rows[full + 1]) ^ 1   # one too many
     final[2, 0] += 0.5                      # the device holds another place
     got = R.rows_check(pos, alive, rows, bad, 50.0, np.arange(4), final)
-    assert got == {"rows_wrong": 2, "avatar_row_off": 1}
+    assert got == {"rows_wrong": 2, "avatar_row_off": 1,
+                   "rows_wrong_near_border": 0}
 
 
 def test_cross_check_wants_an_answer_after_each_definite_crossing():
@@ -242,3 +244,24 @@ def test_cross_check_wants_an_answer_after_each_definite_crossing():
     early = [(t - 2.0, made) for t, made in ev]
     assert R.cross_check([(1, 2)], sends, table, {(1, 2): early}, 50.0,
                          3.0)["cross_missed"] >= 1
+
+
+def test_reference_by_sorted_x_equals_every_pair():
+    """``neighbours_of`` against the brute force over every pair, on
+    points of a lattice (many pairs at EXACTLY the radius, in x, in z
+    and in both) and on random f32 points."""
+    from reference import chebyshev, neighbours_of
+
+    def every_pair(xz, rows, radius):
+        d = chebyshev(xz[rows], xz)
+        return [{int(j) for j in np.nonzero(d[r] <= radius)[0] if j != i}
+                for r, i in enumerate(rows)]
+
+    rng = np.random.default_rng(11)
+    lattice = rng.integers(0, 12, (600, 2)).astype(np.float64) * 25.0
+    cloud = rng.uniform(0.0, 2000.0, (3000, 2)).astype(np.float32) \
+        .astype(np.float64)
+    for xz in (lattice, cloud):
+        rows = rng.choice(len(xz), 200, replace=False)
+        assert neighbours_of(xz, rows, 50.0) \
+            == every_pair(xz, rows, 50.0)

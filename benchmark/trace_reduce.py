@@ -9,7 +9,13 @@ operations that took most device time, and the longest idle gaps.
   each, the line ``XLA Ops`` holds one event per executed operation
   (``XLA Modules`` stands in where a trace has no such line). Busy is
   the UNION of those events' intervals — nested or overlapping events
-  count once — averaged over the device planes;
+  count once. A cell on several chips leaves one plane per chip, and
+  one rule holds for every per-layer metric (benchmark/README.md): it
+  is read on the BUSIEST plane — the one with the most busy time per
+  frame, the device every frame waits for — never as a mean
+  (``busiest``, ``per_plane``; the breakdown's operations and gaps are
+  that plane's). Only ``busy_s`` and ``window_s``, which the contract's
+  ``device`` block carries, are means over the planes;
 * the traced window (``window_s``) is cut to whole frames: from the
   first to the last start of the tick's program on the device (see
   ``reduce_planes``);
@@ -31,6 +37,12 @@ import sys
 
 DEVICE_PLANE = re.compile(r"^/device:TPU:\d+$")
 OPS_LINES = ("XLA Ops", "XLA Modules")
+# operations that move data between chips, by the HLO instruction's name
+COLLECTIVE = re.compile(
+    r"^(all-to-all|all-reduce|all-gather|reduce-scatter|collective-permute"
+    r"|collective-broadcast|ragged-all-to-all|send|recv)\b")
+ASYNC_LINE = "Async XLA Ops"
+IN_FLIGHT = " (async, in flight)"
 
 
 def union(intervals: list[tuple[float, float]]) -> list[tuple[float, float]]:
@@ -72,9 +84,10 @@ def reduce_planes(planes: list[dict], frame_s: float) -> dict:
                  "busy_s": None, "window_s": None, "frames": None,
                  "breakdown": None}
     devs = [p for p in planes if DEVICE_PLANE.match(p["name"])]
-    busy, spans, frames, ops, gaps = [], [], [], {}, {}
-    longest = 0.0
+    busy, spans, frames, names = [], [], [], []
+    per_ops, per_gaps, per_longest = [], [], []
     for p in devs:
+        ops, gaps, longest = {}, {}, 0.0
         out["lines"][p["name"]] = {ln["name"]: len(ln["events"])
                                    for ln in p["lines"]}
         line = next((ln for want in OPS_LINES for ln in p["lines"]
@@ -111,10 +124,24 @@ def reduce_planes(planes: list[dict], frame_s: float) -> dict:
         busy.append(clip(merged, lo, hi) / 1e9)
         spans.append((hi - lo) / 1e9)
         frames.append(n_frames)
+        names.append(p["name"])
+        per_ops.append(ops)
+        per_gaps.append(gaps)
         for name, s, d in line["events"]:
             if s >= lo and s + d <= hi:
                 name = short_name(name)
-                ops[name] = ops.get(name, 0.0) + d / 1e9 / len(devs)
+                ops[name] = ops.get(name, 0.0) + d / 1e9
+        # an asynchronous collective costs the ops line microseconds
+        # (its start and its done); how long it was in flight, beside
+        # the operations it overlaps, is on a line of its own where the
+        # plane has one (a v5e's first chip, PR 28)
+        for ln in p["lines"]:
+            if ln["name"] == ASYNC_LINE:
+                for name, s, d in ln["events"]:
+                    name = short_name(name)
+                    if s >= lo and s + d <= hi and COLLECTIVE.match(name):
+                        name += IN_FLIGHT
+                        ops[name] = ops.get(name, 0.0) + d / 1e9
         edges = [lo] + [min(max(x, lo), hi)
                         for se in merged for x in se] + [hi]
         for i in range(0, len(edges), 2):
@@ -124,19 +151,27 @@ def reduce_planes(planes: list[dict], frame_s: float) -> dict:
             label = "pacing sleep (frame remainder)" \
                 if g > 0.25 * frame_s else \
                 "between ops (host: flush, fetch, decode, pump)"
-            gaps[label] = gaps.get(label, 0.0) + g / len(devs)
+            gaps[label] = gaps.get(label, 0.0) + g
             longest = max(longest, g)
+        per_longest.append(longest)
     if not busy:
         return out
     out["busy_s"] = sum(busy) / len(busy)
     out["window_s"] = sum(spans) / len(spans)
     if all(f is not None for f in frames):
         out["frames"] = sum(frames) / len(frames)
-    out["device_planes"] = len(devs)
-    out["longest_gap_s"] = longest
-    top = sorted(ops.items(), key=lambda kv: -kv[1])[:10]
+    out["device_planes"] = len(busy)
+    out["per_plane"] = [
+        {"plane": n, "busy_s": b, "window_s": w, "frames": f}
+        for n, b, w, f in zip(names, busy, spans, frames)]
+    # the plane every frame waits for: most busy time per frame
+    at = max(range(len(busy)), key=lambda i: busy[i] / (frames[i] or 1))
+    out["busiest"] = out["per_plane"][at]
+    out["longest_gap_s"] = per_longest[at]
+    ops, gaps = per_ops[at], per_gaps[at]
     out["breakdown"] = {
-        "device_ops": [[n, s] for n, s in top],
+        "device_ops": [[n, s] for n, s in
+                       sorted(ops.items(), key=lambda kv: -kv[1])[:10]],
         "idle_gaps": [[n, s] for n, s in
                       sorted(gaps.items(), key=lambda kv: -kv[1])[:10]]}
     return out

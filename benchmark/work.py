@@ -15,12 +15,18 @@ Bytes of one tick (``necessary_bytes``):
 
 ``least_seconds`` divides by the chip's HBM peak (``peaks.json``); the
 bound is bytes: the tick does a few operations per byte, far under the
-chip's 240 FLOP per byte ridge.
+chip's 240 FLOP per byte ridge. In a world tiled over several chips the
+bytes are ONE tile's (its share of the live rows and of the clients)
+against ONE chip's peak, as the busy time they are held against is one
+chip's (the busiest: benchmark/README.md); ghost rows and what the
+exchange moves are the mesh's price, not necessary work.
 """
 from __future__ import annotations
 
 import json
 import os
+
+from world import tiles as tiles_of
 
 ATTR_WIDTH = 8      # WorldConfig.attr_width default
 K = 64              # GridSpec k default (utils/consts.py)
@@ -49,9 +55,11 @@ def peaks(device_kind: str) -> dict:
 
 
 def least_seconds(cfg: dict, mix: dict, device_kind: str) -> float:
-    """The least time the chip could take for one tick: its necessary
-    bytes at the HBM peak."""
-    b = necessary_bytes(int(cfg["world"]["live"]), int(mix["clients"]),
+    """The least time a chip could take for one tick of its tile: the
+    tile's necessary bytes at the HBM peak."""
+    tiles = tiles_of(cfg)
+    b = necessary_bytes(int(cfg["world"]["live"]) // tiles,
+                        int(mix["clients"]) // tiles,
                         int(mix["group_size"]),
                         float(cfg["world"]["expected_neighbours"]))
     return b / float(peaks(device_kind)["hbm_bytes_per_s"])
