@@ -88,6 +88,12 @@ def wave_bounds(tiles_in_order, g: int) -> list[int]:
     return bounds
 
 
+def does(op: tuple) -> dict:
+    """What a generator's ``("call", method, args, does)`` says it does
+    to its client (nothing, where it says nothing)."""
+    return op[3] if len(op) > 3 else {}
+
+
 def load_generator(kind: str):
     path = os.path.join(HERE, "generators", f"{kind}.py")
     spec = importlib.util.spec_from_file_location(f"gen_{kind}", path)
@@ -103,6 +109,8 @@ class Log:
         self.by_eid: dict[bytes, int] = {}    # avatar eid -> client
         self.sync: list[tuple] = []   # (receiver, t, senders, vals)
         self.echo: list[tuple] = []   # (receiver, t, token)
+        self.reply: list[tuple] = []  # (receiver, t, method, token): the
+        #                               fixture's answer to any other call
         self.made: list[tuple] = []   # (receiver, t, eid, created?)
         self.stats: str | None = None
         self.rows: str | None = None
@@ -150,6 +158,8 @@ class Client(BotClient):
                     self.log.stats = args[0]
                 elif method == "OnRows":
                     self.log.rows = args[0]
+                elif args:
+                    self.log.reply.append((self.idx, t, method, args[0]))
             del self.rpc_log[n:]
             return
         if msgtype in (proto.MT_CREATE_ENTITY_ON_CLIENT,
@@ -173,9 +183,24 @@ async def main_async(a) -> int:
     radius = float(cfg["game"]["aoi_radius"])
     shape = Shape(cfg)
     plan = gen.Plan(mix, shape.extent_x, radius, n,
-                    **({"borders": shape.borders} if shape.mega else {}))
+                    **({"borders": shape.borders} if shape.mega else
+                       {"spaces": shape.spaces} if shape.spaces > 1 else {}))
     tile_of = shape.tile_of if shape.mega else None
     table = plan.positions(MAX_SEQ)
+    # a plan of a world of many spaces gives the call with which each
+    # client enters it, at (space, x, z). Anywhere else the fixture
+    # parks the logins itself
+    login = [plan.login(c) for c in range(n)] \
+        if hasattr(plan, "login") else None
+    space_now = np.array([does(op)["at"][0] for op in login], np.int64) \
+        if login else np.zeros(n, np.int64)
+    stands_at = [does(op)["at"][1:] for op in login] if login else None
+    stood_at: list[set] = [set() for _ in range(n)]  # every place so far
+    # a plan that keeps state schedules itself, and is told the first
+    # instant of every stretch
+    schedule = plan.schedule if hasattr(plan, "schedule") else (
+        lambda seed, seconds, stream, _start: gen.schedule(
+            mix, n, seed, seconds, stream))
     observer = np.array([plan.observer(c) for c in range(n)])
     loop = asyncio.get_running_loop()
     log = Log()
@@ -184,8 +209,34 @@ async def main_async(a) -> int:
     tasks = []
     gap = min(WAVE_CALM_FRAMES / float(cfg["game"]["tick_hz"]),
               WAVE_CALM_MAX_S)
+    seq = np.zeros(n, np.int64)            # last sequence number sent
+    written: list[dict] = [{} for _ in range(n)]   # attr -> last value
+
+    def call(c: int, op: tuple, token: str, at: float) -> None:
+        """``("call", method, args, does)``: a call of one of the
+        fixture's ``*_Client`` methods, due at ``at``; the token comes
+        back first in the fixture's reply. What the call does to its
+        client the operation says itself (``does``): ``at`` (space, x,
+        z): the avatar stands there from now on, so the client sends
+        from there (the rows of its table that are still to be sent
+        move by the difference); ``attrs``: values every holder of the
+        avatar must show at the end. No method is known here by name."""
+        bots[c].call_server(op[1], *op[2], token)
+        if "at" in does(op):
+            space, x, z = does(op)["at"]
+            space_now[c] = int(space)
+            dx, dz = x - stands_at[c][0], z - stands_at[c][1]
+            if dx or dz:
+                table[c, seq[c] + 1:, 0] += np.float32(dx)
+                table[c, seq[c] + 1:, 2] += np.float32(dz)
+            stands_at[c] = (x, z)
+            stood_at[c].add((np.float32(x), np.float32(z)))
+        written[c].update(does(op).get("attrs", {}))
+        if hasattr(plan, "called"):
+            plan.called(c, op, at)
     # the order of the waves, and every client's place in it
-    first_tile = np.zeros(n, np.int64) if tile_of is None \
+    # (what a wave must not bring too much of: a tile, or a space)
+    first_tile = space_now.copy() if tile_of is None \
         else tile_of(table[:, 1, 0], table[:, 1, 2])
     order = round_the_tiles(
         [first_tile[plan.members(g)[0]] for g in range(n // plan.g)],
@@ -200,23 +251,32 @@ async def main_async(a) -> int:
         for b in wave:
             await b.connect()
             tasks.append(loop.create_task(b._recv_loop()))
-        while not all(b.player is not None for b in wave):
+        asked = False
+        while not all(b.player is not None for b in wave) or (
+                login and not (asked and wanted <= {
+                    tok for _r, _t, _m, tok in log.reply[n_reply:]})):
             if time.monotonic() > end:
                 print("FAILED login: %d of %d clients have a player" % (
                     sum(b.player is not None for b in bots), n),
                     flush=True)
                 return 1
+            if login and not asked \
+                    and all(b.player is not None for b in wave):
+                asked, n_reply = True, len(log.reply)
+                wanted = {f"login.{c}" for c in order[lo:hi]}
+                for c in order[lo:hi]:
+                    call(int(c), login[c], f"login.{c}", time.monotonic())
             await asyncio.sleep(0.05)
         await asyncio.sleep(gap)
     for i, b in enumerate(bots):
         log.by_eid[b.player.eid.encode("ascii")] = i
 
-    seq = np.zeros(n, np.int64)            # last sequence number sent
     wave_at = 0
     moving = waves[0][1]                   # clients that may send yet
     window: dict = {}
     sends: list[tuple] = []                # (client, seq, due, sent)
     calls: list[tuple] = []                # (client, token, due, sent)
+    others: list[tuple] = []               # (client, op, token, due, sent)
 
     async def drive(offs, who, kind, start, stop, record) -> None:
         """Run one stretch of schedule, open loop, until ``stop()``
@@ -232,7 +292,13 @@ async def main_async(a) -> int:
             c = int(who[k])
             if rank[c] >= moving:
                 continue                   # its wave has not come yet
-            if kind[k] == gen.SEND:
+            if isinstance(kind[k], tuple):     # ("call", method, args, does)
+                token = f"{a.seed}.{c}.{kind[k][1]}.{len(others)}.{record}"
+                call(c, kind[k], token, due)
+                if record:
+                    others.append((c, kind[k], token, due,
+                                   time.monotonic()))
+            elif kind[k] == gen.SEND:
                 seq[c] += 1
                 v = table[c, seq[c]]
                 bots[c].send_position(float(v[0]), float(v[1]),
@@ -256,16 +322,21 @@ async def main_async(a) -> int:
 
     reader = loop.run_in_executor(None, read_window)
 
+    met = np.zeros(n, bool)                # has mirrored its whole group
+    #                                        at a position it SENT (height
+    #                                        >= 1): once is enough, a change
+    #                                        of place puts a partner at
+    #                                        height 0 again for a frame
+
     def placed(upto: int) -> bool:
         for c in order[:upto]:
-            ents = bots[c].entities
-            for d in plan.members(plan.group_of(c)):
-                if d == c:
-                    continue
-                m = ents.get(bots[d].player.eid)
-                if m is None or m.pos[1] < 1.0:
-                    return False
-        return True
+            if not met[c]:
+                ents = bots[c].entities
+                met[c] = all(
+                    (m := ents.get(bots[d].player.eid)) is not None
+                    and m.pos[1] >= 1.0
+                    for d in plan.members(plan.group_of(c)) if d != c)
+        return bool(met[order[:upto]].all())
 
     # warm-up: the same cadence as the window, in stretches of 10 s
     ready = False
@@ -273,7 +344,7 @@ async def main_async(a) -> int:
     while "t0" not in window or time.monotonic() < window["t0"]:
         stretch += 1
         start = time.monotonic()
-        offs, who, kind = gen.schedule(mix, n, a.seed, 10.0, stretch)
+        offs, who, kind = schedule(a.seed, 10.0, stretch, start)
         task = loop.create_task(drive(
             offs, who, kind, start, lambda: window.get("t0"), False))
         while not task.done():
@@ -301,19 +372,19 @@ async def main_async(a) -> int:
     # ---- the window ----------------------------------------------------
     t0, seconds, grace = window["t0"], window["seconds"], window["grace"]
     n_sync0, n_echo0 = len(log.sync), len(log.echo)
-    offs, who, kind = gen.schedule(mix, n, a.seed, seconds, 0)
+    n_reply0 = len(log.reply)
+    offs, who, kind = schedule(a.seed, seconds, 0, t0)
     await asyncio.sleep(max(0.0, t0 - time.monotonic()))
     await drive(offs, who, kind, t0, lambda: None, True)
     close = t0 + seconds + grace
     await asyncio.sleep(max(0.0, close - time.monotonic()))
 
     # ---- settle: wait for the answers that are due, then judge them ----
-    last = seq.copy()
-    final_vals = table[np.arange(n), last]
-    final_xz = final_vals[:, [0, 2]]
     slack = float(cfg.get("check", {}).get("npc_slack", 12.0))
 
     def snapshot():
+        final_vals = table[np.arange(n), seq]      # the last position sent
+        final_xz = final_vals[:, [0, 2]]
         mirrors = []
         for c, b in enumerate(bots):
             mir = {}
@@ -325,17 +396,55 @@ async def main_async(a) -> int:
                 mir[eid] = ("client", d, vals) if d is not None \
                     else ("npc", eid, vals)
             mirrors.append(mir)
-        return R.interest_check(
+        out = R.interest_check(
             final_xz, radius, slack, mirrors, final_vals,
             None if tile_of is None
-            else tile_of(final_xz[:, 0], final_xz[:, 1]))
+            else tile_of(final_xz[:, 0], final_xz[:, 1]),
+            space_now if shape.spaces > 1 else None)
+        # an attr a client holds, of its own avatar or of another's, is
+        # the last value its owner wrote (none: unset)
+        out["attr_wrong"] = sum(
+            m.attrs.get(name) != written[d].get(name)
+            for b in bots for eid, m in b.entities.items()
+            if (d := log.by_eid.get(eid.encode("ascii"))) is not None
+            for name in attrs_written)
+        return out, final_vals, final_xz
+
+    # the stream ends at the close, a real client's goes on: one that
+    # has sent nothing since its last change of place was answered
+    # sends its next position then, as it would have 100 ms later. (What
+    # it sent meanwhile tells the world nothing for sure: the gate
+    # forwards position syncs in batches every 100 ms and calls at once,
+    # as upstream's does, so a position sent before the call may be
+    # applied after it, in the new place; and the program stages no
+    # sync for an avatar between two spaces, as the configuration's
+    # guarantee says.)
+    attrs_written = sorted({k for w in written for k in w})
+    last_sent = {c: sent for c, _q, _due, sent in sends}
+    moving_yet = {c: tok for c, op, tok, _due, _sent in others
+                  if "at" in does(op)}
+
+    def send_on() -> None:
+        told = {(r, tok): t for r, t, _m, tok in log.reply[n_reply0:]}
+        for c, tok in list(moving_yet.items()):
+            t = told.get((c, tok))
+            if t is not None:
+                del moving_yet[c]
+                if last_sent.get(c, -1.0) <= t:
+                    seq[c] += 1
+                    v = table[c, seq[c]]
+                    bots[c].send_position(float(v[0]), float(v[1]),
+                                          float(v[2]), float(v[3]))
 
     settle_end = time.monotonic() + SETTLE_TIMEOUT_S
-    check = snapshot()
-    while (check["final_missing"] or check["interest_extra"]) \
+    send_on()
+    check, final_vals, final_xz = snapshot()
+    while (check["final_missing"] or check["interest_extra"]
+           or check["attr_wrong"] or moving_yet) \
             and time.monotonic() < settle_end:
         await asyncio.sleep(0.5)
-        check = snapshot()
+        send_on()
+        check, final_vals, final_xz = snapshot()
     settled_s = time.monotonic() - close
 
     bots[0].call_server("Stats_Client")
@@ -353,7 +462,8 @@ async def main_async(a) -> int:
     live = int(cfg["world"]["live"])
     rows = {"rows_wrong": ROWS_SAMPLE * shape.tiles + n,
             "avatar_row_off": n, "entities_lost": live, "rows_read": 0,
-            "rows_near_border": 0, "rows_wrong_near_border": 0}
+            "rows_near_border": 0, "rows_wrong_near_border": 0,
+            "space_wrong": n}
     if log.rows is not None:
         with np.load(os.path.join(os.path.dirname(a.out), log.rows)) as z:
             order = {e: i for i, e in enumerate(z["avatar_eids"].tolist())}
@@ -364,9 +474,21 @@ async def main_async(a) -> int:
                 pos, held = z["pos"], z["rows"]
                 near = shape.border_distance(
                     pos[held, 0], pos[held, 2]) <= radius
+                many = shape.spaces > 1
                 rows = R.rows_check(
                     pos, z["alive"], held, z["nbr"], radius,
-                    z["avatar_rows"][mine], final_vals, near)
+                    z["avatar_rows"][mine], final_vals, near,
+                    *((shape.space_of(np.arange(len(pos))), shape.capacity)
+                      if many else ()))
+                # every avatar in the space its client entered last, and
+                # no mirror with an NPC of another space (the device's
+                # rows say which space an NPC lives in)
+                rows["space_wrong"] = R.space_wrong(
+                    shape.space_of(z["avatar_rows"][mine]), space_now,
+                    [set(b.entities) for b in bots],
+                    dict(zip(z["npc_eids"].tolist(),
+                             shape.space_of(z["npc_rows"]).tolist()))
+                ) if many else 0
                 rows["rows_read"] = int(len(held))
                 rows["rows_near_border"] = int(near.sum())
                 # live rows on the device against the configuration's:
@@ -410,9 +532,43 @@ async def main_async(a) -> int:
                        for c, tok, _d, _s in calls])
     rpc_ms, rpc_failed = R.latencies(c_due, c_seen, close)
     never_seen += int(np.isnan(c_seen).sum())
+    # a record at height 0 is no send's: it is the place a call put
+    # the avatar at (the program syncs a row it has just made), to be
+    # held against the places that client has stood at, not against a
+    # send, and out of the order of the sends
+    put = (rc_vals[:, 1] == 0.0) & np.array(
+        [bool(stood_at[c]) for c in range(n)], bool)[rc_sender]
+    put_wrong = sum(
+        (x, z) not in stood_at[c]
+        for c, x, z in zip(rc_sender[put].tolist(), rc_vals[put, 0],
+                           rc_vals[put, 2]))
     pos_wrong, order_back = R.stream_faults(
-        rc_recv, rc_sender, rc_seq, rc_vals, table, n)
-    late = np.concatenate([s_sent - s_due, c_sent - c_due]) * 1e3
+        rc_recv[~put], rc_sender[~put], rc_seq[~put], rc_vals[~put],
+        table, n)
+    pos_wrong += put_wrong
+    # the fixture's other calls: answered once each, at their client
+    told: dict[tuple, float] = {}
+    for r, t, _m, tok in log.reply[n_reply0:]:
+        told.setdefault((r, tok), t)
+    o_due = np.array([o[3] for o in others])
+    o_sent = np.array([o[4] for o in others])
+    o_seen = np.array([told.get((o[0], o[2]), np.nan) for o in others])
+    other_ms, other_failed = R.latencies(o_due, o_seen, close)
+    never_seen += int(np.isnan(o_seen).sum())
+    # a change of place: answered, and told to the partner's mirror as
+    # a leaving and then an entering
+    made_at: dict[tuple, list] = {}
+    for r, t, eid, created in log.made:
+        d = log.by_eid.get(eid.encode("ascii"))
+        if d is not None and r == observer[d] and t >= t0:
+            made_at.setdefault((r, d), []).append((t, created))
+    hops = R.hop_check(
+        [(o[0], o[4], o_seen[i]) for i, o in enumerate(others)
+         if "at" in does(o[1])], observer, made_at,
+        sum(isinstance(k, tuple) and "at" in does(k) for k in kind),
+        t0 + seconds)
+    late = np.concatenate([s_sent - s_due, c_sent - c_due,
+                           o_sent - o_due]) * 1e3
     # enters and leaves between clients of twin groups, inside the window
     pairs = plan.crossers()
     wanted = set(pairs)
@@ -429,7 +585,10 @@ async def main_async(a) -> int:
         "finals_over_border": check.pop("finals_over_border"),
         "rows_near_border": rows.pop("rows_near_border"),
         "rows_wrong_near_border": rows.pop("rows_wrong_near_border")}
+    rows["space_wrong"] += check.pop("space_wrong", 0)
     numbers = dict(check, **rows, cross_missed=cross["cross_missed"],
+                   hop_unanswered=hops["hop_unanswered"],
+                   hops_untested=hops["hops_untested"],
                    pos_wrong=pos_wrong, order_back=order_back,
                    rpc_wrong=rpc_wrong, never_seen=never_seen,
                    mirror_errors=sum(len(b.errors) for b in bots))
@@ -439,10 +598,17 @@ async def main_async(a) -> int:
             "move_seen_ms.p95": R.nearest_rank(move_ms, 0.95),
             "rpc_ms.p95": R.nearest_rank(rpc_ms, 0.95),
         },
-        "attempted": len(sends) + len(calls),
-        "failed": move_failed + rpc_failed,
+        "attempted": len(sends) + len(calls) + len(others),
+        "failed": move_failed + rpc_failed + other_failed,
         "sends": len(sends), "calls": len(calls),
         "move_failed": move_failed, "rpc_failed": rpc_failed,
+        "other_calls": {m: sum(o[1][1] == m for o in others)
+                        for m in sorted({o[1][1] for o in others})},
+        "other_failed": other_failed,
+        "other_ms": {"p50": R.nearest_rank(other_ms, 0.5),
+                     "p95": R.nearest_rank(other_ms, 0.95)}
+        if len(others) else None,
+        "hops": hops,
         "numbers": numbers,
         "gen_late_ms": {"p50": R.nearest_rank(late, 0.5),
                         "p95": R.nearest_rank(late, 0.95),

@@ -2,8 +2,9 @@
 Avatar per client. Runs inside the game process (``python -m goworld_tpu
 start`` executes it from the server directory) and reads its sizes and
 the world's kind from ``bench_params.json`` beside it, which ``run.py``
-writes from the cell's configuration file: one space on one chip, or
-one megaspace tiled over the cell's chips (``megaspace``, ``borders``).
+writes from the cell's configuration file: one space on one chip, one
+megaspace tiled over the cell's chips (``megaspace``, ``borders``), or
+``n_spaces`` spaces on one chip, each a shard of the one vmapped tick.
 
 Besides the world it gives the harness what only the process that owns
 the chip can read (device memory as an RPC reply; the device's rows and
@@ -25,6 +26,8 @@ with open("bench_params.json") as _f:
 EXTENT_X, EXTENT_Z = float(P["extent_x"]), float(P["extent_z"])
 RADIUS = float(P["aoi_radius"])
 MEGA = bool(P.get("megaspace"))
+N_SPACES = 1 if MEGA else int(P.get("n_spaces", 1))
+SPACES: list = []                     # the arenas, in shard order
 BORDERS = P.get("borders") or {}      # a tiled world's inner borders
 # parking: the i-th login enters on a grid wider than an AOI box, so a
 # login wave never puts hundreds of avatars into one neighbourhood. In a
@@ -38,6 +41,8 @@ PARKS = [(x0, z0, max(int((x1 - x0) // PARK) - 1, 1),
           max(int((z1 - z0) // PARK) - 1, 1))
          for x0, x1 in zip(_XS, _XS[1:]) for z0, z1 in zip(_ZS, _ZS[1:])]
 _logins = [0]
+_writes = [0]
+PLANT = P.get("plant") or ""
 
 
 @gw.register_space("Arena", megaspace=MEGA)
@@ -52,9 +57,16 @@ class Npc(gw.Entity):
 
 @gw.register_entity("Avatar")
 class Avatar(gw.Entity):
+    ATTRS = {"hp": "allclients"}      # set by SetHp_Client only
+    _hop = None                       # the token of an EnterSpace under way
+
     def OnClientConnected(self):
-        arena = next(sp for sp in self.world.spaces.values()
-                     if sp.type_name == "Arena")
+        if N_SPACES > 1:
+            # many spaces: no counter knows where a login belongs. The
+            # client says so itself (EnterSpace_Client, from the plan);
+            # until then its avatar is in no space
+            return
+        arena = SPACES[0]
         i = _logins[0]
         _logins[0] += 1
         x0, z0, row, rows = PARKS[i % len(PARKS)]
@@ -68,6 +80,29 @@ class Avatar(gw.Entity):
 
     def Echo_Client(self, token):
         self.call_client("OnEcho", token)
+
+    def EnterSpace_Client(self, space_index, x, z, token):
+        """Change space (from no space: a login's placement). The reply
+        goes out when the program says the avatar has entered
+        (``OnEnterSpace``), not when the call was read."""
+        self._hop = token
+        if PLANT == "stay" and os.path.exists("plant.on"):
+            self.OnEnterSpace()       # answers, and moves nobody
+            return
+        self.enter_space(SPACES[int(space_index)].id,
+                         (float(x), 0.0, float(z)))
+
+    def OnEnterSpace(self):
+        if self._hop is not None:
+            token, self._hop = self._hop, None
+            self.call_client("OnEntered", token)
+
+    def SetHp_Client(self, v, token):
+        _writes[0] += 1
+        if not (PLANT == "hp" and os.path.exists("plant.on")
+                and _writes[0] % 2):
+            self.attrs["hp"] = v      # (the plant: every second write lost)
+        self.call_client("OnHpSet", token)
 
     def Stats_Client(self):
         import jax
@@ -98,12 +133,17 @@ class Avatar(gw.Entity):
         program's gid; a tile's ghost rows are copies and no rows of
         the world). For one space that is the slot, and the file holds
         what it always held. In a tiled world half of a tile's sample
-        comes from its rows within the radius of a tile border."""
+        comes from its rows within the radius of a tile border. Many
+        spaces are read the same way, space * capacity + slot, with ONE
+        sample of ``sample`` NPC rows over all of them, and every NPC's
+        id beside its row (``npc_eids``, ``npc_rows``: the check holds
+        a mirrored NPC to the space of the client that mirrors it)."""
         st = self.world.state
         pos, alive, nbr = (np.asarray(x) for x in (st.pos, st.alive, st.nbr))
         cap = pos.shape[1]
-        if not MEGA:                  # one space: shard 0 is the world
-            pos, alive, nbr = pos[:1], alive[:1], nbr[:1]
+        if not MEGA:                  # spaces in shard order; one space:
+            pos, alive, nbr = (x[:N_SPACES] for x in (pos, alive, nbr))
+            #                           shard 0 is the world
         pos, alive, nbr = (x.reshape((-1,) + x.shape[2:])
                            for x in (pos, alive, nbr))
         avatars = sorted((e.id, int(e.shard or 0) * cap + int(e.slot))
@@ -131,10 +171,17 @@ class Avatar(gw.Entity):
                                replace=False)
                 picked += [a, b]
         rows = np.concatenate([av_rows] + picked)
+        more = {}
+        if N_SPACES > 1:
+            npcs = sorted((e.id, int(e.shard) * cap + int(e.slot))
+                          for e in self.world.entities.values()
+                          if e.type_name == "Npc" and e.slot is not None)
+            more = {"npc_eids": np.array([i for i, _r in npcs]),
+                    "npc_rows": np.array([r for _i, r in npcs], np.int64)}
         np.savez("rows.npz", pos=pos, alive=alive, rows=rows,
                  nbr=nbr[rows], avatar_rows=av_rows,
                  avatar_eids=np.array([i for i, _r in avatars]),
-                 tick=int(self.world.tick_count))
+                 tick=int(self.world.tick_count), **more)
         self.call_client("OnRows", "rows.npz")
 
 
@@ -192,15 +239,17 @@ def _plant(world, kind: str) -> None:
 
 @gw.on_boot
 def fill(world):
-    arena = world.create_space("Arena")
+    # the arenas take the shards in the order they are made
+    SPACES.extend(world.create_space("Arena") for _ in range(N_SPACES))
     rng = np.random.default_rng(int(P["seed"]))
     # uniform over the world's extent (bit for bit uniform(0, extent)
-    # where the world is square)
-    xz = rng.uniform(0.0, 1.0, (int(P["npcs"]), 2)) \
-        * np.array([EXTENT_X, EXTENT_Z])
-    for x, z in xz:
-        world.create_entity("Npc", space=arena, pos=(x, 0.0, z),
-                            moving=True)
+    # where the world is square); of many spaces each gets as many, a
+    # stretch of the one draw
+    n = int(P["npcs"])
+    xz = rng.uniform(0.0, 1.0, (n, 2)) * np.array([EXTENT_X, EXTENT_Z])
+    for i, (x, z) in enumerate(xz):
+        world.create_entity("Npc", space=SPACES[i * N_SPACES // n],
+                            pos=(x, 0.0, z), moving=True)
     opmon.expose("bench_npcs", int(P["npcs"]))
     # the audit plane samples on the logic thread every so many ticks:
     # run.py opens every window at the same place against that cadence,
@@ -209,8 +258,11 @@ def fill(world):
     # (on a megaspace the plane skips every sample before it walks
     # anything, entity/manager.py _audit_sample: no cadence to meet)
     aud = getattr(world, "audit", None)
+    # (... and of many spaces it samples ONE shard, a few hundred rows
+    # on the logic thread: nothing a window has to be placed against)
     opmon.expose("bench_audit_every",
-                 0 if MEGA else int(getattr(aud, "sample_every", 0) or 0))
+                 0 if MEGA or N_SPACES > 1
+                 else int(getattr(aud, "sample_every", 0) or 0))
     if P.get("plant"):
         _plant(world, P["plant"])
 

@@ -63,12 +63,13 @@ from __future__ import annotations
 
 import json
 import os
+import re
 import sys
 import time
 
 import numpy as np
 from scrapes import delta
-from trace_reduce import DEVICE_PLANE, clip, short_name
+from trace_reduce import DEVICE_PLANE, FIRST_WHOLE, clip, short_name
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 WORK = os.path.join(os.path.dirname(HERE), ".bench_work")
@@ -211,9 +212,25 @@ METADATA_PLANE = "/host:metadata"
 HLO_STAT = "Hlo Proto"
 
 
+_WRAPPED = re.compile(r"^\w+\((.*)\)$")
+
+
+def scope_of(part: str) -> str | None:
+    """The ``gw.`` scope one part of a name-stack path stands for, or
+    ``None``: the part itself, or what a transform wraps — under
+    ``vmap`` (a tick over many spaces) jax prints ``gw.sync`` as
+    ``vmap(gw.sync)``."""
+    while True:
+        if part.startswith(SCOPE_PREFIX):
+            return part
+        m = _WRAPPED.match(part)
+        if m is None:
+            return None
+        part = m.group(1)
+
+
 def has_scope(op_name: str) -> bool:
-    return any(part.startswith(SCOPE_PREFIX)
-               for part in op_name.split("/"))
+    return any(scope_of(part) for part in op_name.split("/"))
 
 
 def hlo_names(hlo_bytes: bytes, hlo_cls) -> dict[str, str]:
@@ -344,8 +361,8 @@ def read_raw(path: str) -> list[dict] | None:
 def scopes_of(tf_op: str) -> tuple[str, ...]:
     """The ``gw.`` parts of an operation's name-stack path."""
     return tuple(dict.fromkeys(
-        part for part in str(tf_op).split("/")
-        if part.startswith(SCOPE_PREFIX)))
+        sc for part in str(tf_op).split("/")
+        if (sc := scope_of(part))))
 
 
 def merged(starts, ends) -> list[tuple[float, float]]:
@@ -366,7 +383,7 @@ def merged(starts, ends) -> list[tuple[float, float]]:
 def frame_window(mods: list) -> tuple[float, float, int, list] | None:
     """The whole-frame window as ``trace_reduce.reduce_planes`` cuts it:
     from the first to the last start of a WHOLE run (at least half the
-    median run) of the tick's program — the module with the most device
+    median run; the first one ``FIRST_WHOLE`` of it) of the tick's program — the module with the most device
     time. Returns (lo, hi, frames, the whole runs) or ``None`` with
     fewer than two such starts."""
     total: dict = {}
@@ -379,6 +396,8 @@ def frame_window(mods: list) -> tuple[float, float, int, list] | None:
     whole = 0.5 * durs[len(durs) // 2]
     runs = sorted((ev for ev in mods if ev[0] == tick and ev[2] >= whole),
                   key=lambda ev: ev[1])
+    if runs and runs[0][2] < FIRST_WHOLE * durs[len(durs) // 2]:
+        del runs[0]             # cut at its beginning: see trace_reduce
     if len(runs) < 2:
         return None
     return runs[0][1], runs[-1][1], len(runs) - 1, runs

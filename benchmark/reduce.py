@@ -86,7 +86,7 @@ def stream_faults(rc_recv, rc_sender, rc_seq, rc_vals, table,
 
 
 def interest_check(final_xz, radius: float, slack: float, mirrors,
-                   final_vals, tile=None) -> dict[str, int]:
+                   final_vals, tile=None, space=None) -> dict[str, int]:
     """The clients' mirrors, once the world has settled, against the
     brute-force reference over true positions.
 
@@ -109,19 +109,34 @@ def interest_check(final_xz, radius: float, slack: float, mirrors,
     in: ``finals_over_border`` then counts the clients whose reference
     neighbourhood holds a client of another tile, which says how much
     of the above was judged across a seam (no fault by itself).
+
+    ``space[c]`` (a world of many spaces, which share their coordinates
+    and nothing else) is the space ``c`` entered last: everything above
+    is then judged within each space — the reference's neighbourhood
+    holds clients of the same space only, and an NPC is held to the
+    space of the first client that mirrors it — and ``space_wrong``
+    counts the mirrored clients of another space and the NPCs that
+    clients of two spaces mirror.
     """
     final_xz = np.asarray(final_xz, np.float64)
-    want = neighbourhoods(final_xz, radius)
+    want = neighbourhoods(final_xz, radius, space)
     out = dict(final_missing=0, interest_extra=0, npc_stray=0,
                npc_cross_missing=0, finals_over_border=0)
+    if space is not None:
+        out["space_wrong"] = 0
     if tile is not None:
         out["finals_over_border"] = sum(
             any(tile[d] != tile[c] for d in want[c])
             for c in range(len(want)))
     npc_at: dict = {}
+    npc_space: dict = {}
     for c, mir in enumerate(mirrors):
         got = set()
         for kind, ident, vals in mir.values():
+            if space is not None and (
+                    space[ident] if kind == "client"
+                    else npc_space.setdefault(ident, space[c])) != space[c]:
+                out["space_wrong"] += 1
             if kind == "client":
                 got.add(ident)
                 if ident in want[c] and not np.array_equal(
@@ -136,6 +151,9 @@ def interest_check(final_xz, radius: float, slack: float, mirrors,
         ids = list(npc_at)
         col = {n: i for i, n in enumerate(ids)}
         d = chebyshev(final_xz, np.array([npc_at[n] for n in ids]))
+        if space is not None:          # another space's NPC is nowhere near
+            d[np.asarray(space)[:, None]
+              != np.array([npc_space[n] for n in ids])[None, :]] = np.inf
         for c, mir in enumerate(mirrors):
             held = np.zeros(len(ids), bool)
             for kind, ident, _vals in mir.values():
@@ -148,7 +166,8 @@ def interest_check(final_xz, radius: float, slack: float, mirrors,
 
 
 def rows_check(pos, alive, rows, nbr, radius: float, avatar_rows,
-               final_vals, near_border=None) -> dict[str, int]:
+               final_vals, near_border=None, space=None,
+               capacity=None) -> dict[str, int]:
     """What the game read back from the device once the world had
     settled (program-prepared data: positions of every row, the
     neighbour lists of the sampled ``rows``) against the reference.
@@ -166,17 +185,27 @@ def rows_check(pos, alive, rows, nbr, radius: float, avatar_rows,
     ``near_border[i]`` says that sampled row ``i`` lies within the
     radius of a tile border: ``rows_wrong_near_border`` counts the
     wrong ones among those (part of ``rows_wrong``, not a number of its
-    own).
+    own). A world of many spaces comes the same way (space * capacity +
+    slot) with ``space[row]`` for every row: the brute force then holds
+    a neighbourhood to the rows of the same space. There a list holds
+    SLOTS of its own space (every space is a tick of its own, vmapped;
+    the sentinel is the ``capacity`` of one space), which are turned
+    into row numbers here.
     """
     pos = np.asarray(pos, np.float32)
     live = np.nonzero(np.asarray(alive, bool))[0]
     at = np.full(len(pos), -1, np.int64)
     at[live] = np.arange(len(live))
     rows = np.asarray(rows, np.int64)
-    want = neighbours_of(pos[live][:, [0, 2]], at[rows], radius)
+    want = neighbours_of(pos[live][:, [0, 2]], at[rows], radius,
+                         None if space is None else np.asarray(space)[live])
     wrong = wrong_near = 0
     for i, lst in enumerate(np.asarray(nbr, np.int64)):
-        got = {int(j) for j in lst if 0 <= j < len(pos)}
+        if capacity is None:
+            got = {int(j) for j in lst if 0 <= j < len(pos)}
+        else:
+            base = rows[i] // capacity * capacity
+            got = {int(base + j) for j in lst if 0 <= j < capacity}
         if at[rows[i]] < 0 or got != {int(live[j]) for j in want[i]}:
             wrong += 1
             wrong_near += int(near_border is not None and near_border[i])
@@ -193,6 +222,68 @@ def entities_lost(alive, live: int) -> int:
     row one too many (ghost rows are no rows of the world and are never
     read back)."""
     return abs(int(np.count_nonzero(alive)) - int(live))
+
+
+def space_wrong(avatar_space, space_now, mirrored, npc_space) -> int:
+    """A world of many spaces, once it has settled: clients whose
+    avatar's row lies in another space than the one they entered last
+    (``avatar_space[c]``, from the device's rows, against
+    ``space_now[c]``), and mirrored NPCs that live in another space
+    than the client that mirrors them (``mirrored[c]``: the ids ``c``
+    holds; ``npc_space``: id -> space, for every NPC of the world)."""
+    wrong = int((np.asarray(avatar_space) != np.asarray(space_now)).sum())
+    for c, ids in enumerate(mirrored):
+        wrong += sum(bool(npc_space.get(i, space_now[c]) != space_now[c])
+                     for i in ids)
+    return int(wrong)
+
+
+def hop_check(hops, observer, made_at, scheduled: int,
+              window_end: float) -> dict[str, int]:
+    """Changes of space inside the window. ``hops`` are (client,
+    instant sent, instant answered or NaN); ``made_at[(r, c)]`` the
+    (instant, created?) events of ``c``'s avatar at the mirror of its
+    observer ``r``, in arrival order; ``scheduled`` how many the
+    window's schedule held.
+
+    * ``hop_unanswered``: hops never answered, and answered hops that
+      the partner's mirror was not told of, between the hop and the
+      client's next one, as a leaving and then an entering (both
+      members of a pair hop together: the partner sees the avatar go
+      where it leaves and come where it arrives). A second leaving and
+      entering may follow the first — the gate forwards position syncs
+      in batches and calls at once, as upstream's does, so a position
+      of the old place sent just before the call can be applied in the
+      new one and carry the avatar out of its partner's sight for a
+      frame — but never two leavings or two enterings in a row (an
+      entity doubled or lost in the mirror), and the last is an
+      entering;
+    * ``hops_untested``: 1 where the schedule held hops and fewer than
+      three quarters of them were answered before the window's end (a
+      run that has not tested what its cell is for), else 0."""
+    by: dict[int, list] = {}
+    for c, sent, seen in hops:
+        by.setdefault(int(c), []).append((sent, seen))
+    bad = done = 0
+    first_bad: list = []
+    for c, mine in by.items():
+        mine.sort()
+        ev = made_at.get((int(observer[c]), c), [])
+        for i, (sent, seen) in enumerate(mine):
+            if not seen == seen:                   # NaN: never answered
+                bad += 1
+                continue
+            done += int(seen <= window_end)
+            until = mine[i + 1][0] if i + 1 < len(mine) else np.inf
+            mid = [made for t, made in ev if sent <= t < until]
+            if not mid or len(mid) % 2 or any(
+                    made != bool(i % 2) for i, made in enumerate(mid)):
+                bad += 1
+                first_bad.append(
+                    [int(c), sent, seen, [[t, made] for t, made in ev]])
+    return {"hop_unanswered": bad, "hops": len(hops), "hops_done": done,
+            "first_bad": first_bad[:4],
+            "hops_untested": int(bool(scheduled) and 4 * done < 3 * scheduled)}
 
 
 def excursions(t, dist, radius: float, band: float) -> list[tuple]:

@@ -22,12 +22,14 @@ on several chips is rehearsed on as many host devices
 only).
 
 A cell's world is read from its configuration (``world.py``): one space
-on one chip, or one megaspace tiled over the cell's chips. Nothing here
-branches on a cell's or a configuration's name.
+on one chip, one megaspace tiled over the cell's chips, or many spaces
+on one chip (``game.n_spaces``). Nothing here branches on a cell's or a
+configuration's name.
 """
 from __future__ import annotations
 
 import argparse
+import collections
 import importlib.util
 import json
 import math
@@ -75,14 +77,6 @@ GRACE_FRAMES = 2          # a send not seen this long after the close (and
 GRACE_MIN_S = 1.0         # at least this long) is `failed`
 WINDOW_LEAD_S = 0.5       # from choosing the window's start to the start
 SAMPLE_AT = 0.125         # where in the window the first audit sample falls
-# what --rehearsal changes (a CPU run at a tiny size): sizes of ONE tile;
-# a tiled world keeps its tiling and takes them once a tile
-REHEARSAL = {"game": {"capacity": 2048, "extent_x": 1400.0,
-                      "extent_z": 1400.0, "tick_hz": 4},
-             "world": {"live": 1500}, "clients": 16, "twin_sites": 2,
-             # a lap in 10 s, not 50: a window of 8 s holds crossings of
-             # the AOI edge (and, in a tiled world, of a tile border)
-             "orbit_step_rad": 0.125}
 # the program's own words when a tile's exchange buffers overflow
 # (entity/manager.py: it has no counter for them): lines of the game's
 # log since the window opened
@@ -97,7 +91,9 @@ LIMITS = {"pos_wrong": 0, "order_back": 0, "final_missing": 0,
           "rows_wrong": 0, "avatar_row_off": 0, "cross_missed": 0,
           "rpc_wrong": 0, "mirror_errors": 0, "never_seen": 0,
           "shed": 0, "events_undecoded": 0, "world_size_off": 0,
-          "entities_lost": 0, "mesh_dropped": 0, "border_untested": 0}
+          "entities_lost": 0, "mesh_dropped": 0, "border_untested": 0,
+          "space_wrong": 0, "attr_wrong": 0, "hop_unanswered": 0,
+          "hops_untested": 0}
 
 
 def say(msg: str) -> None:
@@ -236,6 +232,15 @@ class Cluster:
             f.seek(lo)
             data = f.read()
         return len(re.findall(rb"Compiling \S+", data)), lo + len(data)
+
+    def compiled(self, lo: int, hi: int) -> dict[str, int]:
+        """What the game's log says was compiled between two of its
+        sizes: {program name: times}."""
+        with open(self.game_log, "rb") as f:
+            f.seek(lo)
+            names = re.findall(rb"Compiling (\S+)", f.read(hi - lo))
+        return {n.decode(errors="replace"): k
+                for n, k in collections.Counter(names).items()}
 
     def game_came_up(self, timeout: float) -> bool:
         """After a `start` that gave up on the game: whether the game's
@@ -463,29 +468,32 @@ def find_xplane(logdir: str) -> str | None:
 
 
 def effective(cfg: dict, mix: dict, rehearsal: bool) -> tuple[dict, dict]:
+    """The configuration and the mix as they are served. Each states
+    under ``rehearsal`` the sizes of a CPU run (a lap of the orbit in
+    10 s, not 50, so that a window of 8 s holds crossings of the AOI
+    edge and, in a tiled world, of a tile border): ``--rehearsal`` takes
+    them, a measurement never does."""
     cfg, mix = json.loads(json.dumps(cfg)), json.loads(json.dumps(mix))
+    small_cfg, small_mix = cfg.pop("rehearsal", {}), mix.pop("rehearsal", {})
     if rehearsal:
-        sh = Shape(cfg)
-        cfg["game"].update(REHEARSAL["game"])
-        cfg["game"]["extent_x"] *= sh.tx
-        cfg["game"]["extent_z"] *= sh.tz
-        cfg["world"]["live"] = REHEARSAL["world"]["live"] * sh.tiles
-        mix["clients"] = REHEARSAL["clients"] * sh.tiles
-        if "orbit_step_rad" in mix:
-            mix["orbit_step_rad"] = REHEARSAL["orbit_step_rad"]
-        twins = int(mix.get("twin_sites", 0))
-        mix["twin_sites"] = min(
-            twins, REHEARSAL["twin_sites"] * sh.tiles,
-            mix["clients"] // int(mix["group_size"]) // 2)
-        if twins and "twin_border_sites" in mix:    # the mix's own share
-            mix["twin_border_sites"] = math.ceil(
-                mix["twin_sites"] * int(mix["twin_border_sites"]) / twins)
+        for k, v in small_cfg.items():
+            cfg[k].update(v)
+        mix.update(small_mix)
     return cfg, mix
 
 
 def run(a) -> int:
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
+    if a.cell_file:         # a cell not admitted yet, by hand and in tests
+        with open(os.path.join(ROOT, a.cell_file)) as f:
+            more = json.load(f)
+        bench["configs"].append(more["config"])
+        bench["workloads"].append(more["workload"])
+        bench["per_layer"] += more.get("per_layer", [])
+        for m in bench["per_layer"]:
+            if m["name"] in more.get("per_layer_workloads", ()):
+                m["workloads"] = m["workloads"] + [more["workload"]["name"]]
     cell = next((w for w in bench["workloads"]
                  if w["name"] == a.workload), None)
     if cell is None:
@@ -542,6 +550,7 @@ def run(a) -> int:
     with open(os.path.join(sd, "bench_params.json"), "w") as f:
         json.dump({"npcs": npcs, "seed": a.seed,
                    "megaspace": shape.mega, "borders": shape.borders,
+                   "n_spaces": shape.spaces,
                    "extent_x": shape.extent_x, "extent_z": shape.extent_z,
                    "aoi_radius": cfg["game"]["aoi_radius"],
                    "plant": a.plant}, f)
@@ -551,9 +560,11 @@ def run(a) -> int:
     say(f"[run] {a.workload}: config {cell['config']}, mix "
         f"{cell['traffic']}, {clients} clients, {npcs} NPCs, "
         + (f"a megaspace of {shape.tx}x{shape.tz} tiles, " if shape.mega
+           else f"{shape.spaces} spaces, " if shape.spaces > 1
            else "one space, ")
         + f"capacity {cfg['game']['capacity']}"
-        + (" a tile" if shape.mega else "")
+        + (" a tile" if shape.mega else " a space" if shape.spaces > 1
+           else "")
         + f", {hz:g} Hz, seed {a.seed}, window "
         f"{a.seconds:g} s, trace {int(traced)}"
         + (", REHEARSAL" if a.rehearsal else "")
@@ -616,25 +627,43 @@ def run(a) -> int:
             # whole frames. No longer: the tile's trace is ~60 MB and
             # ~25 s of stop_trace for every second captured.
             span = min(6.0, max(2.0, 3.3 / hz), a.seconds - 4.0)
-            time.sleep(max(0.0, t0 + 0.5 * (a.seconds - span) - 1.0
-                           - time.monotonic()))
-            # the frames the capture holds, by the game's own histogram:
-            # host_ms is read over these, not over the whole window
+            # in mid-window; in a world of many spaces over the window's
+            # last frames, ending at its close. stop_trace holds the
+            # game's interpreter for seconds, and the one such world
+            # there is, under its cell's load, never hears from its
+            # clients again after standing still that long in mid-window
+            # (four traced runs of four, my chip runs, PR 29; PERF.md
+            # section 7, fault 11): there the stall has to fall where
+            # the traffic has ended
+            last_frames = shape.spaces > 1
+            time.sleep(max(0.0, t0 - time.monotonic() + (
+                a.seconds - span - 0.25 if last_frames
+                else 0.5 * (a.seconds - span) - 1.0)))
+            # the series the readers take from the scrapes end here,
+            # where the capture begins
             span_open = {
                 "game": parse_prom(http(cl.ports["game_http_port"],
                                         "metrics")),
                 "gate": parse_prom(http(cl.ports["gate_http_port"],
                                         "metrics"))}
+            if last_frames:
+                time.sleep(max(0.0, t0 + a.seconds - span
+                               - time.monotonic()))
             prof = capture(cl, span, os.path.join(sd, "profile"))
             say(f"[run] profiler capture: {prof}")
-            time.sleep(span)
-            span_close = {"game": parse_prom(http(
-                cl.ports["game_http_port"], "metrics"))}
+            if not last_frames:
+                # the frames the capture holds, by the game's own
+                # histogram: host_ms is read over these
+                time.sleep(span)
+                span_close = {"game": parse_prom(http(
+                    cl.ports["game_http_port"], "metrics"))}
         time.sleep(max(0.0, t0 + a.seconds - time.monotonic()))
         edge1 = cl.scrape()
         tick1, _ = world_tick(cl)
         samples = tick1 // every - tick0 // every if every else 0
         if traced:
+            if span_close is None:      # the capture ended at the close
+                span_close = {"game": edge1["game"]}
             # the capture has to be on disk before the game is stopped;
             # stop_trace holds /profile's lock meanwhile, so watch the
             # file, not the endpoint
@@ -654,7 +683,8 @@ def run(a) -> int:
             - cl.compiles(edge1["log_size"])[0]
         say(f"[run] window closed: frames {edge0['frames']} -> "
             f"{edge1['frames']} in {edge1['t'] - edge0['t']:.3f} s; "
-            f"compiles logged inside the window: {in_window}; audit "
+            f"compiles logged inside the window: {in_window} "
+            f"{cl.compiled(edge0['log_size'], edge1['log_size'])}; audit "
             f"samples inside the window (world ticks {tick0}..{tick1}, "
             f"one every {every}): {samples}; ladder "
             f"at the edges {edge0['ladder']} / {edge1['ladder']}; "
@@ -798,7 +828,7 @@ def report(a, bench, cell, cfg, mix, cl, res, shape) -> int:
               for k in LIMITS}
     correct = all(c["value"] <= c["limit"] for c in checks.values())
     say(f"[run] end to end: {json.dumps(end_to_end)}")
-    say(f"[run] clients: {json.dumps({k: bots[k] for k in ('sends', 'calls', 'move_failed', 'rpc_failed', 'gen_late_ms', 'receipts', 'sync_records', 'npcs_mirrored', 'settled_s_after_close', 'crossings', 'rows_read', 'over_border', 'mirror_errors_first')})}")
+    say(f"[run] clients: {json.dumps({k: bots[k] for k in ('sends', 'calls', 'other_calls', 'move_failed', 'rpc_failed', 'other_failed', 'other_ms', 'hops', 'gen_late_ms', 'receipts', 'sync_records', 'npcs_mirrored', 'settled_s_after_close', 'crossings', 'rows_read', 'over_border', 'mirror_errors_first')})}")
     line = {"correct": correct, "attempted": bots["attempted"],
             "failed": bots["failed"], "metrics": metrics_out,
             "device": device}
@@ -830,12 +860,16 @@ def main(argv=None) -> int:
     ap.add_argument("--tick-hz", type=float, dest="tick_hz",
                     help="serve at another rate than the configuration's "
                          "(the sweep that found it; never the driver)")
+    ap.add_argument("--cell-file", dest="cell_file", default="",
+                    help="a cell that is not in BENCHMARK.json yet: its "
+                         "configuration, workload and per-layer entries "
+                         "(benchmark/cells/<cell>.json; by hand, in tests)")
     ap.add_argument("--control-faults", dest="control_faults", default="",
                     help="the control: a [deployment] faults spec that "
                          "breaks a guarantee (docs/ROBUSTNESS.md)")
     ap.add_argument("--plant", default="",
                     choices=("", "alter", "half", "freeze", "radius",
-                             "caps", "lose"),
+                             "caps", "lose", "stay", "hp"),
                     help="tests: break the timed path underneath")
     return run(ap.parse_args(argv))
 

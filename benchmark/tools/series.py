@@ -6,22 +6,31 @@ and its last line, with the seed and what was varied, to
 ``chiprun_out/<label>.jsonl``; a compact line per run is printed.
 
     python benchmark/tools/series.py --label sets_tile --workload tile.roam \\
-        --seeds 11,12,13 --seconds 40 [--trace 0] [--tick-hz 4,5,6] \\
-        [--control-faults SPEC]
+        --seeds 11,12,13 --seconds 40 [--trace 0,1] [--tick-hz 4,5,6] \\
+        [--control-faults SPEC] [--cell-file FILE]
 
-With ``--tick-hz`` a list, run ``i`` takes rate ``i`` (the sweep).
+With ``--tick-hz`` or ``--trace`` a list, run ``i`` takes entry ``i``
+(the sweep).
 """
 from __future__ import annotations
 
 import argparse
 import json
 import os
+import shutil
 import subprocess
 import sys
 import time
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(os.path.dirname(HERE))
+
+
+def nth(listed: str, i: int) -> str:
+    """Entry ``i`` (cyclically) of a comma-separated option, "" where
+    the option was not given."""
+    values = [v for v in listed.split(",") if v]
+    return values[i % len(values)] if values else ""
 
 
 def main() -> int:
@@ -33,10 +42,10 @@ def main() -> int:
     ap.add_argument("--trace", default="0")
     ap.add_argument("--tick-hz", dest="tick_hz", default="")
     ap.add_argument("--control-faults", dest="control_faults", default="")
+    ap.add_argument("--cell-file", dest="cell_file", default="")
     ap.add_argument("--rehearsal", action="store_true")
     a = ap.parse_args()
     seeds = [int(s) for s in a.seeds.split(",")]
-    rates = [r for r in a.tick_hz.split(",") if r]
     out_dir = os.path.join(ROOT, "chiprun_out", a.label)
     os.makedirs(out_dir, exist_ok=True)
     worst = 0
@@ -46,12 +55,13 @@ def main() -> int:
             cmd = [sys.executable, os.path.join(ROOT, "benchmark",
                                                 "run.py"),
                    "--workload", a.workload, "--seed", str(seed),
-                   "--seconds", a.seconds, "--trace", a.trace]
-            rate = rates[i % len(rates)] if rates else ""
-            if rate:
-                cmd += ["--tick-hz", rate]
-            if a.control_faults:
-                cmd += ["--control-faults", a.control_faults]
+                   "--seconds", a.seconds, "--trace", nth(a.trace, i)]
+            rate = nth(a.tick_hz, i)
+            for flag, value in (("--tick-hz", rate),
+                                ("--control-faults", a.control_faults),
+                                ("--cell-file", a.cell_file)):
+                if value:
+                    cmd += [flag, value]
             if a.rehearsal:
                 cmd += ["--rehearsal"]
             t0 = time.monotonic()
@@ -60,16 +70,26 @@ def main() -> int:
             wall = time.monotonic() - t0
             with open(os.path.join(out_dir, f"{i}.log"), "w") as f:
                 f.write(r.stdout + "\n---- stderr ----\n" + r.stderr)
-            try:        # the game's own account of its frames
-                with open(os.path.join(ROOT, ".bench_work", a.workload,
-                                       "run", "game1.log"),
-                          errors="replace") as f, \
-                        open(os.path.join(out_dir, f"{i}.game1.log"),
-                             "w") as g:
-                    g.writelines(ln for ln in f if "Compiling" not in ln
-                                 and "Finished" not in ln)
-            except OSError:
-                pass
+            # every served process's own account (the game's of its
+            # frames), without the compile lines
+            rd = os.path.join(ROOT, ".bench_work", a.workload, "run")
+            for name in (sorted(os.listdir(rd)) if os.path.isdir(rd)
+                         else ()):
+                if name.endswith(".log"):
+                    with open(os.path.join(rd, name),
+                              errors="replace") as f, \
+                            open(os.path.join(out_dir, f"{i}.{name}"),
+                                 "w") as g:
+                        g.writelines([ln[:200].rstrip("\n") + "\n"
+                                      for ln in f
+                                      if "Finished" not in ln][-4000:])
+            for name in ("phases.json", "host_phases.json"):
+                try:    # a traced run: what the capture held, by name
+                    shutil.copy(os.path.join(ROOT, ".bench_work", a.workload,
+                                             name),
+                                os.path.join(out_dir, f"{i}.{name}"))
+                except OSError:
+                    pass
             lines = r.stdout.strip().splitlines()
             try:
                 last = json.loads(lines[-1])

@@ -45,6 +45,14 @@ ASYNC_LINE = "Async XLA Ops"
 IN_FLIGHT = " (async, in flight)"
 
 
+# the first run of the tick's program marks a frame's start only if it
+# is this much of the median run: one cut at its beginning to two
+# thirds of itself was read as whole under the rule of one half (busy
+# 340.8 ms a frame for 384.1, my chip run, PR 29). A whole first run
+# that is dropped for being a little short costs a frame, and bends none
+FIRST_WHOLE = 0.95
+
+
 def union(intervals: list[tuple[float, float]]) -> list[tuple[float, float]]:
     """Merge overlapping [start, end) intervals."""
     out: list[list[float]] = []
@@ -77,7 +85,8 @@ def reduce_planes(planes: list[dict], frame_s: float) -> dict:
     Modules`` line with the most device time — so a capture that starts
     or ends inside a tick, or idles while the profiler starts and
     stops, does not bend busy per frame. A run of that program cut short
-    by the capture's edge (under half the median run) marks no start. A
+    by the capture's edge (under half the median run; the first one:
+    under ``FIRST_WHOLE`` of it) marks no start. A
     capture with fewer than two such starts is taken from its first to
     its last event."""
     out: dict = {"planes": [p["name"] for p in planes], "lines": {},
@@ -116,8 +125,15 @@ def reduce_planes(planes: list[dict], frame_s: float) -> dict:
             durs = sorted(d for name, _s, d in mods["events"]
                           if name == tick)
             whole = 0.5 * durs[len(durs) // 2]
-            starts = sorted(s for name, s, d in mods["events"]
-                            if name == tick and d >= whole)
+            runs = sorted((s, d) for name, s, d in mods["events"]
+                          if name == tick and d >= whole)
+            # ... and the FIRST run has to be all there (FIRST_WHOLE of
+            # the median): cut at its beginning it starts where the
+            # capture does, not where the frame did, and the frame it
+            # would mark lacks the operations that ran before
+            if runs and runs[0][1] < FIRST_WHOLE * durs[len(durs) // 2]:
+                del runs[0]
+            starts = [s for s, _d in runs]
             if len(starts) >= 2:
                 lo, hi, n_frames = starts[0], starts[-1], len(starts) - 1
         merged = union([(s, s + d) for _n, s, d in line["events"]])

@@ -19,7 +19,12 @@ chip's 240 FLOP per byte ridge. In a world tiled over several chips the
 bytes are ONE tile's (its share of the live rows and of the clients)
 against ONE chip's peak, as the busy time they are held against is one
 chip's (the busiest: benchmark/README.md); ghost rows and what the
-exchange moves are the mesh's price, not necessary work.
+exchange moves are the mesh's price, not necessary work. Many spaces on
+one chip are ALL that chip's work: every space's live rows and lists,
+and the records by the kind of space a client stands in (the mix says
+how many clients each kind holds, ``clients_by_kind``; the
+configuration what a client of that kind holds in its list,
+``world.expected_neighbours_by_kind``).
 """
 from __future__ import annotations
 
@@ -36,12 +41,16 @@ SYNC_RECORD_BYTES = 24
 
 
 def necessary_bytes(live: int, clients: int, group_size: int,
-                    expected_neighbours: float, k: int = K) -> int:
+                    expected_neighbours: float, k: int = K,
+                    records: float | None = None) -> int:
+    """``records``: the sync records a tick emits, where the caller has
+    counted them itself (by the kind of space); absent, every client
+    mirrors its expected neighbours and its group's other members."""
     state = 2 * ROW_BYTES * live
     lists = 2 * 4 * k * live
-    records = SYNC_RECORD_BYTES * clients * (
-        expected_neighbours + group_size - 1)
-    return int(state + lists + records)
+    if records is None:
+        records = clients * (expected_neighbours + group_size - 1)
+    return int(state + lists + SYNC_RECORD_BYTES * records)
 
 
 def peaks(device_kind: str) -> dict:
@@ -58,8 +67,12 @@ def least_seconds(cfg: dict, mix: dict, device_kind: str) -> float:
     """The least time a chip could take for one tick of its tile: the
     tile's necessary bytes at the HBM peak."""
     tiles = tiles_of(cfg)
+    by_kind = cfg["world"].get("expected_neighbours_by_kind")
     b = necessary_bytes(int(cfg["world"]["live"]) // tiles,
                         int(mix["clients"]) // tiles,
                         int(mix["group_size"]),
-                        float(cfg["world"]["expected_neighbours"]))
+                        float(cfg["world"]["expected_neighbours"]),
+                        records=None if not by_kind else sum(
+                            float(by_kind[kind]) * int(n) for kind, n
+                            in mix["clients_by_kind"].items()))
     return b / float(peaks(device_kind)["hbm_bytes_per_s"])

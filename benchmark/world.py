@@ -1,5 +1,6 @@
 """The shape of a cell's world, read from its configuration file: one
-space on one chip, or one megaspace tiled over the cell's chips.
+space on one chip, one megaspace tiled over the cell's chips, or many
+spaces on one chip.
 
 The world's kind is a key of the configuration: ``game.megaspace``
 (with ``mesh_devices`` and ``mega_shape``, the program's own ini keys).
@@ -9,8 +10,16 @@ is ``tx x tz`` tiles of ``capacity`` rows each, ``extent_x`` and
 (d // tz, d % tz)`` (``parallel/megaspace.py``; the benchmark's own
 copy of that rule, so that the reference and the check need nothing of
 the program). A row of the world has ONE global number, ``tile *
-capacity + slot``: the program's own gid. Pure arithmetic: no numpy
-needed by its callers in ``run.py``, no jax anywhere.
+capacity + slot``: the program's own gid.
+
+The third kind is ``game.n_spaces`` > 1 (the program's own ini key, no
+megaspace): that many spaces of ``capacity`` rows each on ONE chip,
+every one a shard of the one vmapped tick, ``extent_x``/``extent_z``
+being each SPACE's. All spaces share their coordinates and nothing
+else: a row's number is ``space * capacity + slot`` (the shard is the
+space, in the order the spaces are made), and a neighbourhood holds
+only rows of the same space. Pure arithmetic: no numpy needed by its
+callers in ``run.py``, no jax anywhere.
 """
 from __future__ import annotations
 
@@ -46,6 +55,9 @@ class Shape:
                 raise ValueError(f"mega_shape {game.get('mega_shape')!r} "
                                  f"does not tile {n} devices")
         self.tiles = self.tx * self.tz
+        # many spaces on one chip (never with a megaspace, which claims
+        # every shard)
+        self.spaces = 1 if self.mega else max(int(game.get("n_spaces", 1)), 1)
         self.tile_w = self.extent_x / self.tx
         self.tile_d = self.extent_z / self.tz
         # the inner borders: where a row changes its tile
@@ -58,6 +70,11 @@ class Shape:
         ix = _clip(x // self.tile_w, self.tx)
         iz = _clip(z // self.tile_d, self.tz)
         return ix * self.tz + iz
+
+    def space_of(self, row):
+        """The space of a global row number (scalars or numpy arrays);
+        0 wherever the world is one space or one megaspace."""
+        return row // self.capacity if self.spaces > 1 else row * 0
 
     def border_distance(self, x, z):
         """Chebyshev-wise nearest inner border: the smaller of the
