@@ -177,6 +177,10 @@ class GameServer:
         self._loop: asyncio.AbstractEventLoop | None = None
         self._net_thread: threading.Thread | None = None
         self._stop = threading.Event()
+        # set by the net thread when it has queued a packet (and by
+        # stop()): the serve loop waits on it through the frame's
+        # remainder, so a call is served when it arrives
+        self._wake = threading.Event()
         self.deployment_ready = False
         self.ready_event = threading.Event()
         # dispatcher ids that acked our SET_GAME_ID (handshake barrier)
@@ -257,6 +261,15 @@ class GameServer:
         self._m_event_records = metrics.counter(
             "client_event_records_total",
             help="client event records flushed downstream")
+        # where the serve loop handled a packet: in the frame's pump
+        # ahead of the tick, or between ticks as it arrived
+        self._m_pumped = {
+            where: metrics.counter(
+                "game_pump_packets_total",
+                help="packets the serve loop handled, by where",
+                where=where)
+            for where in ("frame", "between")
+        }
 
         # incident flight recorder + live workload signature (ISSUE 11,
         # utils/flightrec.py): one correlated frame per tick; an SLO
@@ -441,11 +454,20 @@ class GameServer:
         from goworld_tpu.net.loops import drain_and_close
 
         self._stop.set()
+        self._wake.set()        # a serve loop waiting for its next frame
         drain_and_close(self._loop, self._net_thread,
                         pre_stop=self.cluster.stop)
 
     def serve_forever(self) -> None:
-        """The logic loop: drain packets, tick the world, repeat."""
+        """The logic loop. A frame is: pump what is queued, tick the
+        world (device step, decode, fan-out), then wait for the next
+        frame's instant ON the packet queue. A packet that arrives
+        during that wait is handled at once, by the same ``pump`` on
+        this same thread against the host state the finished tick left,
+        and the client events its handler staged go on the wire then
+        (``_flush_events_out``); position syncs leave once a tick, as
+        before. Only while the thread is inside ``tick()`` does a call
+        wait."""
         if self.gc_freeze_on_boot:
             # Move everything alive at boot (the spawned entity
             # population, attr trees, numpy mirrors, handler tables)
@@ -477,18 +499,18 @@ class GameServer:
             t_pump = tl.begin_tick(self.world.tick_count)
             self._m_queue_depth.set(self._packet_q.qsize())
             # residency accounting (utils/residency.py): the pump below
-            # is useful host work between device dispatches, the pacing
-            # sleep at the bottom is idle by design — declare both so
-            # neither reads as a bubble
+            # is useful host work between device dispatches, the wait
+            # for the next frame at the bottom is idle by design —
+            # declare both so neither reads as a bubble
             rt = getattr(self.world, "residency", None)
             with tl.span("drain_inputs") as sp_pump:
                 # 1.5 frames of handler work per tick keeps the loop
                 # observing (and the p99 near 2x the interval) under a
                 # flood; the surplus waits in the class queues
-                self.pump(
+                self._m_pumped["frame"].inc(self.pump(
                     budget=1.5 * self.tick_interval
                     if self.overload_enabled else None
-                )
+                ))
             if rt is not None:
                 rt.add_host(sp_pump.t1 - t_pump)
             self.tick()
@@ -508,13 +530,31 @@ class GameServer:
             if self.overload_enabled:
                 with tl.lone_span("overload_observe"):
                     self._observe_overload(dur, backlog)
-            if delay > 0:
-                with tl.lone_span("pacing_sleep"):
-                    time.sleep(delay)
-                if rt is not None:
-                    rt.add_idle(delay)
-            else:
+            if delay <= 0:
                 next_tick = time.monotonic()  # fell behind; don't spiral
+            # the frame's remainder: wait on the queue, not on the clock
+            while (left := next_tick - time.monotonic()) > 0:
+                with tl.lone_span("pacing_sleep") as sp_wait:
+                    self._wake.wait(left)
+                if rt is not None:
+                    rt.add_idle(sp_wait.t1 - sp_wait.t0)
+                if self._stop.is_set():
+                    break
+                self._wake.clear()
+                if not self._packet_q.qsize():
+                    continue
+                with tl.lone_span("drain_inputs") as sp_burst:
+                    # a budget already spent still serves one packet
+                    self._m_pumped["between"].inc(self.pump(
+                        budget=next_tick - time.monotonic()))
+                    # no position sync is staged between ticks, so an
+                    # event sent now overtakes none; under the DEGRADED
+                    # coalescing hold some are, and the events stay
+                    # held with them
+                    if not self._sync_out:
+                        self._flush_events_out()
+                if rt is not None:
+                    rt.add_host(sp_burst.t1 - sp_burst.t0)
 
     def _observe_overload(self, dur: float | None,
                           backlog: float) -> None:
@@ -1401,7 +1441,9 @@ class GameServer:
             # ingress, before any logic-thread work; every drop counted
             overload.shed_counter(cls, "game_ingress").inc()
             return
-        if not self._packet_q.offer(cls, (didx, msgtype, pkt)):
+        if self._packet_q.offer(cls, (didx, msgtype, pkt)):
+            self._wake.set()
+        else:
             # class queue full (offer counted the shed); the old
             # aggregate drop counter keeps its series alive
             self._m_pkt_drop.inc()
