@@ -1389,7 +1389,7 @@ def measure_residency(n: int) -> dict:
     census on the SpaceState carry, and the scan-marginal -> serve-loop
     gap as ONE ratio — measured on a REAL instrumented World ticking a
     paced serve-like loop (utils/residency.py marks riding
-    World._tick_phases; zero added device syncs).
+    World.tick_dispatch and tick_land; zero added device syncs).
 
     The serve_gap reference is measured HERE: a device-only
     back-to-back ``_step`` marginal on the same compiled executable and

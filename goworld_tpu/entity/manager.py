@@ -18,8 +18,12 @@ one tick later (``_release_next``).
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
+import queue
+import threading
 import time
+import weakref
 from collections import defaultdict
 from typing import Any, Callable
 
@@ -158,6 +162,60 @@ def _start_host_copy(tree) -> None:
         return
     for leaf in jax.tree.leaves(tree):
         leaf.copy_to_host_async()
+
+
+# what the waiter of a tick in flight blocks in (the interpreter lock is
+# released inside), and what says that nothing has to be waited for;
+# names of their own so a test can hold the device
+_block_until_landed = jax.block_until_ready
+
+
+def _has_landed(tree) -> bool:
+    return all(leaf.is_ready() for leaf in jax.tree.leaves(tree))
+
+
+class TickInFlight:
+    """A tick between its two halves: ``World.tick_dispatch`` has
+    flushed the staging and dispatched the device step, and
+    ``World.tick_land`` will fetch and decode what ``fetch`` names.
+    In between the caller may run handlers: what they stage belongs to
+    the next flush. ``landed``: nothing is left to wait for (set by the
+    World's waiter where the caller asked for one:
+    ``World.watch_landing``)."""
+
+    __slots__ = ("fetch", "outs", "age_mark", "t_start", "t0", "landed")
+
+    def __init__(self, fetch, outs, age_mark, t_start, t0):
+        self.fetch = fetch
+        self.outs = outs
+        self.age_mark = age_mark
+        self.t_start = t_start
+        self.t0 = t0
+        self.landed = not fetch
+
+
+def _await_landings(flights: queue.SimpleQueue) -> None:
+    """A World's waiter: for each tick in flight handed over, start the
+    outputs' copy to the host, block until the device is done, mark
+    the tick ``landed`` and set the caller's event. Ends with its
+    World (a ``None``)."""
+    while (job := flights.get()) is not None:
+        flight, wake = job
+        fetch = flight.fetch    # tick_land may have taken it (a stop)
+        try:
+            _start_host_copy(fetch)
+            _block_until_landed(fetch)
+        except Exception:
+            # a device error surfaces in tick_land's own fetch, on the
+            # caller's thread, as it always has
+            logger.exception("waiting for a tick's outputs failed")
+        finally:
+            flight.landed = True
+            wake.set()
+        # nothing of a landed tick outlives it here: the device's
+        # output planes are freed when tick_land lets go of them, not
+        # when the next tick is handed over
+        del job, flight, wake, fetch
 
 
 class AdmissionPausedError(RuntimeError):
@@ -493,6 +551,9 @@ class World:
 
         # attr journaling
         self._dirty_attr_entities: dict[str, list[AttrDelta]] = {}
+
+        # the waiter of ticks in flight (watch_landing), made when needed
+        self._landings: queue.SimpleQueue | None = None
 
         # per-tick device read cache
         self._pos_cache: np.ndarray | None = None
@@ -839,6 +900,14 @@ class World:
 
     def _slot_clear(self, shard: int, slot: int) -> None:
         self._slot_owner[shard].pop(slot, None)
+        self._slot_abandon(shard, slot)
+
+    def _slot_abandon(self, shard: int, slot: int) -> None:
+        """The owner has left this row and its despawn (or its move) is
+        staged. The owner mapping stays for the row's leave events; the
+        mirror columns go now, so that a decode which runs before that
+        flush (the serve loop handles packets while the device
+        computes) fans out no sync record to the row or about it."""
         self._mir_eid[shard, slot] = b""
         self._write_client_cols(shard, slot, None)
 
@@ -895,6 +964,7 @@ class World:
                 (e.shard, e.slot, target.shard, e.id)
             )
             self._drop_staged_for(e.shard, e.slot)
+            self._slot_abandon(e.shard, e.slot)
             src.members.discard(e.id)
             e.OnLeaveSpace(src)
             src.OnEntityLeaveSpace(e)
@@ -928,6 +998,7 @@ class World:
         if e.slot is not None:
             self._drop_staged_for(e.shard, e.slot)
             self._staged_despawn.append((e.shard, e.slot))
+            self._slot_abandon(e.shard, e.slot)
             e.slot = None
             e.shard = None
         self._cancel_migration(e)
@@ -1783,6 +1854,15 @@ class World:
         )
 
     def tick(self) -> None:
+        """One tick with a blocking fetch: a standalone World (tests,
+        ``chip_smoke.py``, embedded loops) has no queue to serve while
+        the device computes. The GameServer calls the two halves itself
+        and pumps its queue between them."""
+        with self.tick_record():
+            self.tick_land(self.tick_dispatch())
+
+    @contextlib.contextmanager
+    def tick_record(self):
         # per-tick phase timeline (debug_http /trace): the GameServer's
         # serve loop opens the tick record (so pump/fan-out spans land in
         # it too); a standalone World opens its own and must close it
@@ -1792,12 +1872,37 @@ class World:
         if self_opened:
             tl.begin_tick(self.tick_count)
         try:
-            self._tick_phases(tl)
+            yield
         finally:
             if self_opened:
                 tl.end_tick()
 
-    def _tick_phases(self, tl) -> None:
+    def watch_landing(self, flight: TickInFlight,
+                      wake: threading.Event) -> None:
+        """Have something other than the caller wait for the outputs
+        of ``flight``: this World's waiter blocks until the device is
+        done, marks the tick ``landed`` and sets ``wake``. Nothing is
+        handed over where the device is done already (a pipelined
+        decode fetches the previous tick's outputs). One waiter a
+        World, started with the first tick that needs it and fed
+        through a queue: starting a thread per tick held the caller 3
+        ms a tick in a served process (CPU rehearsal, PR 34)."""
+        if flight.landed or _has_landed(flight.fetch):
+            flight.landed = True
+            return
+        flights = self._landings
+        if flights is None:
+            flights = self._landings = queue.SimpleQueue()
+            threading.Thread(target=_await_landings, args=(flights,),
+                             name="tick-landing", daemon=True).start()
+            weakref.finalize(self, flights.put, None)
+        flights.put((flight, wake))
+
+    def tick_dispatch(self) -> TickInFlight:
+        """First half of a tick: flush what was staged, dispatch the
+        device step, name what the second half fetches. Returns at once
+        (the step runs asynchronously)."""
+        tl = metrics.timeline
         t_start = time.perf_counter()
         # serve-loop residency marks (utils/residency.py): perf_counter
         # instants at the phase boundaries this method already has —
@@ -1891,30 +1996,41 @@ class World:
         # adds zero sync points. Only the single-controller non-mega
         # shape is judged (a mesh slice would gather cross-device; the
         # skip is recorded honestly in _audit_sample).
+        fetch = {}
+        if outs is not None:
+            fetch["outs"] = outs
+        if acc_fetch is not None:
+            # the telemetry drain rides the EXISTING fetch: one
+            # combined transfer, zero added sync points per tick
+            fetch["acc"] = acc_fetch
+        ap = self.audit
+        if (ap is not None and self.mega is None
+                and self.mesh is None and not self.pipeline_decode
+                and ap.want_sample(self.tick_count)):
+            s = self._audit_shard % self.n_spaces
+            with tl.span("fetch_outputs"):
+                fetch["aud"] = (self.state.pos[s], self.state.alive[s],
+                                self.state.aoi_radius[s])
+        if rt is not None:
+            rt.mark_fetch()
+        return TickInFlight(fetch, outs, age_mark, t_start, t0)
+
+    def tick_land(self, flight: TickInFlight) -> None:
+        """Second half of a tick: fetch the outputs ``tick_dispatch``
+        named (a copy of what is on the host already where the caller
+        waited for ``flight.landed``), decode them, fan out."""
+        tl = metrics.timeline
+        rt = self.residency
+        outs, age_mark = flight.outs, flight.age_mark
+        # the device's planes are let go once they are on the host, as
+        # when fetch and decode were one method's locals
+        fetch, flight.fetch, flight.outs = flight.fetch, None, None
         with tl.span("fetch_outputs"):
-            aud_req = None
-            ap = self.audit
-            if (ap is not None and self.mega is None
-                    and self.mesh is None and not self.pipeline_decode
-                    and ap.want_sample(self.tick_count)):
-                s = self._audit_shard % self.n_spaces
-                aud_req = (self.state.pos[s], self.state.alive[s],
-                           self.state.aoi_radius[s])
             acc_host = None
             aud_host = None
-            if rt is not None:
-                rt.mark_fetch()
-            fetch = {}
-            if outs is not None:
-                fetch["outs"] = outs
-            if acc_fetch is not None:
-                # the telemetry drain rides the EXISTING fetch: one
-                # combined transfer, zero added sync points per tick
-                fetch["acc"] = acc_fetch
-            if aud_req is not None:
-                fetch["aud"] = aud_req
             if fetch:
                 got = self._dget(fetch)
+                del fetch
                 if "outs" in got:
                     outs = got["outs"]
                 acc_host = got.get("acc")
@@ -1950,7 +2066,7 @@ class World:
         # actually waited on the device, the number the 16 ms budget
         # cares about (the true per-step device time is not
         # host-observable without a sync)
-        dt = time.perf_counter() - t0
+        dt = time.perf_counter() - flight.t0
         self.op_stats["device_step_s"] = dt
         if rt is not None:
             rt.observe_device_step(dt)
@@ -1992,7 +2108,8 @@ class World:
                         "residency sampling failed; disabled")
                     self.residency = None
         self.tick_count += 1
-        opmon.monitor.record("world.tick", time.perf_counter() - t_start)
+        opmon.monitor.record("world.tick",
+                             time.perf_counter() - flight.t_start)
 
     def _decode_outputs(self, outs) -> None:
         """The host half of a tick: record + decode fetched outputs.
@@ -2588,6 +2705,10 @@ class World:
                       else entities.get(slot_eid(j)))
                 if we is None or je is None:
                     continue
+                # a client is told of a leaving only for what it was
+                # told had entered (an enter the pass below skipped, or
+                # one past enter_cap, was never sent)
+                told = je.id in we.interested_in
                 we.interested_in.discard(je.id)
                 je.interested_by.discard(we.id)
                 wcls = we.__class__
@@ -2600,7 +2721,7 @@ class World:
                         we.OnLeaveAOI(je)
                     except Exception:
                         logger.exception("OnLeaveAOI failed")
-                if we.client is not None and not we.destroyed:
+                if told and we.client is not None and not we.destroyed:
                     we.client.send({
                         "type": "destroy_entity", "eid": je.id,
                         "is_player": False,
@@ -2650,6 +2771,13 @@ class World:
                 je = (self._owner_subject(shard, j) if mega
                       else entities.get(slot_eid(j)))
                 if we is None or je is None:
+                    continue
+                if we.slot is None or je.slot is None:
+                    # alive on the device in this tick, left by its
+                    # entity since the dispatch (destroyed, or moved out
+                    # of the space, by a handler the serve loop ran
+                    # while the device computed; the despawn waits for
+                    # the next flush): no client hears of it again
                     continue
                 we.interested_in.add(je.id)
                 je.interested_by.add(we.id)
@@ -2763,7 +2891,7 @@ class World:
                 for slot, col, v in zip(es.tolist(), cs.tolist(),
                                         vs.tolist()):
                     e = entities.get(slot_eid(slot))
-                    if e is None:
+                    if e is None or e.slot is None:
                         continue
                     info = e._type_desc.hot_attr_by_col.get(col)
                     if info is None:
@@ -2865,9 +2993,15 @@ class World:
             # forget destroyed host objects even when the slot was already
             # re-occupied by an arrival (cur != expect): destroy_entity
             # kept them alive only for this release point
+            # ... unless a row of theirs still waits for its despawn
+            # (a destroyed entity whose row hopped tiles meanwhile): its
+            # watchers' leave events come with that one
             if expect is not None:
                 e = self.entities.get(expect)
-                if e is not None and e.destroyed and e.slot is None:
+                if e is not None and e.destroyed and e.slot is None \
+                        and not any(
+                            self._slot_owner[sh_].get(sl_) == expect
+                            for sh_, sl_ in self._staged_despawn):
                     self.entities.pop(expect, None)
         self._release_now = self._release_next
         self._release_next = []
@@ -2915,22 +3049,86 @@ class World:
             )
         return pending
 
+    def _claim_arrival_row(self, shard: int, slot: int, eid: str) -> None:
+        """The device has put a migrated row into ``slot``: the host
+        follows. A row the host handed to a spawn of its own since the
+        dispatch (a handler the serve loop ran while the device
+        computed; still staged, nothing on the device names it) gives
+        way: that spawn moves to another free row."""
+        cur = self._slot_owner[shard].get(slot)
+        if cur is not None and cur != eid:
+            for i, (sh_, sl_, _) in enumerate(self._staged_spawn):
+                if (sh_, sl_) == (shard, slot):
+                    self._move_staged_spawn(i, cur)
+                    break
+        self._slot_set(shard, slot, eid)
+        self._free[shard].discard(slot)
+
+    def _move_staged_spawn(self, i: int, eid: str) -> None:
+        shard, slot, data = self._staged_spawn[i]
+        z = self.entities.get(eid)
+        holds = z is not None and (z.shard, z.slot) == (shard, slot)
+        if self._free[shard]:
+            new = self._free[shard].pop()
+            self._staged_spawn[i] = (shard, new, data)
+            self._restage_row((shard, slot), (shard, new))
+            self._slot_set(shard, new, eid)
+            if holds:
+                z.slot = new
+            return
+        logger.error(
+            "spawn of %s lost its row to an arrival and shard %d is "
+            "full; parked in nil space", eid, shard)
+        del self._staged_spawn[i]
+        self._drop_staged_for(shard, slot)
+        self._staged_despawn = [
+            x for x in self._staged_despawn if x != (shard, slot)]
+        if holds:
+            z.slot = z.shard = None
+            sp = z.space
+            if sp is not None:
+                sp.members.discard(eid)
+                z.space = None
+                self._enter_space_or_park(z, sp, data["pos"])
+
+    def _restage_row(self, old: tuple[int, int],
+                     new: tuple[int, int]) -> None:
+        """Writes staged for row ``old`` since the dispatch follow the
+        row's entity to ``new`` (the device moved it in the tick that
+        was in flight, or an arrival took a staged spawn's row)."""
+        if old in self._staged_pos:
+            self._staged_pos[new] = self._staged_pos.pop(old)
+        if self._batch_pos_any and self._batch_pos_mask[old]:
+            self._batch_pos_mask[old] = False
+            self._batch_pos_mask[new] = True
+            self._batch_pos_vals[new] = self._batch_pos_vals[old]
+        for name in ("_staged_despawn", "_staged_hot", "_staged_moving",
+                     "_staged_client"):
+            rows = getattr(self, name)
+            if rows:
+                setattr(self, name, [
+                    new + x[2:] if x[:2] == old else x for x in rows])
+
     def _mega_apply_arrivals(self, pending: list[tuple], outs) -> None:
         for shard, s, old_sh, old_sl, eid in pending:
             # old slot keeps its owner mapping through THIS step's leave
             # events; released at the end of _process_outputs
             self._release_now.append((old_sh, old_sl, eid))
-            self._slot_set(shard, s, eid)
-            self._free[shard].discard(s)
+            self._claim_arrival_row(shard, s, eid)
+            self._restage_row((old_sh, old_sl), (shard, s))
             e = self.entities.get(eid)
-            if e is not None:
+            if e is not None and (e.shard, e.slot) == (old_sh, old_sl):
                 e.shard = shard
                 e.slot = s
-                if e.destroyed:
-                    # destroyed while the row hopped tiles: drop it
+            else:
+                # its entity left the row while it hopped tiles
+                # (destroyed, or moved out of the space: by a hook of
+                # the leave pass above, or by a handler the serve loop
+                # ran while the device computed): drop the arrived row.
+                # The despawn staged for the old row names it by now.
+                if (shard, s) not in self._staged_despawn:
                     self._staged_despawn.append((shard, s))
-                    e.slot = None
-                    e.shard = None
+                self._slot_abandon(shard, s)
         total_dropped = int(np.asarray(outs.migrate_dropped).sum())
         if total_dropped:
             self._mega_reconcile_dropped(total_dropped)
@@ -3015,8 +3213,7 @@ class World:
                 e._migrating = None
                 e.slot = int(s)
                 e.shard = shard
-                self._slot_set(shard, int(s), eid)
-                self._free[shard].discard(int(s))
+                self._claim_arrival_row(shard, int(s), eid)
                 if e.destroyed:
                     # destroyed mid-flight after the row already moved:
                     # drop the arrived row

@@ -262,13 +262,14 @@ class GameServer:
             "client_event_records_total",
             help="client event records flushed downstream")
         # where the serve loop handled a packet: in the frame's pump
-        # ahead of the tick, or between ticks as it arrived
+        # ahead of the tick, between ticks as it arrived, or inside the
+        # tick while the device computed
         self._m_pumped = {
             where: metrics.counter(
                 "game_pump_packets_total",
                 help="packets the serve loop handled, by where",
                 where=where)
-            for where in ("frame", "between")
+            for where in ("frame", "between", "device_wait")
         }
 
         # incident flight recorder + live workload signature (ISSUE 11,
@@ -466,8 +467,9 @@ class GameServer:
         this same thread against the host state the finished tick left,
         and the client events its handler staged go on the wire then
         (``_flush_events_out``); position syncs leave once a tick, as
-        before. Only while the thread is inside ``tick()`` does a call
-        wait."""
+        before. Inside ``tick()`` the wait for the device is a wait on
+        the queue too (``_serve_in_flight``): a call waits only while
+        the thread flushes, dispatches, decodes or fans out."""
         if self.gc_freeze_on_boot:
             # Move everything alive at boot (the spawned entity
             # population, attr trees, numpy mirrors, handler tables)
@@ -664,21 +666,68 @@ class GameServer:
         deadline = (
             time.monotonic() + budget if budget is not None else None
         )
-        while True:
-            try:
-                didx, msgtype, pkt = self._packet_q.pop()
-            except IndexError:
-                return n
-            try:
-                self._handle_packet(didx, msgtype, pkt)
-            except Exception:
-                logger.exception(
-                    "game%d: handler for msgtype %d failed",
-                    self.game_id, msgtype,
-                )
+        while self._handle_next():
             n += 1
             if deadline is not None and time.monotonic() > deadline:
-                return n
+                break
+        return n
+
+    def _handle_next(self) -> bool:
+        """Handle the first queued packet; False where none is."""
+        try:
+            didx, msgtype, pkt = self._packet_q.pop()
+        except IndexError:
+            return False
+        try:
+            self._handle_packet(didx, msgtype, pkt)
+        except Exception:
+            logger.exception(
+                "game%d: handler for msgtype %d failed",
+                self.game_id, msgtype,
+            )
+        return True
+
+    def _serve_in_flight(self, flight) -> None:
+        """The wait for the device as a wait ON THE QUEUE, like the
+        frame's remainder in ``serve_forever``: until the tick's
+        outputs have landed (the world's waiter then sets
+        the event the net thread sets) a packet is handled as it
+        arrives and the client events its handler staged go on the
+        wire, ahead of the tick's sync batch. Landing is looked at
+        after every packet, so a flood lengthens the frame by one
+        handler at most. What a handler stages belongs to the next
+        flush; a handler that reads device state blocks on the tick in
+        flight as any read of ``world.state`` does. ``stop()`` and a
+        freeze end the loop at once (the tick is then fetched as a
+        standalone World fetches it, and ``_do_freeze`` runs after
+        it). The wait is ``fetch_outputs`` spans and each burst a
+        ``drain_inputs`` span of the tick record; the residency plane's
+        ``device_wait`` lane covers both."""
+        tl = metrics.timeline
+        self.world.watch_landing(flight, self._wake)
+        while not (flight.landed or self._stop.is_set()
+                   or self.run_state != "running"):
+            with tl.span("fetch_outputs"):
+                self._wake.wait()
+            if self._stop.is_set():
+                return
+            self._wake.clear()
+            if flight.landed or not self._packet_q.qsize():
+                continue
+            with tl.span("drain_inputs"):
+                n = 0
+                while self._handle_next():
+                    n += 1
+                    if flight.landed or self.run_state != "running":
+                        break
+                self._m_pumped["device_wait"].inc(n)
+                # as between ticks: under the DEGRADED hold the events
+                # stay with the syncs they were held with
+                if not self._sync_out:
+                    self._flush_events_out()
+        if self._packet_q.qsize():
+            # what the landing cut short is the frame's remainder's
+            self._wake.set()
 
     def tick(self) -> None:
         if self._standby_applier is not None and not self._promoted:
@@ -693,14 +742,23 @@ class GameServer:
         t0 = time.perf_counter()
         tl = metrics.timeline
         rt = getattr(self.world, "residency", None)
-        if self.world._multihost:
+        w = self.world
+        if w._multihost:
             # the exchange also publishes world.mh_group_ready, which
             # gates the World's own tick-cadence service reconcile
             with tl.span("mh_exchange"):
                 self._mh_exchange_mutations()
             if rt is not None:
                 rt.add_host(time.perf_counter() - t0)
-        self.world.tick()
+            # every controller must reach the fetch (a
+            # process_allgather) at the same point, and mutations cross
+            # controllers in the exchange alone: the blocking tick
+            w.tick()
+        else:
+            with w.tick_record():
+                flight = w.tick_dispatch()
+                self._serve_in_flight(flight)
+                w.tick_land(flight)
         # everything from here to the end of tick() is useful host work
         # between device dispatches — declared to the residency plane
         # so the bubble verdict only counts genuinely idle time

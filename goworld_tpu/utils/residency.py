@@ -296,7 +296,7 @@ class ResidencyTracker:
         # window mark for the flight-recorder regression trigger
         self._win_mark: list[int] | None = None
 
-    # -- per-tick marks (called from World._tick_phases) -----------------
+    # -- per-tick marks (called from World.tick_dispatch / tick_land) ----
     def tick_begin(self) -> None:
         self._t_begin = time.perf_counter()
         if not self._gc_bound:

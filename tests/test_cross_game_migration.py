@@ -166,7 +166,10 @@ async def _login(bot: BotClient, name: str):
     raise AssertionError("avatar never arrived")
 
 
-async def _migrate_script(bot: BotClient, space_id: str, n_pings: int):
+async def _migrate_script(bot: BotClient, space_id: str, n_pings: int,
+                          hold: threading.Event | None = None):
+    """``hold``: the connection stays up until it is set, for a caller
+    that looks at the server's side of it (the close unbinds it)."""
     import asyncio
 
     recv = await _login(bot, "bob")
@@ -187,6 +190,10 @@ async def _migrate_script(bot: BotClient, space_id: str, n_pings: int):
         assert any(m == "OnArrived" for _, m, _ in bot.rpc_log), \
             "client never told about migrate-in"
         await asyncio.sleep(0.5)
+        for _ in range(2000):
+            if hold is None or hold.is_set():
+                break
+            await asyncio.sleep(0.01)
     finally:
         recv.cancel()
         await bot.conn.close()
@@ -199,10 +206,23 @@ def test_cross_game_enter_space_with_rpcs_in_flight(two_game_cluster):
     bot = BotClient(host, port, strict=True)
     n_pings = 40
 
+    hold = threading.Event()
     fut = harness.submit(
-        _migrate_script(bot, w2._test_space.id, n_pings)
+        _migrate_script(bot, w2._test_space.id, n_pings, hold)
     )
+    # client binding survived (OnArrived already proves the downstream
+    # path; this proves the server-side handle): looked at while the
+    # connection is up, since the game unbinds a client that has gone
+    # as soon as it hears of it
+    bound = False
+    deadline = time.time() + 60
+    while time.time() < deadline and not bound:
+        av = _avatar_in(w2)
+        bound = av is not None and av.client is not None
+        time.sleep(0.02)
+    hold.set()
     fut.result(timeout=60)
+    assert bound, "the migrated avatar never had its client"
     assert not bot.errors, bot.errors
 
     # the avatar left game1 entirely...
@@ -223,9 +243,6 @@ def test_cross_game_enter_space_with_rpcs_in_flight(two_game_cluster):
     # EVERY ping was delivered exactly once (block+queue, no loss): the
     # counter is an attr, so it also proves attr state moved intact
     assert av.attrs.get("pings") == n_pings
-    # client binding survived (OnArrived already proves the downstream
-    # path; this proves the server-side handle)
-    assert av.client is not None
     # timers survived and keep firing on the new game
     assert av.timer_ids, "timers were not restored after migration"
     hb0 = av.attrs.get("heartbeats") or 0
