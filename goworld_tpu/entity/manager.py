@@ -345,6 +345,15 @@ class World:
             )
             self._step = make_mega_tick(self.mega, mesh,
                                         donate=resident)
+            # tile-ticks that named their neighbours by the all-rows
+            # gather (more ghost-holding rows than the fast path takes)
+            self.mega_ghost_full_path = 0
+            self._m_mega_ghost_full = metrics.counter(
+                "mega_ghost_full_path_total",
+                help="tile-ticks whose ghost-holding rows exceeded "
+                     "ghost_sort_rows (all-rows gather and sort)",
+            )
+            self._m_mega_ghost_rows = metrics.gauge("mega_ghost_rows")
         else:
             state_cfg = cfg
             if mesh is None and n_spaces > 1 and cfg.grid.skin > 0:
@@ -3047,6 +3056,16 @@ class World:
                 "cross-border neighbors invisible this tick",
                 int(hdem.max()), self.mega.halo_cap,
             )
+        # rows holding a ghost: the tick re-sorts only those, unless a
+        # tile holds more than ghost_sort_rows and takes the full path
+        gdem = np.asarray(outs.ghost_row_demand)
+        full = int((gdem > self.mega.ghost_sort_rows).sum())
+        if full:
+            self.mega_ghost_full_path += full
+            self._m_mega_ghost_full.inc(full)
+        self._m_mega_ghost_rows.set(int(gdem.max()))
+        opmon.expose("mega_ghost_rows", int(gdem.max()))
+        opmon.expose("mega_ghost_full_path_total", self.mega_ghost_full_path)
         return pending
 
     def _claim_arrival_row(self, shard: int, slot: int, eid: str) -> None:

@@ -164,6 +164,15 @@ class MegaConfig:
         return (4 if self.is_2d else 2) * self.halo_cap
 
     @property
+    def ghost_sort_rows(self) -> int:
+        """Rows of a tile that the tick's fast path names through the
+        ghost ids and sorts again: those that hold a ghost. A row that
+        sees a ghost lies within the radius of a border, so it is one
+        this tile ships as a ghost itself: the ghost buffer bounds them.
+        A tick with more takes the full path on that tile."""
+        return min(self.ghost_rows, self.cfg.capacity)
+
+    @property
     def gid_sentinel(self) -> int:
         return self.n_dev * self.cfg.capacity
 
@@ -189,6 +198,8 @@ class MegaTickOutputs:
                                # linger on the wrong tile with degraded AOI)
     halo_demand: jax.Array     # i32[n_dev] boundary strip occupancy (alarm
                                # when > halo_cap)
+    ghost_row_demand: jax.Array  # i32[n_dev] rows holding a ghost (over
+                                 # ghost_sort_rows: the full path ran)
     global_alive: jax.Array    # i32[n_dev]
 
 
@@ -222,6 +233,7 @@ def make_mega_tick(mc: MegaConfig, mesh: Mesh, donate: bool = False):
     gsent = mc.gid_sentinel
     tx, tz = mc.shape
     ghost_rows = mc.ghost_rows
+    sort_rows = mc.ghost_sort_rows
 
     def shard_fn(state, inputs: MultiTickInputs, policy):
         state = jax.tree.map(lambda x: x[0], state)
@@ -385,11 +397,35 @@ def make_mega_tick(mc: MegaConfig, mesh: Mesh, donate: bool = False):
             gid_ext = jnp.concatenate(
                 [d * n + jnp.arange(n, dtype=jnp.int32), ggid]
             )
-            nbr_gid = jnp.where(
-                nbr_ext == p_ext, gsent,
-                gid_ext[jnp.minimum(nbr_ext, p_ext - 1)],
+
+            def by_gather(rows):
+                # every lane through gid_ext, then each row sorted again:
+                # a ghost's gid falls anywhere among the local ones
+                return jnp.sort(jnp.where(
+                    rows == p_ext, gsent,
+                    gid_ext[jnp.minimum(rows, p_ext - 1)],
+                ), axis=1)
+
+            ghost_row = ((nbr_ext >= n) & (nbr_ext < p_ext)).any(axis=1)
+            ghost_row_demand = ghost_row.sum().astype(jnp.int32)
+
+            def ghost_rows_only(_):
+                # a local lane's gid is d*n + lane, a map that keeps
+                # order, and gsent tops every gid: a row with no ghost
+                # stays ascending and sentinel-padded as the sweep wrote it
+                local = jnp.where(nbr_ext < n, d * n + nbr_ext, gsent)
+                rows = jnp.flatnonzero(ghost_row, size=sort_rows,
+                                       fill_value=n)
+                fixed = by_gather(nbr_ext[jnp.minimum(rows, n - 1)])
+                return local.at[rows].set(fixed, mode="drop",
+                                          indices_are_sorted=True)
+
+            # exact either way: a tick with more ghost-holding rows than
+            # the bounded set takes the all-rows gather and sort
+            nbr_gid = jax.lax.cond(
+                ghost_row_demand <= sort_rows, ghost_rows_only,
+                lambda _: by_gather(nbr_ext), None,
             )
-            nbr_gid = jnp.sort(nbr_gid, axis=1)
             (enter_w, enter_j, enter_n, leave_w, leave_j, leave_n,
              delta_rows_n) = interest_pairs(
                 state.nbr, nbr_gid, gsent, cfg.enter_cap, cfg.leave_cap,
@@ -445,6 +481,7 @@ def make_mega_tick(mc: MegaConfig, mesh: Mesh, donate: bool = False):
             migrate_dropped=dropped,
             migrate_demand=mig_demand,
             halo_demand=halo_demand,
+            ghost_row_demand=ghost_row_demand,
             global_alive=global_alive,
         )
         state = jax.tree.map(lambda x: x[None], state)

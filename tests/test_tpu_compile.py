@@ -277,3 +277,104 @@ def test_ppermute_halo_compiles_on_v5e_2x2(mesh4, for_tpu):
     text = jax.jit(mapped).lower(*shapes).compile().as_text()
     assert "collective-permute" in text
     assert "tpu_custom_call" not in text
+
+
+def _computations(text: str) -> dict[str, list[str]]:
+    """{computation name: its instruction lines} of an HLO module's
+    text."""
+    comps: dict[str, list[str]] = {}
+    lines = None
+    for line in text.splitlines():
+        head = re.match(r"^(?:ENTRY )?%([\w.\-]+) .*\{\s*$", line)
+        if head:
+            lines = comps.setdefault(head.group(1), [])
+        elif line.startswith("}"):
+            lines = None
+        elif lines is not None:
+            lines.append(line)
+    return comps
+
+
+def _reached(comps, root: str) -> set[str]:
+    """``root`` and every computation it calls, transitively (fusions,
+    loops, branches, reducers)."""
+    seen, todo = set(), [root]
+    while todo:
+        name = todo.pop()
+        if name in seen or name not in comps:
+            continue
+        seen.add(name)
+        for line in comps[name]:
+            for attr in re.finditer(
+                    r"\b(?:calls|to_apply|body|condition|"
+                    r"branch_computations)=(\{[^}]*\}|%[\w.\-]+)", line):
+                todo += re.findall(r"%([\w.\-]+)", attr.group(1))
+    return seen
+
+
+def _sorts_and_gathers(comps, names) -> list[tuple[int, int, str]]:
+    """(rows, lanes, op_name) of every sort and gather in ``names``
+    (the first operand's shape for a sort of several)."""
+    found = []
+    for name in names:
+        for line in comps[name]:
+            m = re.match(r"\s*(?:ROOT )?%[\w.\-]+ = \(?\w+\[(\d+),(\d+)\]"
+                         r"[^=]*?\b(?:sort|gather)\(", line)
+            if m:
+                op = re.search(r'op_name="([^"]*)"', line)
+                found.append((int(m.group(1)), int(m.group(2)),
+                              op.group(1) if op else ""))
+    return found
+
+
+def test_mega_tick_names_ghost_rows_only_on_v5e_2x2(mesh4, for_tpu):
+    """The served (ppermute) mega tick at the real tile: under
+    ``gw.delta`` a ``cond`` names the neighbours, and only its full
+    branch gathers and sorts the whole [131072, 64] neighbour plane.
+    The branch a tick takes while the ghost-holding rows fit sorts
+    [ghost_sort_rows, 64] and gathers no more. Outside the full
+    branches of ``gw.delta``'s conds (this one, and the mass-event tier
+    of ``interest_pairs``) no sort or gather spans the plane: the 59.8
+    ms fusion of the all-rows gather (PERF.md fault 9) is gone from
+    the path an ordinary tick runs."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from goworld_tpu.parallel.megaspace import (
+        create_mega_state,
+        make_mega_tick,
+    )
+    from goworld_tpu.parallel.mesh import SPACE_AXIS
+    from goworld_tpu.parallel.step import MultiTickInputs
+
+    mc = _mega_config("ppermute")
+    plane = (N, mc.cfg.grid.k)
+    sh = NamedSharding(mesh4, P(SPACE_AXIS))
+    state = _shaped(jax.eval_shape(lambda: create_mega_state(mc)), sh)
+    inputs = _shaped(jax.eval_shape(
+        lambda: MultiTickInputs.empty(mc.cfg, 4)), sh)
+    comps = _computations(make_mega_tick(mc, mesh4, donate=True)
+                          .lower(state, inputs, None).compile().as_text())
+    conds = {}
+    for lines in comps.values():
+        for line in lines:
+            op = re.search(r'op_name="([^"]*)"', line)
+            if " conditional(" in line and op \
+                    and "gw.delta" in op.group(1).split("/"):
+                conds[op.group(1)] = re.findall(r"%([\w.\-]+)", re.search(
+                    r"branch_computations=\{([^}]*)\}", line).group(1))
+    naming = "jit(shard_fn)/shard_map/gw.delta/cond"
+    assert naming in conds, sorted(conds)
+    full_paths = set()
+    for op, branches in conds.items():
+        shapes = {b: {(r, c) for r, c, _ in _sorts_and_gathers(
+            comps, _reached(comps, b))} for b in branches}
+        full = [b for b in branches if plane in shapes[b]]
+        assert len(full) == 1, (op, shapes)
+        full_paths |= _reached(comps, full[0])
+        if op == naming:
+            (fast,) = set(branches) - set(full)
+            assert shapes[fast] == {(mc.ghost_sort_rows, plane[1])}
+    left = [(name, op) for name in set(comps) - full_paths
+            for r, c, op in _sorts_and_gathers(comps, [name])
+            if (r, c) == plane and "gw.delta" in op.split("/")]
+    assert not left, left
