@@ -201,6 +201,13 @@ class GateService:
             "gate_downstream_batch_records",
             buckets=metrics.DEFAULT_SIZE_BUCKETS,
             help="records per downstream batch from games")
+        # the gate's own share of the sync-age ``gate_flush`` lane: a
+        # sync batch's decode, regroup and per-client sends, from the
+        # handler's entry to the last send (the rest of the lane is the
+        # wire leg and the wait for this loop)
+        self._m_relay_ms = metrics.histogram(
+            "gate_relay_ms",
+            help="sync batch relay: handler entry to last client send")
         # admission control (utils/overload.py; docs/ROBUSTNESS.md
         # "Overload & degradation"): connection cap, per-client
         # token-bucket rate limits, bounded per-client downstream
@@ -582,8 +589,9 @@ class GateService:
                 self._relay_to_client(mt, rec)
             return
         if msgtype == proto.MT_SYNC_POSITION_YAW_ON_CLIENTS:
+            t0 = time.perf_counter()
             pkt.read_u16()  # gate_id routing prefix (ours)
-            self._handle_sync_on_clients(pkt, age=pkt.age)
+            self._handle_sync_on_clients(pkt, t0)
             return
         if msgtype == proto.MT_SYNC_POSITION_YAW_DELTA_ON_CLIENTS:
             # delta-compressed sync leg (ISSUE 12): reconstruct full
@@ -592,6 +600,7 @@ class GateService:
             # Decoder state is PER SENDING GAME — each game assigns
             # handles from its own counter, so one shared table would
             # collide (and one game's reset would wipe the others)
+            t0 = time.perf_counter()
             pkt.read_u16()
             sender = pkt.read_u16()
             dec = self._sync_delta_dec.get(sender)
@@ -605,7 +614,7 @@ class GateService:
                 logger.warning("gate%d: bad delta-sync batch from "
                                "game%d: %s", self.gate_id, sender, exc)
                 return
-            self._relay_sync_records(cids, eids, vals, age=pkt.age)
+            self._relay_sync_records(cids, eids, vals, age=pkt.age, t0=t0)
             return
         if msgtype == proto.MT_SET_CLIENT_FILTER_PROP:
             pkt.read_u16()
@@ -652,25 +661,39 @@ class GateService:
         to the socket — the sync-age plane must weight by DELIVERED
         records, and a dropped-under-overload batch is precisely the
         case the breach trigger exists for."""
-        if self.downstream_max_bytes <= 0:
+        if self._down_room(cp, len(p.buf)):
             cp.send(p)
             return True
-        buffered = cp.downstream_buffered()
-        if buffered + len(p.buf) <= self.downstream_max_bytes:
-            if cp.down_full_since is not None:
-                cp.down_full_since = None
-                self._down_full.discard(cp.client_id)
-            cp.send(p)
-            return True
-        self._m_down_dropped.inc()
-        now = asyncio.get_event_loop().time()
         mt = (int.from_bytes(bytes(p.buf[:2]), "little") & MSGTYPE_MASK
               if len(p.buf) >= 2 else 0)
         p.release()
+        self._down_refused(cp, mt)
+        return False
+
+    def _down_room(self, cp: ClientProxy, nbytes: int) -> bool:
+        """True iff a packet of ``nbytes`` (msgtype + body) fits under
+        the client's downstream bound; a client with room again leaves
+        the stalled set."""
+        if self.downstream_max_bytes <= 0:
+            return True
+        if cp.downstream_buffered() + nbytes > self.downstream_max_bytes:
+            return False
+        if cp.down_full_since is not None:
+            cp.down_full_since = None
+            self._down_full.discard(cp.client_id)
+        return True
+
+    def _down_refused(self, cp: ClientProxy, mt: int) -> None:
+        """A packet of msgtype ``mt`` did not fit: count the drop, and
+        kick the client at once for a message nothing re-sends, or once
+        its buffer has stayed full past the kick window."""
+        self._m_down_dropped.inc()
+        buffered = cp.downstream_buffered()
+        now = asyncio.get_event_loop().time()
         if overload.classify(mt) < overload.CLASS_SYNC:
             self._kick_stalled(cp, buffered,
                                f"cannot take msgtype {mt}")
-            return False
+            return
         if cp.down_full_since is None:
             cp.down_full_since = now
             self._down_full.add(cp.client_id)
@@ -684,7 +707,6 @@ class GateService:
             self._kick_stalled(
                 cp, buffered,
                 f"full for {now - cp.down_full_since:.1f}s")
-        return False
 
     def _kick_stalled(self, cp: ClientProxy, buffered: int,
                       why: str) -> None:
@@ -717,46 +739,68 @@ class GateService:
         out.append_bytes(bytes(memoryview(pkt.buf)[pkt.rpos:]))
         self._send_to_client(cp, out)
 
-    def _handle_sync_on_clients(self, pkt: Packet, age=None) -> None:
+    def _handle_sync_on_clients(self, pkt: Packet, t0: float) -> None:
         """Regroup 48B (cid+eid+pos) records per client and send each its
-        own 32B-record bundle (reference ``:350-375``). Grouping is a
-        vectorized unique+argsort over the 16B client ids — Python work
-        scales with CLIENTS, not records."""
+        own 32B-record bundle (reference ``:350-375``); ``t0`` is the
+        handler's entry, for ``gate_relay_ms``."""
         buf = memoryview(pkt.buf)[pkt.rpos:]
         cids, eids, vals = codec.decode_client_sync_batch(buf)
-        self._relay_sync_records(cids, eids, vals, age=age)
+        self._relay_sync_records(cids, eids, vals, age=pkt.age, t0=t0)
 
-    def _relay_sync_records(self, cids, eids, vals, age=None) -> None:
+    def _relay_sync_records(self, cids, eids, vals, age=None,
+                            t0: float | None = None) -> None:
         """The shared back half of both sync legs (full-record and
-        delta-decoded): per-client regroup + relay. ``age`` is the
-        batch's sync-age stamp (or None): delivered records are aged
-        ONCE per batch at the flush instant, weighted by how many
-        records actually left toward clients."""
+        delta-decoded): per-client regroup + relay, vectorized over the
+        batch so Python work scales with CLIENTS, not records. One
+        stable sort by client id serves the clients in id order, each
+        its records in batch order; one structured fill lays every 32B
+        client record (``codec.SYNC_DTYPE``) of the batch into one
+        buffer, and a client's packet body is a slice of it. ``age``
+        is the batch's sync-age stamp (or None): delivered records are
+        aged ONCE per batch at the flush instant, weighted by how many
+        records actually left toward clients. ``t0`` (the handler's
+        entry; default now) starts the batch's ``gate_relay_ms``."""
+        if t0 is None:
+            t0 = time.perf_counter()
         n = len(cids)
         self._m_down_batch.observe(n)
         if n == 0:
             return
-        keys = np.ascontiguousarray(cids).view("V16").ravel()
-        uniq, inv = np.unique(keys, return_inverse=True)
-        order = np.argsort(inv, kind="stable")
-        bounds = np.cumsum(np.bincount(inv, minlength=len(uniq)))
-        start = 0
+        ca = np.ascontiguousarray(cids, "S16")
+        # the 16B ids as two big-endian u64 columns: integer order is
+        # byte order, and lexsort is stable
+        key = ca.view(">u8").reshape(n, 2)
+        hi = key[:, 0].astype(np.uint64)
+        lo = key[:, 1].astype(np.uint64)
+        order = np.lexsort((lo, hi))
+        hi, lo = hi[order], lo[order]
+        starts = np.flatnonzero(
+            np.r_[True, (hi[1:] != hi[:-1]) | (lo[1:] != lo[:-1])])
+        names = ca[order[starts]].tobytes().decode("ascii", "replace")
+        rec = np.empty(n, codec.SYNC_DTYPE)
+        rec["eid"] = np.take(eids, order)
+        rec["v"] = np.take(vals, order, axis=0)
+        blob = rec.tobytes()
+        bounds = (np.append(starts, n) * rec.itemsize).tolist()
+        mt = proto.MT_CLIENT_SYNC_POSITION_YAW
         delivered = 0
-        for u, stop in zip(uniq, bounds):
-            idxs = order[start:start + (stop - start)]
-            start = stop
-            cp = self.clients.get(bytes(u).decode("ascii", "replace"))
+        for g in range(len(starts)):
+            cp = self.clients.get(names[16 * g:16 * g + 16])
             if cp is None:
                 continue
-            out = new_packet(proto.MT_CLIENT_SYNC_POSITION_YAW)
-            out.append_bytes(
-                codec.encode_sync_batch(eids[idxs], vals[idxs])
-            )
+            body = blob[bounds[g]:bounds[g + 1]]
             # only records the socket actually took count as delivered
             # (a full-buffer drop under overload must not pollute the
             # age-at-delivery SLO it exists to trip)
-            if self._send_to_client(cp, out):
-                delivered += len(idxs)
+            if not self._down_room(cp, 2 + len(body)):
+                self._down_refused(cp, mt)
+                continue
+            if not cp.conn.send_raw(mt, body):
+                out = new_packet(mt)
+                out.append_bytes(body)
+                cp.send(out)
+            delivered += len(body) // rec.itemsize
+        self._m_relay_ms.observe((time.perf_counter() - t0) * 1e3)
         if age is not None and delivered:
             # age-at-delivery: the whole point of the stamp — measured
             # HERE, after the last per-client send of the batch

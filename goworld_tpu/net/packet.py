@@ -29,6 +29,7 @@ from goworld_tpu.utils.ids import ENTITYID_LENGTH
 MAX_PAYLOAD_LENGTH = 32 * 1024 * 1024  # defensive cap (reference 16M-ish)
 _SIZE_FMT = struct.Struct("<I")
 _TYPE_FMT = struct.Struct("<H")
+_HEAD_FMT = struct.Struct("<IH")  # size prefix + msgtype
 _U32 = struct.Struct("<I")
 _U16 = struct.Struct("<H")
 _F32 = struct.Struct("<f")
@@ -350,6 +351,22 @@ class PacketConnection:
             self._closed = True
         if release:
             p.release()
+
+    def send_raw(self, msgtype: int, body: bytes) -> bool:
+        """Write a packet of ``msgtype`` and ``body`` without building a
+        Packet: the bytes :meth:`send` writes for it untraced and
+        unstamped. Returns False, writing nothing, where this
+        connection must go through :meth:`send`: it compresses, or the
+        fault plane may act on its edge."""
+        if self.compress or (faults.active and self.edge):
+            return False
+        if not self._closed:
+            try:
+                self.writer.write(
+                    _HEAD_FMT.pack(2 + len(body), msgtype) + body)
+            except (ConnectionError, RuntimeError):
+                self._closed = True
+        return True
 
     def _faulted_send(self, p: Packet) -> bool:
         """Apply a seeded wire fault to this send, if one fires.

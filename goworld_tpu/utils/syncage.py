@@ -38,8 +38,9 @@ end-to-end age by construction):
                       (per-gate concat + batch/delta encode)
 ``dispatcher``        game send -> dispatcher forward (wire leg + any
                       dispatcher pend-queue residence)
-``gate_flush``        dispatcher forward -> gate per-client send (wire
-                      leg + delta decode + per-client regroup)
+``gate_flush``        dispatcher forward -> the batch's last per-client
+                      send (wire leg + wait for the gate's loop +
+                      ``gate_relay_ms``: decode, regroup, sends)
 ====================  ==================================================
 
 Clock honesty: instants are ``time.time()`` microseconds from three

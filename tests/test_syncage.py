@@ -397,18 +397,20 @@ def test_gate_relay_ages_delivered_records(monkeypatch):
     gate._m_down_batch = metrics.histogram(
         "gate_downstream_batch_records",
         buckets=metrics.DEFAULT_SIZE_BUCKETS)
+    gate._m_relay_ms = metrics.histogram("gate_relay_ms")
     gate.syncage = syncage.AgeTracker()
     gate.downstream_max_bytes = 0
 
     sent = []
 
+    class _Conn:
+        def send_raw(self, msgtype, body):
+            sent.append(body)
+            return True
+
     class _CP:
         client_id = "C" + "0" * 15
-
-        def send(self, p, release=True):
-            sent.append(bytes(p.buf))
-            if release:
-                p.release()
+        conn = _Conn()
 
     gate.clients[_CP.client_id] = _CP()
     cids = np.asarray([_CP.client_id.encode(), b"C%015d" % 9], "S16")
